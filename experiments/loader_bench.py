@@ -147,7 +147,7 @@ def main() -> int:
             "batch": BATCH, "workers": workers,
             "cpus": os.cpu_count(),
             "note": "synthetic ImageNet-shaped JPEGs; feed target is "
-                    "~2500 img/s/chip (ResNet-50 bf16, BENCH_r01)",
+                    "~2500 img/s/chip (ResNet-50 bf16, July 2026 reading)",
         },
         "img_per_sec": results,
         "native_thread_scaling": {

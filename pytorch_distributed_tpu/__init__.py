@@ -24,29 +24,4 @@ JAX/XLA for TPU:
 
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax 0.4.x ships shard_map under jax.experimental with the replication
-    # check spelled ``check_rep``; newer jax promotes it to jax.shard_map
-    # with ``check_vma``.  The framework is written against the promoted
-    # API — bridge it here (this package is imported before any module
-    # that does ``from jax import shard_map``).
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def _shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=True,
-                          **kw):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma, **kw)
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    # Promoted alongside jax.shard_map; on 0.4.x the idiom is psum(1, axis)
-    # (special-cased to return the static axis size, not a collective).
-    def _axis_size_compat(axis_name):
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size_compat
-
 from pytorch_distributed_tpu import models  # noqa: F401  (registry import)

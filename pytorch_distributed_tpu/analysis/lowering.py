@@ -33,15 +33,6 @@ deterministic functions of the checked-in step builders, so the first
 build is authoritative for the session.  Cross-session reuse is safe
 only for text re-analysis (ledgers, detectors); anything needing the
 live ``compiled`` object recompiles via ``get``.
-
-Persistent *compilation* caching (jax's ``jax_compilation_cache_dir``)
-is separate and version-gated here: on jaxlib 0.4.x re-executing a
-deserialized cached executable on the CPU backend aborts the process
-("Fatal Python error: Aborted", observed on jax 0.4.37 in
-test_trainer's train step), so ``maybe_enable_persistent_cache`` hard-
-disables it for the known-bad range and on newer jaxlibs only enables
-after a populate+warm round-trip self-check passes in subprocesses
-(the failure mode is a process abort — it cannot be try/except'd).
 """
 
 from __future__ import annotations
@@ -49,10 +40,8 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import subprocess
-import sys
 import tempfile
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence
 
 from pytorch_distributed_tpu.analysis import core
 
@@ -238,146 +227,3 @@ def aot_ledgers(jitted, args: Sequence[Any], *, step: str,
         persist(cache_dir, step, text=text, mesh_shape=mesh_shape,
                 measured_peak_bytes=measured, arg_classes=arg_classes)
     return comm_ledger, mem_ledger
-
-
-# ------------------------------------- persistent compilation cache guard
-
-# jaxlib versions where the round-trip is KNOWN to abort the process:
-# the whole 0.4.x line (observed on jaxlib 0.4.36 / jax 0.4.37, CPU
-# backend — re-executing a deserialized executable dies with "Fatal
-# Python error: Aborted").  Kept as a range, not a list: every 0.4.x we
-# tried fails, and probing one costs a crashed subprocess anyway.
-_KNOWN_BAD_BELOW = (0, 5, 0)
-
-_SELFCHECK_SNIPPET = """\
-import jax, jax.numpy as jnp
-jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir", {cache_dir!r})
-try:
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-except Exception:
-    pass
-f = jax.jit(lambda x: (x * 2.0 + 1.0).sum())
-print(float(f(jnp.arange(64.0))))
-"""
-
-
-def jaxlib_version_tuple(version: Optional[str] = None) -> Tuple[int, ...]:
-    if version is None:
-        import jaxlib
-
-        version = jaxlib.__version__
-    parts: List[int] = []
-    for tok in str(version).split(".")[:3]:
-        digits = "".join(c for c in tok if c.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-def persistent_cache_known_bad(version: Optional[str] = None) -> bool:
-    return jaxlib_version_tuple(version) < _KNOWN_BAD_BELOW
-
-
-def persistent_cache_selfcheck(cache_dir: str, *, timeout: float = 120.0,
-                               _runner=None) -> bool:
-    """Populate + warm round-trip in fresh subprocesses: run the snippet
-    twice against ``cache_dir``; the second run deserializes the first's
-    entry, which is exactly the path that aborts on bad jaxlibs — only a
-    subprocess survives probing it.  Verdict is memoized per jaxlib
-    version in ``<cache_dir>/selfcheck.json`` so the pair of interpreter
-    launches is paid once per cache dir, not once per session."""
-    os.makedirs(cache_dir, exist_ok=True)
-    ver = ".".join(map(str, jaxlib_version_tuple()))
-    memo_path = os.path.join(cache_dir, "selfcheck.json")
-    try:
-        with open(memo_path) as f:
-            memo = json.load(f)
-        if memo.get("jaxlib") == ver:
-            return bool(memo.get("ok"))
-    except (OSError, ValueError):
-        pass
-    snippet = _SELFCHECK_SNIPPET.format(cache_dir=cache_dir)
-    runner = _runner or (lambda: subprocess.run(
-        [sys.executable, "-c", snippet], timeout=timeout,
-        capture_output=True, text=True))
-    ok = True
-    outs = []
-    try:
-        for _ in range(2):  # populate, then warm (deserialize + execute)
-            r = runner()
-            if r.returncode != 0:
-                ok = False
-                break
-            outs.append(r.stdout.strip())
-        else:
-            ok = len(outs) == 2 and outs[0] == outs[1] and outs[0] != ""
-    except (subprocess.TimeoutExpired, OSError):
-        ok = False
-    try:
-        with open(memo_path, "w") as f:
-            json.dump({"jaxlib": ver, "ok": ok}, f)
-    except OSError:
-        pass
-    return ok
-
-
-# The gate verdict is logged exactly once per interpreter session: the
-# gate is funneled through by every test session (conftest) and CLI
-# entry, and the one stderr line — detected jaxlib + enabled/disabled +
-# why — is the breadcrumb the ROADMAP's "revisit at jaxlib 0.5.0" item
-# needs when reading CI logs.  Reset by tests to assert the logging.
-_GATE_VERDICT_LOGGED = False
-
-
-def _log_gate_verdict(verdict: Dict[str, Any]) -> None:
-    global _GATE_VERDICT_LOGGED
-    if _GATE_VERDICT_LOGGED:
-        return
-    _GATE_VERDICT_LOGGED = True
-    state = "enabled" if verdict.get("enabled") else "disabled"
-    ver = ".".join(map(str, jaxlib_version_tuple()))
-    print(f"[lowering] persistent compilation cache {state} "
-          f"(jaxlib {ver}): {verdict['reason']}", file=sys.stderr)
-
-
-def maybe_enable_persistent_cache(
-        cache_dir: Optional[str] = None) -> Dict[str, Any]:
-    """Version-gated re-attempt of jax's persistent compilation cache.
-
-    Known-bad jaxlibs (< 0.5.0) short-circuit to disabled WITHOUT running
-    the self-check — the failure mode is a process abort, so probing on a
-    version already documented bad buys nothing and costs two interpreter
-    launches.  On newer jaxlibs the populate+warm subprocess round-trip
-    must pass before the cache dir is handed to jax.  ``PTD_PERSISTENT_
-    CACHE=0`` force-disables; ``=1`` skips the version gate but NOT the
-    self-check.  Returns ``{"enabled": bool, "reason": str}``; the
-    detected jaxlib + verdict is logged to stderr once per session."""
-    verdict = _gate_persistent_cache(cache_dir)
-    _log_gate_verdict(verdict)
-    return verdict
-
-
-def _gate_persistent_cache(
-        cache_dir: Optional[str] = None) -> Dict[str, Any]:
-    env = os.environ.get("PTD_PERSISTENT_CACHE", "")
-    if env == "0":
-        return {"enabled": False, "reason": "disabled by PTD_PERSISTENT_CACHE=0"}
-    ver = ".".join(map(str, jaxlib_version_tuple()))
-    if env != "1" and persistent_cache_known_bad():
-        return {"enabled": False, "reason": (
-            f"jaxlib {ver} is in the known-bad range (< "
-            f"{'.'.join(map(str, _KNOWN_BAD_BELOW))}): deserialized CPU "
-            "executables abort the process (see tests/conftest.py NOTE)")}
-    if cache_dir is None:
-        cache_dir = os.environ.get("PTD_JAX_CACHE_DIR") or os.path.join(
-            tempfile.gettempdir(), "ptd_jax_compilation_cache")
-    if not persistent_cache_selfcheck(cache_dir):
-        return {"enabled": False, "reason": (
-            f"jaxlib {ver}: populate+warm round-trip self-check failed "
-            f"in {cache_dir}")}
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    return {"enabled": True,
-            "reason": f"jaxlib {ver}: round-trip self-check passed",
-            "cache_dir": cache_dir}

@@ -101,9 +101,11 @@ class SelfAttention(nn.Module):
             else:
                 out = ring_self_attention(q, k, v, self.mesh, causal=True)
         elif _pick_attention(L, self.attn_impl) == "flash":
-            from pytorch_distributed_tpu.ops.flash_attention import flash_attention
+            from pytorch_distributed_tpu.ops.flash_attention import (
+                flash_attention_on_mesh,
+            )
 
-            out = flash_attention(q, k, v, True)
+            out = flash_attention_on_mesh(q, k, v, True, self.mesh)
         else:
             out = dense_attention(q, k, v, causal=True)
         out = out.reshape(B, L, C)
@@ -151,10 +153,11 @@ class SelfAttention(nn.Module):
             # Chunked-prefill callers must leave this off: a later chunk
             # needs the masked cache attention below.
             from pytorch_distributed_tpu.ops.flash_attention import (
-                flash_attention,
+                flash_attention_on_mesh,
             )
 
-            out = flash_attention(q, k, v, True).reshape(B, L, C)
+            out = flash_attention_on_mesh(
+                q, k, v, True, self.mesh).reshape(B, L, C)
             return _dense_cls(self.quant)(
                 C, use_bias=False, dtype=self.dtype, name="proj")(out)
         keys, values = ck.value, cv.value                 # [B, Lmax, H, D]
@@ -258,6 +261,17 @@ class TransformerLM(nn.Module):
             return x
         # Tied output head (embed.attend) keeps params lean at long context.
         return embed.attend(x.astype(jnp.float32)).astype(jnp.float32)
+
+
+def bind_mesh(model, mesh: Mesh):
+    """The step builders' hook: a ``TransformerLM`` built without a mesh
+    learns the step's mesh here, so that attention can wrap the Pallas
+    kernel for it (``flash_attention_on_mesh``).  A model that already
+    carries a mesh (sequence parallelism), and any other model, is
+    returned as it is."""
+    if isinstance(model, TransformerLM) and model.mesh is None:
+        return model.clone(mesh=mesh)
+    return model
 
 
 def transformer_lm(num_classes: int = 32000, dtype: Any = jnp.float32, **kw):

@@ -11,7 +11,7 @@ a flush-time step sink, like ``GoodputTracker``).
 Rule kinds (anchors in parentheses):
 
 - ``step_time_p95``   step-time quantile ceiling in ms (the
-  ``obs_report --diff`` step-time fence / ``BENCH_LKG.json`` trajectory);
+  ``obs_report --diff`` step-time fence);
 - ``goodput_floor``   live productive-seconds / wall-span estimate below
   ``min_pct`` (obs/goodput.py);
 - ``exposed_comm``    un-overlapped collective ms per step above

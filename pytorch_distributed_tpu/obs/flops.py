@@ -103,41 +103,41 @@ CPU_FALLBACK_HBM_BW = 20e9
 
 def device_peak_flops(device=None) -> float:
     """Peak FLOP/s for one chip.  ``PTD_TPU_PEAK_FLOPS`` overrides (chips
-    this table predates, or a measured-roofline denominator); unknown
-    accelerators fall back to the CPU placeholder rather than failing the
-    run — MFU is observability, not a gate."""
-    env = os.environ.get("PTD_TPU_PEAK_FLOPS")
-    if env:
-        return float(env)
+    this table predates, or a measured-roofline denominator).  A CPU
+    device gets the placeholder; any other device that is not in the
+    table is an error — a utilization against a made-up peak would read
+    as a measurement."""
     if device is None:
         import jax
 
         device = jax.devices()[0]
-    kind = (getattr(device, "device_kind", "") or "").lower()
-    for prefix, peak in PEAK_FLOPS_PER_CHIP.items():
-        if kind.startswith(prefix):
-            return peak
-    return CPU_FALLBACK_PEAK
+    return chip_peak_flops(getattr(device, "device_kind", "") or "")
 
 
 def _chip_table_lookup(table: Dict[str, float], kind: Optional[str],
                        fallback: float, env: str) -> float:
     """Shared device_kind-prefix lookup for the capability tables.
-    ``kind=None`` stays jax-free (the planner's analytic path): the env
-    override or the fallback, never a device query."""
+    ``kind=None`` stays jax-free (the planner's analytic path) and, like
+    the CPU's own ``device_kind`` ("cpu"), gets the CPU placeholder; the
+    env override wins over both.  Any other kind that is not in the table
+    raises."""
     env_val = os.environ.get(env)
     if env_val:
         return float(env_val)
-    kind = (kind or "").lower()
+    kind = (kind or "cpu").lower()
+    if kind == "cpu":
+        return fallback
     for prefix, value in table.items():
         if kind.startswith(prefix):
             return value
-    return fallback
+    raise ValueError(
+        f"device_kind {kind!r} is not in the chip tables (obs/flops.py); "
+        f"add it with its published figure or set ${env}")
 
 
 def chip_hbm_bytes(kind: Optional[str] = None) -> float:
     """Per-chip HBM bytes for a device_kind string (``PTD_TPU_HBM_BYTES``
-    overrides); unknown/absent kinds get the CPU placeholder."""
+    overrides); an absent or CPU kind gets the CPU placeholder."""
     return _chip_table_lookup(HBM_BYTES_PER_CHIP, kind, CPU_FALLBACK_HBM,
                               "PTD_TPU_HBM_BYTES")
 
@@ -151,8 +151,8 @@ def chip_link_bytes(kind: Optional[str] = None) -> float:
 
 def chip_hbm_bw(kind: Optional[str] = None) -> float:
     """Per-chip HBM bandwidth, bytes/s (``PTD_TPU_HBM_BW`` overrides);
-    unknown/absent kinds get the CPU placeholder — roofline labels on the
-    simulated mesh assert plumbing, never real intensity."""
+    an absent or CPU kind gets the CPU placeholder — roofline labels on
+    the simulated mesh assert plumbing, never real intensity."""
     return _chip_table_lookup(HBM_BW_PER_CHIP, kind, CPU_FALLBACK_HBM_BW,
                               "PTD_TPU_HBM_BW")
 

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -385,6 +386,36 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
     if interpret is not None:
         return interpret
     return jax.default_backend() != "tpu"
+
+
+def flash_attention_on_mesh(q, k, v, causal: bool,
+                            mesh: Optional[Mesh]) -> jnp.ndarray:
+    """``flash_attention`` inside a GSPMD program over ``mesh``.
+
+    A Mosaic kernel has no SPMD partitioning rule (the TPU compiler
+    refuses it: "Mosaic kernels cannot be automatically partitioned"), so
+    on a mesh of more than one device the call is wrapped in a
+    ``shard_map`` over the axes that shard batch (``data``) and heads
+    (``model``, parallel/mesh.py) — the form ``parallel/ulysses.py`` uses —
+    and every device runs the kernel on its own ``[B/data, L, H/model, D]``
+    block.  An axis that does not divide
+    its dimension is left out (GSPMD then gathers that dimension).  With no
+    mesh, one device, or a caller that is already inside a ``shard_map``
+    (the explicit-collectives step), this is the bare call."""
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return flash_attention(q, k, v, causal)
+
+    def axis(name: str, dim: int) -> Optional[str]:
+        fits = name in mesh.axis_names and dim % mesh.shape[name] == 0
+        return name if fits else None
+
+    spec = P(axis("data", q.shape[0]), None, axis("model", q.shape[2]), None)
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
 
 
 def pick_attention_impl(L: int, attn_impl: str = "auto") -> str:

@@ -87,12 +87,7 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
 # let conv3x3_plane_fits_vmem keep genuinely oversized slots on the XLA
 # backward.
 _VMEM_LIMIT_BYTES = 96 << 20
-# jax 0.4.x ships the class as TPUCompilerParams, newer as CompilerParams —
-# resolve whichever this container's pallas exposes (import-time, so a miss
-# would take the whole package down with it).
-_COMPILER_PARAMS = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
 
 def _pick_mtile(M: int, Ci: int, Co: int, itemsize: int) -> int:

@@ -112,14 +112,8 @@ def initialize(
         if "cpu" in platforms.split(","):
             # Multi-process CPU meshes (the test/e2e simulation path) need
             # the gloo collectives implementation — the default XLA CPU
-            # client refuses cross-process computations outright.  No-op
-            # on TPU pods, and tolerated where the option is gone (newer
-            # jax enables CPU collectives by default).
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception:  # noqa: BLE001
-                pass
+            # client refuses cross-process computations outright.
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,

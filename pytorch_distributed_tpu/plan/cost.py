@@ -53,9 +53,8 @@ class HW:
 
 def hw_for(chip: Optional[str] = None) -> HW:
     """HW record for a chip name ("v4", "v5e", "tpu v5p", ... or None/
-    "cpu" for the simulated-mesh placeholder).  Unknown names fall back
-    to the CPU placeholders — the planner still ranks, the absolute
-    times are then nominal."""
+    "cpu" for the simulated-mesh placeholder).  A name that is not in
+    the chip tables (obs/flops.py) raises."""
     if chip is None or chip.lower() in ("cpu", "host"):
         kind, name = None, "cpu"
     else:

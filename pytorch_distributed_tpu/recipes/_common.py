@@ -11,6 +11,7 @@ import numpy as np
 from pytorch_distributed_tpu.parallel import DistContext, data_parallel_mesh, initialize
 from pytorch_distributed_tpu.train.config import Config, parse_config
 from pytorch_distributed_tpu.train.trainer import Trainer
+from pytorch_distributed_tpu.utils.compile_cache import enable_compile_cache
 
 
 def seed_everything(seed: Optional[int]) -> None:
@@ -34,6 +35,7 @@ def run_recipe(
     bootstrap: bool = True,
 ) -> float:
     cfg: Config = parse_config(argv, description=description)
+    enable_compile_cache()
     seed_everything(cfg.seed)
     if cfg.precision is None:  # explicit --precision always wins
         cfg.precision = precision_default or "fp32"

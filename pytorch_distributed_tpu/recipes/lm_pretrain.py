@@ -46,6 +46,7 @@ from pytorch_distributed_tpu.train.lm import (
     SyntheticTokenDataset,
     TextFileDataset,
 )
+from pytorch_distributed_tpu.utils.compile_cache import enable_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -306,6 +307,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> float:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     ctx = initialize()
     n = jax.device_count()
     if args.ep > 1 and (args.tp > 1 or args.sp > 1 or args.pp > 1):

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from pytorch_distributed_tpu.models.transformer import bind_mesh
 from pytorch_distributed_tpu.ops import cross_entropy, qcomm
 from pytorch_distributed_tpu.train.meters import StepMeters
 from pytorch_distributed_tpu.train.optim import sgd_init, sgd_update
@@ -274,6 +275,7 @@ def make_lm_train_step(
     from pytorch_distributed_tpu.parallel import overlap as overlap_lib
     from pytorch_distributed_tpu.parallel import zero as zero_lib
 
+    model = bind_mesh(model, mesh)
     zero_mode = zero_lib.resolve_zero(zero)
     overlap_mode = overlap_lib.resolve_overlap(overlap)
     if explicit_collectives or overlap_mode == "bucketed":
@@ -673,6 +675,7 @@ def make_lm_eval_step(model, mesh: Mesh, param_specs, data_axis: str = "data",
     overrides the residual layout: the bucketed-overlap explicit step
     stores residuals stacked per rank and sharded ``P(data_axis)``, not
     param-shaped."""
+    model = bind_mesh(model, mesh)
 
     def step(state: TrainState, tokens: jnp.ndarray):
         # mutable=["losses"]: MoE models sow the router aux loss even in

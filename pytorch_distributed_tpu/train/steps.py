@@ -566,6 +566,26 @@ def make_train_step(
     )
 
 
+def state_shardings(mesh: Mesh, data_axis: str = "data",
+                    residual_sharded: bool = False,
+                    momentum_sharding=None) -> TrainState:
+    """Where a ``TrainState`` lives between steps, as a ``TrainState`` of
+    shardings: everything replicated, except the explicit quantized
+    path's stacked residuals (``residual_sharded``: ``P(data_axis)``) and
+    ``--zero wus`` momentum (``momentum_sharding``: a NamedSharding prefix
+    or a momentum-shaped tree of them)."""
+    replicated = NamedSharding(mesh, P())
+    return TrainState(
+        step=replicated,
+        params=replicated,
+        batch_stats=replicated,
+        momentum=(replicated if momentum_sharding is None
+                  else momentum_sharding),
+        residual=(NamedSharding(mesh, P(data_axis)) if residual_sharded
+                  else replicated),
+    )
+
+
 def make_eval_step(
     model,
     mesh: Mesh,
@@ -598,19 +618,13 @@ def make_eval_step(
         )
         return {"loss_sum": loss_sum, "correct1": c1, "correct5": c5, "count": count}
 
-    replicated = NamedSharding(mesh, P())
     sharded = NamedSharding(mesh, P(data_axis))
-    state_shardings = TrainState(
-        step=replicated,
-        params=replicated,
-        batch_stats=replicated,
-        momentum=(replicated if momentum_sharding is None
-                  else momentum_sharding),
-        residual=sharded if residual_sharded else replicated,
-    )
     batch_shardings = {"images": sharded, "labels": sharded, "weights": sharded}
     return jax.jit(
         step,
-        in_shardings=(state_shardings, batch_shardings),
-        out_shardings=replicated,
+        in_shardings=(
+            state_shardings(mesh, data_axis, residual_sharded,
+                            momentum_sharding),
+            batch_shardings),
+        out_shardings=NamedSharding(mesh, P()),
     )

@@ -41,7 +41,11 @@ from pytorch_distributed_tpu.train.lr import cosine_lr, step_decay_lr
 from pytorch_distributed_tpu.train.meters import AverageMeter, ProgressMeter, StepMeters
 from pytorch_distributed_tpu.train.optim import sgd_init
 from pytorch_distributed_tpu.train.state import TrainState
-from pytorch_distributed_tpu.train.steps import make_eval_step, make_train_step
+from pytorch_distributed_tpu.train.steps import (
+    make_eval_step,
+    make_train_step,
+    state_shardings,
+)
 from pytorch_distributed_tpu.utils import EpochCSVLogger
 
 
@@ -476,11 +480,18 @@ class Trainer:
             overlap=getattr(cfg, "overlap", "none"),
             bucket_mb=float(getattr(cfg, "bucket_mb", 4.0)),
         )
+        residual_sharded = (self._explicit
+                            and self.grad_compress in qcomm.QUANTIZED_MODES)
         self.eval_step = make_eval_step(
             self.model, mesh, data_axis=self.data_axis,
-            residual_sharded=(self._explicit
-                              and self.grad_compress in qcomm.QUANTIZED_MODES),
+            residual_sharded=residual_sharded,
             momentum_sharding=self._mom_sharding)
+        # Commit the state to the steps' own layout before the first call.
+        # An unplaced state has another abstract type than the placed one
+        # the step returns, so the second call would trace and compile the
+        # whole step again (on the chip ResNet-50's step compiled twice).
+        self.state = jax.device_put(self.state, state_shardings(
+            mesh, self.data_axis, residual_sharded, self._mom_sharding))
         self.feeder = DeviceFeeder(mesh, data_axis=self.data_axis)
         self._agree = None        # PreemptionAgreement holds the old mesh
         self._comm_fields = None  # ledger re-emits against the new mesh
@@ -568,14 +579,6 @@ class Trainer:
         self.state = TrainState(host.step, host.params, host.batch_stats,
                                 momentum, residual)
         self._build_for_mesh(new_mesh)
-        if self._mom_sharding is not None:
-            # The stacked/sharded momentum is placed eagerly (its layout
-            # just changed); everything param-shaped re-shards lazily via
-            # the step's in_shardings.
-            self.state = TrainState(
-                self.state.step, self.state.params, self.state.batch_stats,
-                jax.device_put(self.state.momentum, self._mom_sharding),
-                self.state.residual)
         if self._mfu_on:
             self._build_mfu()  # n_devices (and maybe batch) changed
         self._membership_epoch += 1

@@ -1420,7 +1420,7 @@ def _selftest() -> int:
                 "bench_event": "stale", "t": now,
                 "metric": "resnet50_train_images_per_sec_per_chip",
                 "last_good": "2026-07-31T06:32:08+0000",
-                "reason": "device discovery hung (tunnel unreachable)",
+                "reason": "no TPU found",
             }) + "\n")
             f.write('{"step": 20, "step_time": 0.0')
         # heartbeats: pid 0 current (elastic, epoch-stamped), pid 1
@@ -1489,7 +1489,7 @@ def _selftest() -> int:
         bench_events = os.path.join(d, "bench_events.jsonl")
         with open(bench_events, "w") as f:
             f.write(json.dumps({"bench_event": "stale", "t": now - 3600,
-                                "reason": "tunnel unreachable"}) + "\n")
+                                "reason": "no TPU found"}) + "\n")
 
         # a real autoplan payload (plan/ is jax-free on this path) for
         # the plan section + the --diff drift row

@@ -152,7 +152,11 @@ def main(argv=None) -> int:
         LoadConfig,
         generate_load,
     )
+    from pytorch_distributed_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
 
+    enable_compile_cache()
     if args.checkpoint:
         (params, args.vocab_size, args.d_model,
          args.n_layers) = load_checkpoint_params(args.checkpoint)

@@ -2,7 +2,12 @@
 # Canonical launch lines, one per recipe — reference start.sh:1-5 parity.
 # For smoke runs on a non-TPU host, prefix any line with
 #   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
-# to simulate an 8-chip mesh on CPU.
+# to simulate an 8-chip mesh on CPU (this is how the tests run).
+# On a TPU host, `python chip_smoke.py` first: it proves the trainers and the
+# serving engine start, compile and step on the chip.  One process per chip
+# (one process drives all the chips of a host): never start a recipe from a
+# parent that has already touched JAX.  Compiled programs are cached in
+# $JAX_COMPILATION_CACHE_DIR if set, else in ./.jax_cache (git-ignored).
 
 # 1. self-contained multi-process DP (ref start.sh:1: python multiprocessing_distributed.py)
 python -m pytorch_distributed_tpu.recipes.multiprocessing_distributed --data "$DATA"

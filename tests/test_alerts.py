@@ -204,7 +204,15 @@ def test_hang_and_recompile_event_rules():
     assert b.value == 2.0 and b.threshold == 1.0
 
 
-def test_engine_never_alerts_on_alert_events():
+@pytest.fixture
+def no_bench_history(monkeypatch, tmp_path):
+    """The default ``bench_stale`` rule ages the checkout's own
+    ``bench_events.jsonl``; point it at nothing, so a test of the default
+    rule set does not depend on when ``bench.py`` last ran here."""
+    monkeypatch.setenv("BENCH_EVENTS_JSONL", str(tmp_path / "none.jsonl"))
+
+
+def test_engine_never_alerts_on_alert_events(no_bench_history):
     eng = AlertEngine(default_rules())
     assert eng.observe({"ft_event": "alert", "alert": "hang",
                         "rule": "hang", "t": 1.0, "process": 0}) == []
@@ -256,7 +264,7 @@ def test_bench_stale_rule(tmp_path):
     assert eng2.check_bench() == []
 
 
-def test_evaluate_stream_one_shot():
+def test_evaluate_stream_one_shot(no_bench_history):
     now = time.time()
     recs = ([step_rec(i) for i in range(5)]
             + [{"ft_event": "hang", "step": 5, "t": now, "process": 0}])

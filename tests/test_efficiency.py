@@ -142,7 +142,9 @@ def test_device_peak_flops_table_and_override(monkeypatch):
 
     assert device_peak_flops(FakeDev("TPU v5e")) == 197e12
     assert device_peak_flops(FakeDev("TPU v4")) == 275e12
-    assert device_peak_flops(FakeDev("weird accelerator")) == CPU_FALLBACK_PEAK
+    assert device_peak_flops(FakeDev("cpu")) == CPU_FALLBACK_PEAK
+    with pytest.raises(ValueError, match="weird accelerator"):
+        device_peak_flops(FakeDev("weird accelerator"))
     monkeypatch.setenv("PTD_TPU_PEAK_FLOPS", "123e9")
     assert device_peak_flops(FakeDev("TPU v4")) == 123e9
 
@@ -331,10 +333,11 @@ def test_benchlib_bench_event_and_report_fold(tmp_path, monkeypatch):
     text = "\n".join(lines)
     assert "== bench ==" in text and "stale" in text
     assert "last good 2026-07-31" in text and "hung" in text
-    # unwritable path: best-effort, never raises (bench emission survives)
+    # unwritable path: on the benchmark path a failure is a failure
     monkeypatch.setenv("BENCH_EVENTS_JSONL",
                        str(tmp_path / "no" / "such" / "dir" / "x.jsonl"))
-    benchlib.bench_event("stale", reason="r")
+    with pytest.raises(OSError):
+        benchlib.bench_event("stale", reason="r")
 
 
 # ------------------------------------------------------- obs_report diff fence

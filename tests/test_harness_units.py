@@ -119,8 +119,8 @@ def test_telemetry_reports_real_bytes_without_memory_stats(tmp_path):
 
 def test_measure_train_step_and_oom_heuristic():
     """Shared bench harness (utils/benchstep.py): measures a real compiled
-    step with the value-fetch barrier; the OOM heuristic separates
-    capacity failures (halve and retry) from deterministic ones."""
+    step; the OOM heuristic separates capacity failures (halve and retry)
+    from deterministic ones."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -156,3 +156,36 @@ def test_measure_train_step_and_oom_heuristic():
     assert looks_like_oom(RuntimeError("RESOURCE_EXHAUSTED: ..."))
     assert looks_like_oom(MemoryError("Out of memory allocating 1GB"))
     assert not looks_like_oom(ValueError("unknown arch 'resnet999'"))
+
+
+def test_measure_train_step_barrier_is_block_until_ready():
+    """The clock stops on ``block_until_ready`` of the step's outputs —
+    the device barrier — and the loss value is fetched only after it."""
+    from pytorch_distributed_tpu.utils.benchstep import measure_train_step
+
+    log = []
+
+    class Loss:
+        def __init__(self, value):
+            self.value = value
+
+        def block_until_ready(self):
+            log.append("block")
+            return self
+
+        def __float__(self):
+            log.append("fetch")
+            return self.value
+
+    def step(state, batch, lr):
+        log.append("step")
+        return state + 1, {"loss": Loss(1.0)}
+
+    dt, state = measure_train_step(step, 0, None, 0.1, iters=2, warmup=1)
+    assert dt > 0 and state == 3
+    assert log == ["step", "block", "step", "step", "block", "fetch"]
+
+    with pytest.raises(FloatingPointError):
+        measure_train_step(
+            lambda s, b, lr: (s, {"loss": Loss(float("nan"))}), 0, None, 0.1,
+            iters=1, warmup=0)

@@ -355,91 +355,40 @@ def test_predicted_mfu_and_best_plan():
     assert best is not None and best.chips == 4 and best.key() == "c4/dp4"
 
 
-# ------------------------------------------- persistent-cache guard
+# ------------------------------------------- compile cache placement
 
-def test_jaxlib_version_guard():
-    from pytorch_distributed_tpu.analysis import lowering
+@pytest.fixture
+def cache_dir_config():
+    """Restore jax's compilation-cache directory after the test."""
+    import jax
 
-    assert lowering.jaxlib_version_tuple("0.4.36") == (0, 4, 36)
-    assert lowering.jaxlib_version_tuple("0.5.0") == (0, 5, 0)
-    assert lowering.persistent_cache_known_bad("0.4.36")
-    assert lowering.persistent_cache_known_bad("0.4.37")
-    assert not lowering.persistent_cache_known_bad("0.5.0")
-    assert not lowering.persistent_cache_known_bad("0.6.2")
-
-
-def test_maybe_enable_short_circuits_on_known_bad(monkeypatch):
-    from pytorch_distributed_tpu.analysis import lowering
-
-    if not lowering.persistent_cache_known_bad():
-        pytest.skip("jaxlib here is outside the known-bad range")
-    monkeypatch.delenv("PTD_PERSISTENT_CACHE", raising=False)
-    verdict = lowering.maybe_enable_persistent_cache()
-    assert verdict["enabled"] is False
-    assert "known-bad" in verdict["reason"]
+    before = jax.config.jax_compilation_cache_dir
+    yield jax.config
+    jax.config.update("jax_compilation_cache_dir", before)
 
 
-def test_maybe_enable_force_disable(monkeypatch):
-    from pytorch_distributed_tpu.analysis import lowering
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path,
+                                           cache_dir_config):
+    """$JAX_COMPILATION_CACHE_DIR set: that directory is reported and no
+    directory is set in code (jax reads the variable by itself)."""
+    from pytorch_distributed_tpu.utils import compile_cache
 
-    monkeypatch.setenv("PTD_PERSISTENT_CACHE", "0")
-    verdict = lowering.maybe_enable_persistent_cache()
-    assert verdict["enabled"] is False and "PTD_PERSISTENT_CACHE=0" in (
-        verdict["reason"])
-
-
-def test_gate_verdict_logged_once_per_session(monkeypatch, capsys):
-    from pytorch_distributed_tpu.analysis import lowering
-
-    monkeypatch.setenv("PTD_PERSISTENT_CACHE", "0")
-    monkeypatch.setattr(lowering, "_GATE_VERDICT_LOGGED", False)
-    lowering.maybe_enable_persistent_cache()
-    err = capsys.readouterr().err
-    ver = ".".join(map(str, lowering.jaxlib_version_tuple()))
-    assert "[lowering] persistent compilation cache disabled" in err
-    assert f"jaxlib {ver}" in err
-    assert "PTD_PERSISTENT_CACHE=0" in err
-    # second call in the same session: the verdict line must not repeat
-    lowering.maybe_enable_persistent_cache()
-    assert capsys.readouterr().err == ""
+    cache_dir_config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert cache_dir_config.jax_compilation_cache_dir is None
 
 
-class _FakeRun:
-    def __init__(self, returncode, stdout):
-        self.returncode = returncode
-        self.stdout = stdout
+def test_compile_cache_default_is_fixed_path_in_checkout(monkeypatch,
+                                                         cache_dir_config):
+    from pytorch_distributed_tpu.utils import compile_cache
 
-
-def test_selfcheck_roundtrip_and_memo(tmp_path):
-    from pytorch_distributed_tpu.analysis import lowering
-
-    cache = str(tmp_path / "jaxcache")
-    calls = []
-
-    def good_runner():
-        calls.append(1)
-        return _FakeRun(0, "129.0\n")
-
-    assert lowering.persistent_cache_selfcheck(cache, _runner=good_runner)
-    assert len(calls) == 2  # populate + warm
-    assert os.path.exists(os.path.join(cache, "selfcheck.json"))
-
-    def must_not_run():
-        raise AssertionError("self-check verdict must be memoized")
-
-    assert lowering.persistent_cache_selfcheck(cache, _runner=must_not_run)
-
-
-def test_selfcheck_fails_on_crash_and_mismatch(tmp_path):
-    from pytorch_distributed_tpu.analysis import lowering
-
-    crash = str(tmp_path / "crash")
-    assert not lowering.persistent_cache_selfcheck(
-        crash, _runner=lambda: _FakeRun(134, ""))
-    outs = iter(["1.0\n", "2.0\n"])
-    drift = str(tmp_path / "drift")
-    assert not lowering.persistent_cache_selfcheck(
-        drift, _runner=lambda: _FakeRun(0, next(outs)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert compile_cache.enable_compile_cache() == want  # never moves
+    assert cache_dir_config.jax_compilation_cache_dir == want
 
 
 # ------------------------------------------- shared sweep + validation
