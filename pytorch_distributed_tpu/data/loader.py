@@ -27,6 +27,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pytorch_distributed_tpu.data.sampler import DistributedShardSampler
+from pytorch_distributed_tpu.obs.trace import span
 
 Batch = Dict[str, np.ndarray]
 
@@ -102,6 +103,12 @@ class DataLoader:
                 return self.dataset.get(int(index), rng)
             return self.dataset[int(index)]
         return None  # padding slot
+
+    def _fetch_timed(self, index: int, valid: int):
+        """``_fetch`` and the seconds it took on its worker thread."""
+        t = time.perf_counter()
+        sample = self._fetch(index, valid)
+        return sample, time.perf_counter() - t
 
     def _assemble_native(self, samples):
         """Batch the ("jpeg", blob, params, label) / ("u8", arr, None, label)
@@ -237,11 +244,36 @@ class DataLoader:
         if self.worker_type == "process":
             yield from self._iter_process(indices, valid, nb, start)
             return
-        with ThreadPoolExecutor(max_workers=self.num_workers) as pool:
+        # Each worker thread's CPU clock, added by the thread as it starts
+        # and read from here: of the seconds the workers spent in samples
+        # (``sample_wall_s``), ``sample_cpu_s`` is what they ran; the rest
+        # they waited (GIL, I/O).  Read per batch and not per sample: a
+        # thread-CPU clock read is a system call of 6 us under the
+        # interpreter lock on the benchmark's host, and 512 of them a batch
+        # cost the fed cell 1.5% (PERF.md, PR 24).
+        clocks: list = []
+
+        def note_worker():
+            clocks.append(time.pthread_getcpuclockid(threading.get_ident()))
+
+        def workers_cpu_s() -> float:
+            return sum(time.clock_gettime(c) for c in clocks)
+
+        with ThreadPoolExecutor(max_workers=self.num_workers,
+                                initializer=note_worker) as pool:
             for b in range(start, nb):
                 idx, val = self._batch_indices(indices, valid, b)
-                samples = list(pool.map(self._fetch, idx, val))
-                yield self._assemble(b, val, samples)
+                # spans close before the yield (obs/trace.py: the stack of
+                # open spans is the thread's, not the generator's)
+                with span("fetch", id=b - start) as fetch:
+                    cpu = workers_cpu_s()
+                    timed = list(pool.map(self._fetch_timed, idx, val))
+                    fetch.set(samples=len(timed),
+                              sample_wall_s=sum(t[1] for t in timed),
+                              sample_cpu_s=workers_cpu_s() - cpu)
+                with span("assemble", id=b - start):
+                    batch = self._assemble(b, val, [t[0] for t in timed])
+                yield batch
 
     def _ensure_pool(self):
         """The spawn pool persists across epochs (advisor r3: a per-__iter__
@@ -318,11 +350,15 @@ class DataLoader:
             bounds = [(len(args) * w // W, len(args) * (w + 1) // W)
                       for w in range(W)]
             chunks = [args[lo:hi] for lo, hi in bounds if hi > lo]
-            samples = [
-                s for chunk in pool.map(_process_fetch_chunk, chunks)
-                for s in chunk
-            ]
-            yield self._assemble(b, val, samples)
+            # the samples are timed in other processes: no counts here
+            with span("fetch", id=b - start):
+                samples = [
+                    s for chunk in pool.map(_process_fetch_chunk, chunks)
+                    for s in chunk
+                ]
+            with span("assemble", id=b - start):
+                batch = self._assemble(b, val, samples)
+            yield batch
 
 
 _LIVE_POOLS: list = []
@@ -392,29 +428,19 @@ class AsyncFeeder:
     replaces the apex CUDA-stream ``data_prefetcher``
     (reference apex_distributed.py:115-169).
 
-    Wait accounting (obs/stepattr.py's data_wait component, ISSUE 20):
-    the feeder times how long the *consumer* sat blocked on an empty
-    queue — ``wait_ms_last`` / ``wait_ms_ema`` read as "the producer
-    couldn't keep up by this much".  Zero when prefetch hides the host
-    work entirely; the number an input-starved rank shows in its
-    heartbeats.
+    Both threads are on ``obs/trace.py``'s spans, every one with the
+    batch's ordinal as ``id``: the consumer's ``data_wait`` is the time it
+    sat blocked on an empty queue ("the producer couldn't keep up by this
+    much"; zero when prefetch hides the host work); one ``produce`` per
+    turn of the producer holds ``host_batch`` (the ``next()`` on the host
+    iterator), whatever ``put`` records, and ``queue_full`` (blocked on a
+    queue the consumer has not emptied: zero when the producer sets the
+    pace).
     """
-
-    _EMA_ALPHA = 0.1
 
     def __init__(self, put, prefetch: int = 2):
         self.put = put
         self.prefetch = max(1, prefetch)
-        self.wait_ms_last = 0.0
-        self.wait_ms_ema: Optional[float] = None
-
-    def _note_wait(self, waited_s: float) -> None:
-        self.wait_ms_last = waited_s * 1e3
-        if self.wait_ms_ema is None:
-            self.wait_ms_ema = self.wait_ms_last
-        else:
-            self.wait_ms_ema += self._EMA_ALPHA * (
-                self.wait_ms_last - self.wait_ms_ema)
 
     def __call__(self, host_iter) -> Iterator:
         q: "queue.Queue" = queue.Queue(maxsize=self.prefetch)
@@ -438,9 +464,22 @@ class AsyncFeeder:
             # Exceptions must surface at the consumer, not die in the thread —
             # otherwise a bad batch silently truncates the epoch.
             try:
-                for batch in host_iter:
-                    if dead.is_set() or not offer(self.put(batch)):
-                        return
+                batches = iter(host_iter)
+                n = 0
+                while True:
+                    with span("produce", id=n):
+                        with span("host_batch"):
+                            batch = next(batches, stop)
+                        if batch is stop:
+                            break
+                        if dead.is_set():
+                            return
+                        item = self.put(batch)
+                        with span("queue_full"):
+                            taken = offer(item)
+                        if not taken:
+                            return
+                    n += 1
                 offer(stop)
             except BaseException as e:  # noqa: BLE001 — re-raised at consumer
                 offer(e)
@@ -448,15 +487,16 @@ class AsyncFeeder:
         t = threading.Thread(target=producer, daemon=True)
         t.start()
         try:
+            n = 0
             while True:
-                t0 = time.perf_counter()
-                item = q.get()
-                self._note_wait(time.perf_counter() - t0)
+                with span("data_wait", id=n):
+                    item = q.get()
                 if item is stop:
                     break
                 if isinstance(item, BaseException):
                     raise item
                 yield item
+                n += 1
         finally:
             dead.set()
             t.join(timeout=5.0)
@@ -487,37 +527,38 @@ class DeviceFeeder:
         }
 
     def _put(self, batch: Batch) -> Dict[str, jax.Array]:
-        n_shards = self.mesh.shape[self.data_axis]
-        bsz = next(iter(batch.values())).shape[0] * jax.process_count()
-        if bsz % n_shards:
-            raise ValueError(
-                f"global batch {bsz} must divide the '{self.data_axis}' mesh "
-                f"axis ({n_shards} shards); pick a per-process batch that is a "
-                f"multiple of {n_shards // jax.process_count() or 1}"
-            )
-        sh = self._shardings()
-        out = {
-            k: jax.make_array_from_process_local_data(sh[k], v)
-            for k, v in batch.items()
-        }
-        if out["images"].dtype == jnp.uint8:
-            # u8_wire mode: the batch crossed the wire as uint8; normalize on
-            # device (fused by XLA; replaces the apex GPU-side sub_/div_,
-            # reference apex_distributed.py:123-158 — minus its
-            # double-normalize quirk, SURVEY.md §7.5).
-            if self._dev_norm is None:
-                from pytorch_distributed_tpu.data.transforms import (
-                    IMAGENET_MEAN,
-                    IMAGENET_STD,
+        with span("put"):  # staging the copies, dispatching the normalisation
+            n_shards = self.mesh.shape[self.data_axis]
+            bsz = next(iter(batch.values())).shape[0] * jax.process_count()
+            if bsz % n_shards:
+                raise ValueError(
+                    f"global batch {bsz} must divide the '{self.data_axis}' mesh "
+                    f"axis ({n_shards} shards); pick a per-process batch that is a "
+                    f"multiple of {n_shards // jax.process_count() or 1}"
                 )
+            sh = self._shardings()
+            out = {
+                k: jax.make_array_from_process_local_data(sh[k], v)
+                for k, v in batch.items()
+            }
+            if out["images"].dtype == jnp.uint8:
+                # u8_wire mode: the batch crossed the wire as uint8; normalize on
+                # device (fused by XLA; replaces the apex GPU-side sub_/div_,
+                # reference apex_distributed.py:123-158 — minus its
+                # double-normalize quirk, SURVEY.md §7.5).
+                if self._dev_norm is None:
+                    from pytorch_distributed_tpu.data.transforms import (
+                        IMAGENET_MEAN,
+                        IMAGENET_STD,
+                    )
 
-                mean = jnp.asarray(IMAGENET_MEAN)
-                std = jnp.asarray(IMAGENET_STD)
-                self._dev_norm = jax.jit(
-                    lambda x: (x.astype(jnp.float32) / 255.0 - mean) / std
-                )
-            out["images"] = self._dev_norm(out["images"])
-        return out
+                    mean = jnp.asarray(IMAGENET_MEAN)
+                    std = jnp.asarray(IMAGENET_STD)
+                    self._dev_norm = jax.jit(
+                        lambda x: (x.astype(jnp.float32) / 255.0 - mean) / std
+                    )
+                out["images"] = self._dev_norm(out["images"])
+            return out
 
     def __call__(self, host_iter) -> Iterator[Dict[str, jax.Array]]:
         return AsyncFeeder(self._put, self.prefetch)(host_iter)

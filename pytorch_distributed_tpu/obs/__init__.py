@@ -5,8 +5,11 @@ is three ``.item()`` calls per batch plus a 500 ms nvidia-smi CSV).
   (step-time EMA/percentiles, throughput, loss/lr, in-graph grad/param
   norms), with lazy device-scalar conversion and sink registration so the
   epoch CSV and telemetry sampler hang off one entry point.
-- ``trace``     — ``scope()``/``ProfileWindow``: TraceAnnotation +
-  named_scope under one idiom, and epoch/step-windowed profiler capture.
+- ``trace``     — ``span()``/``RECORDER``: host spans of the run loop, the
+  feeder and the loader, kept in a bounded in-memory ring and mirrored as
+  ``ptd:<name>`` TraceAnnotations; ``scope()``: TraceAnnotation +
+  named_scope for in-graph names; ``ProfileWindow``: epoch/step-windowed
+  profiler capture.
 - ``heartbeat`` — per-process ``{pid, step, t, ema, last_ft}`` beats to a
   shared run directory + cross-process straggler detection that tells
   *slow* ranks from *dead* ones (stdlib-only monitor).
@@ -125,11 +128,12 @@ from pytorch_distributed_tpu.obs.metrics import (
     read_metrics,
 )
 from pytorch_distributed_tpu.obs.trace import (
+    RECORDER,
     ProfileWindow,
-    annotate,
     capture,
     parse_span,
     scope,
+    span,
 )
 from pytorch_distributed_tpu.obs.watchdog import RecompileWatchdog
 
@@ -142,7 +146,8 @@ __all__ = [
     "find_stragglers",
     "sample_process_memory",
     "scope",
-    "annotate",
+    "span",
+    "RECORDER",
     "capture",
     "parse_span",
     "ProfileWindow",

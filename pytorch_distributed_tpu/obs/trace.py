@@ -1,4 +1,12 @@
-"""Trace annotation helpers: one idiom for host spans and in-graph names.
+"""Tracing: host spans kept in the process, in-graph names, profiler windows.
+
+``span(name)`` times a piece of **host** code: one record in the
+process-wide ring ``RECORDER`` on ``time.perf_counter``, and a
+``TraceAnnotation`` named ``ptd:<name>`` so that inside any profiler capture
+the same span lies on the capture's clock beside the device's operations.
+It is always on and costs a tuple and a ``deque.append``; the run loop
+(``train/trainer.py``), the feeder and the loader (``data/loader.py``) are
+on it, and ``benchmark/program_spans.py`` reads it.
 
 ``scope(name)`` composes ``jax.profiler.TraceAnnotation`` (a host-side
 XPlane span around whatever runs inside the ``with``) with
@@ -20,10 +28,137 @@ exactly one start/stop_trace call site outside the trainers.
 
 from __future__ import annotations
 
+import collections
 import contextlib
-from typing import Optional, Tuple
+import itertools
+import json
+import threading
+import time
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
+
+SPAN_PREFIX = "ptd:"  # a span's name inside a profiler capture
+
+
+class Span(NamedTuple):
+    """One closed ``span()``; ``start``/``end`` are ``time.perf_counter``."""
+
+    serial: int             # process-wide, in order of entry
+    name: str
+    start: float
+    end: float
+    thread: int             # threading.get_ident() of the thread it ran on
+    id: Optional[int]       # the batch's ordinal since its iterator was made
+    parent: Optional[int]   # serial of the span open on this thread at entry
+    fields: Dict[str, Any]  # counts the span carries (``fetch``)
+
+
+class SpanRecorder:
+    """A bounded ring of closed spans.  Appending and snapshotting are each
+    one C call under the GIL: no lock, no I/O, no device sync.
+
+    ``enabled = False`` makes ``span()`` hand back one shared no-op (for
+    tests and A/B runs; there is no flag and no environment variable)."""
+
+    def __init__(self, maxlen: int = 32768):
+        self.enabled = True
+        self._ring: "collections.deque[Span]" = collections.deque(
+            maxlen=maxlen)
+        self._serial = itertools.count(1)
+        self._open = threading.local()  # .stack: [(serial, id)] per thread
+
+    def _stack(self) -> list:
+        try:
+            return self._open.stack
+        except AttributeError:
+            self._open.stack = []
+            return self._open.stack
+
+    def records(self, t0: Optional[float] = None,
+                t1: Optional[float] = None) -> List[Span]:
+        """The closed spans that overlap ``[t0, t1]``, oldest first."""
+        return [r for r in tuple(self._ring)
+                if (t1 is None or r.start <= t1)
+                and (t0 is None or r.end >= t0)]
+
+    def clear(self) -> None:
+        self._ring.clear()
+
+    def dump(self, path: str) -> int:
+        """Write every record as one JSON object a line; returns the count."""
+        records = self.records()
+        with open(path, "w") as f:
+            for r in records:
+                f.write(json.dumps(r._asdict(), default=float) + "\n")
+        return len(records)
+
+
+RECORDER = SpanRecorder()
+
+
+class _NoSpan:
+    """What ``span()`` hands back while the recorder is off."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **fields) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _OpenSpan:
+    __slots__ = ("_name", "_id", "_fields", "_serial", "_parent", "_note",
+                 "_t0")
+
+    def __init__(self, name: str, id: Optional[int], fields: Dict[str, Any]):
+        self._name, self._id, self._fields = name, id, fields
+
+    def set(self, **fields) -> None:
+        """Counts known only once the work is done."""
+        self._fields.update(fields)
+
+    def __enter__(self):
+        stack = RECORDER._stack()
+        self._parent = None
+        if stack:
+            self._parent, inherited = stack[-1]
+            if self._id is None:
+                self._id = inherited
+        self._serial = next(RECORDER._serial)
+        stack.append((self._serial, self._id))
+        self._note = jax.profiler.TraceAnnotation(SPAN_PREFIX + self._name)
+        self._note.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._note.__exit__(*exc)
+        RECORDER._stack().pop()
+        RECORDER._ring.append(Span(
+            self._serial, self._name, self._t0, t1, threading.get_ident(),
+            self._id, self._parent, self._fields))
+        return False
+
+
+def span(name: str, id: Optional[int] = None, **fields):
+    """Time a piece of host code: ``with span("put"): ...``.
+
+    ``id`` says which batch the work is for; left out, it is the enclosing
+    span's.  ``parent`` comes from a per-thread stack, so a span must close
+    before a generator that opened it yields."""
+    if not RECORDER.enabled:
+        return _NO_SPAN
+    return _OpenSpan(name, id, fields)
 
 
 @contextlib.contextmanager
@@ -47,22 +182,6 @@ def capture(trace_dir: str):
         yield trace_dir
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Decorator form of ``scope`` for whole functions."""
-
-    def wrap(fn):
-        import functools
-
-        @functools.wraps(fn)
-        def inner(*a, **kw):
-            with scope(name):
-                return fn(*a, **kw)
-
-        return inner
-
-    return wrap
 
 
 def parse_span(spec: Optional[str]) -> Optional[Tuple[int, int]]:

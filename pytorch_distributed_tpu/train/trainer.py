@@ -9,6 +9,7 @@ diversity (SURVEY.md §7.1).
 
 from __future__ import annotations
 
+import os
 import time
 from contextlib import nullcontext
 from typing import Iterator, Optional, Tuple
@@ -28,11 +29,13 @@ from pytorch_distributed_tpu.data import (
 )
 from pytorch_distributed_tpu.data.transforms import eval_transform, train_transform
 from pytorch_distributed_tpu.obs import (
+    RECORDER,
     HeartbeatWriter,
     MetricsLogger,
     ProfileWindow,
     sample_process_memory,
     scope,
+    span,
 )
 from pytorch_distributed_tpu.parallel import DistContext, data_parallel_mesh
 from pytorch_distributed_tpu.train.checkpoint import load_checkpoint, save_checkpoint
@@ -611,8 +614,6 @@ class Trainer:
         weights).  TPU pods have no network egress, so weights come from a
         local directory: ``$PTD_TPU_PRETRAINED_DIR/<arch>.msgpack`` — any
         checkpoint this framework saved for the same arch."""
-        import os
-
         d = os.environ.get("PTD_TPU_PRETRAINED_DIR", "pretrained")
         path = os.path.join(d, f"{self.cfg.arch}.msgpack")
         if not os.path.exists(path):
@@ -893,147 +894,156 @@ class Trainer:
         epoch_len = len(self.train_loader)
         batch_iter = self.feeder(self.train_loader.iter_batches(start_step))
         i = start_step
+        taken = 0  # batches taken from batch_iter: what the spans call id
         while i < epoch_len:
-            if profiler is not None:
-                profiler.step_begin(epoch, i)
-            # Polled at print_freq cadence so the agreement collective (a
-            # tiny any-rank-flagged all-reduce every rank runs at the same
-            # step — signal skew across hosts must not break ranks at
-            # different boundaries) stays off the per-step hot path.
-            if (self.preempt is not None and i % cfg.print_freq == 0
-                    and self._preempt_agreed()):
-                return completed, True
-            if self.chaos is not None:
-                self.chaos.on_step(self, i)
-            if self.elastic is not None:
-                # Membership epochs are committed by the coordinator and
-                # read by every rank at the same step — an agreed value,
-                # not a local probe (synclint would otherwise flag the
-                # re-mesh below as a rank-divergent collective path).
-                chg = self.elastic.poll(self._global_step)  # synclint: agreement
-                if chg is not None:
-                    # Membership changed: rebuild against the survivor set
-                    # and rewind to the snapshot step (the sampler's
-                    # (seed, epoch) permutation regenerates the identical
-                    # index stream, so replayed steps see the same data).
-                    batch_iter.close()
-                    resume_global = self._apply_remesh(chg, epoch)
-                    self._global_step = resume_global
-                    completed = i = max(0, resume_global - epoch_base)
-                    epoch_len = len(self.train_loader)  # batch rescale
-                    batch_iter = self.feeder(
-                        self.train_loader.iter_batches(i))
-                    lr_arr = jnp.float32(
-                        lr * scale * self._elastic_lr_scale)
-                    meters.restart_clock()
-                    continue
-            # Attribution windows (--step-attr): data_wait wraps batch
-            # acquisition *and* the chaos on_batch hook, so an injected
-            # loader delay (chaoskit drill slow-loader) lands in the
-            # measured component by design.
-            sa = self.stepattr
-            _dw = sa.data_wait if sa is not None else nullcontext
-            with _dw():
-                batch = next(batch_iter, None)
-            if batch is None:
-                break
-            if self.chaos is not None:
+            # One `step` span an iteration (obs/trace.py); its children are
+            # the feeder's `data_wait`, `dispatch` and the `host_sync`
+            # drains, so its self time is the loop's own overhead: the
+            # polls and hooks below, and waiting for the GIL between them.
+            with span("step", id=taken):
+                if profiler is not None:
+                    profiler.step_begin(epoch, i)
+                # Polled at print_freq cadence so the agreement collective (a
+                # tiny any-rank-flagged all-reduce every rank runs at the same
+                # step — signal skew across hosts must not break ranks at
+                # different boundaries) stays off the per-step hot path.
+                if (self.preempt is not None and i % cfg.print_freq == 0
+                        and self._preempt_agreed()):
+                    return completed, True
+                if self.chaos is not None:
+                    self.chaos.on_step(self, i)
+                if self.elastic is not None:
+                    # Membership epochs are committed by the coordinator and
+                    # read by every rank at the same step — an agreed value,
+                    # not a local probe (synclint would otherwise flag the
+                    # re-mesh below as a rank-divergent collective path).
+                    chg = self.elastic.poll(self._global_step)  # synclint: agreement
+                    if chg is not None:
+                        # Membership changed: rebuild against the survivor set
+                        # and rewind to the snapshot step (the sampler's
+                        # (seed, epoch) permutation regenerates the identical
+                        # index stream, so replayed steps see the same data).
+                        batch_iter.close()
+                        resume_global = self._apply_remesh(chg, epoch)
+                        self._global_step = resume_global
+                        completed = i = max(0, resume_global - epoch_base)
+                        epoch_len = len(self.train_loader)  # batch rescale
+                        batch_iter = self.feeder(
+                            self.train_loader.iter_batches(i))
+                        taken = 0
+                        lr_arr = jnp.float32(
+                            lr * scale * self._elastic_lr_scale)
+                        meters.restart_clock()
+                        continue
+                # Attribution windows (--step-attr): data_wait wraps batch
+                # acquisition *and* the chaos on_batch hook, so an injected
+                # loader delay (chaoskit drill slow-loader) lands in the
+                # measured component by design.
+                sa = self.stepattr
+                _dw = sa.data_wait if sa is not None else nullcontext
                 with _dw():
-                    batch = self.chaos.on_batch(i, batch)
-            n = self.cfg.batch_size
-            if ((getattr(cfg, "comm_ledger", None)
-                    or getattr(cfg, "mem_ledger", None))
-                    and self._comm_fields is None):
-                self._emit_ledgers(batch, lr_arr)
-            if self.flight is not None:
-                # Ring: step window + collective region (labelled with the
-                # ledger's dominant entry when the AOT lowering ran) —
-                # two deque appends, no sync/I/O.
-                self.flight.step_begin(self._global_step)
-                fc = self._flight_coll or {}
-                self.flight.coll_enter(self._global_step,
-                                       kind=fc.get("kind"),
-                                       bytes=fc.get("bytes"),
-                                       name=fc.get("name"))
-            if self.chaos is not None:
-                self.chaos.on_collective(self, self._global_step)
-            _dev = sa.device if sa is not None else nullcontext
-            _hs = sa.host_sync if sa is not None else nullcontext
-            with scope("train_step"), self._wd_watch("train_step",
-                                                     self._global_step), \
-                    _dev():
-                self.state, metrics = self.train_step(self.state, batch, lr_arr)
-                if sa is not None:
-                    # The step's blocking transfer: without it, async
-                    # dispatch smears step N's device time into N+1's
-                    # windows and the identity stops meaning anything.
-                    # Only when --step-attr opted in; overhead fenced
-                    # <2% p50 in RESULTS_stepattr.json.
-                    jax.block_until_ready(metrics)  # shardlint: allow-sync
-            if self.flight is not None:
-                self.flight.coll_exit(self._global_step)
-                self.flight.step_end(self._global_step)
-            completed = i + 1
-            # Unready device scalars: meters and the metrics logger convert
-            # lazily, so no per-step host sync (SURVEY.md §7.4 item 1).
-            with _hs():
-                dt = meters.update(metrics, n)
-            extra = {"epoch": epoch}
-            if self._mfu is not None:
-                extra.update(self._mfu.fields(dt))
-            if self._comm_fields:
-                extra.update(self._comm_fields)
-            if sa is not None:
-                extra.update(sa.fields(dt))
-            # The lazy-flush scalar drain inside log_step accrues to the
-            # *next* step's host_sync window (its dt covers this wall
-            # time), keeping the identity aligned.
-            with _hs():
-                self.obs.log_step(
-                    self._global_step, step_time=dt, n_items=n, lr=lr,
-                    scalars=dict(metrics),  # incl. norms when --metrics-jsonl
-                    extra=extra,
-                )
-            # booked after the first step's record so the event's
-            # timestamp cannot widen the post-hoc goodput wall span back
-            # across the step-0 compile
-            if sa is not None and not self._stepattr_phases_booked:
-                self._book_stepattr_phases()
-            if self.hb is not None:
-                self.hb.beat(self._global_step, step_time_ema=self.obs.ema,
-                             last_ft=self.obs.last_event_kind,
-                             mem_bytes=sample_process_memory(),
-                             data_wait_ms=(sa.data_wait_ema_ms
-                                           if sa is not None else None))
+                    batch = next(batch_iter, None)
+                if batch is None:
+                    break
+                taken += 1
+                if self.chaos is not None:
+                    with _dw():
+                        batch = self.chaos.on_batch(i, batch)
+                n = self.cfg.batch_size
+                if ((getattr(cfg, "comm_ledger", None)
+                        or getattr(cfg, "mem_ledger", None))
+                        and self._comm_fields is None):
+                    self._emit_ledgers(batch, lr_arr)
                 if self.flight is not None:
-                    self.flight.heartbeat(
-                        {"step": self._global_step,
-                         "last_ft": self.obs.last_event_kind})
-            self._global_step += 1
-            meters.maybe_display(i, cfg.print_freq)
-            at_save = (cfg.save_steps > 0 and completed % cfg.save_steps == 0
-                       and completed < len(self.train_loader))
-            if self.ft_guard is not None:
-                # Flags buffer unconverted; drained every ft_check_every
-                # steps (one amortized host sync) — forced before a
-                # snapshot so it never races an undetected divergence.
-                rollback = self.ft_guard.observe(
-                    self._global_step - 1, metrics.get("nonfinite"))
+                    # Ring: step window + collective region (labelled with the
+                    # ledger's dominant entry when the AOT lowering ran) —
+                    # two deque appends, no sync/I/O.
+                    self.flight.step_begin(self._global_step)
+                    fc = self._flight_coll or {}
+                    self.flight.coll_enter(self._global_step,
+                                           kind=fc.get("kind"),
+                                           bytes=fc.get("bytes"),
+                                           name=fc.get("name"))
+                if self.chaos is not None:
+                    self.chaos.on_collective(self, self._global_step)
+                _dev = sa.device if sa is not None else nullcontext
+                _hs = sa.host_sync if sa is not None else nullcontext
+                with span("dispatch"), scope("train_step"), \
+                        self._wd_watch("train_step", self._global_step), \
+                        _dev():
+                    self.state, metrics = self.train_step(self.state, batch, lr_arr)
+                    if sa is not None:
+                        # The step's blocking transfer: without it, async
+                        # dispatch smears step N's device time into N+1's
+                        # windows and the identity stops meaning anything.
+                        # Only when --step-attr opted in; overhead fenced
+                        # <2% p50 in RESULTS_stepattr.json.
+                        jax.block_until_ready(metrics)  # shardlint: allow-sync
+                if self.flight is not None:
+                    self.flight.coll_exit(self._global_step)
+                    self.flight.step_end(self._global_step)
+                completed = i + 1
+                # Unready device scalars: meters and the metrics logger convert
+                # lazily, so no per-step host sync (SURVEY.md §7.4 item 1).
+                with span("host_sync"), _hs():
+                    dt = meters.update(metrics, n)
+                extra = {"epoch": epoch}
+                if self._mfu is not None:
+                    extra.update(self._mfu.fields(dt))
+                if self._comm_fields:
+                    extra.update(self._comm_fields)
+                if sa is not None:
+                    extra.update(sa.fields(dt))
+                # The lazy-flush scalar drain inside log_step accrues to the
+                # *next* step's host_sync window (its dt covers this wall
+                # time), keeping the identity aligned.
+                with span("host_sync"), _hs():
+                    self.obs.log_step(
+                        self._global_step, step_time=dt, n_items=n, lr=lr,
+                        scalars=dict(metrics),  # incl. norms when --metrics-jsonl
+                        extra=extra,
+                    )
+                # booked after the first step's record so the event's
+                # timestamp cannot widen the post-hoc goodput wall span back
+                # across the step-0 compile
+                if sa is not None and not self._stepattr_phases_booked:
+                    self._book_stepattr_phases()
+                if self.hb is not None:
+                    self.hb.beat(self._global_step, step_time_ema=self.obs.ema,
+                                 last_ft=self.obs.last_event_kind,
+                                 mem_bytes=sample_process_memory(),
+                                 data_wait_ms=(sa.data_wait_ema_ms
+                                               if sa is not None else None))
+                    if self.flight is not None:
+                        self.flight.heartbeat(
+                            {"step": self._global_step,
+                             "last_ft": self.obs.last_event_kind})
+                self._global_step += 1
+                with span("host_sync"):
+                    meters.maybe_display(i, cfg.print_freq)
+                at_save = (cfg.save_steps > 0 and completed % cfg.save_steps == 0
+                           and completed < len(self.train_loader))
+                if self.ft_guard is not None:
+                    # Flags buffer unconverted; drained every ft_check_every
+                    # steps (one amortized host sync) — forced before a
+                    # snapshot so it never races an undetected divergence.
+                    rollback = self.ft_guard.observe(
+                        self._global_step - 1, metrics.get("nonfinite"))
+                    if at_save:
+                        # The drained flag is the in-step all-reduced nonfinite
+                        # count: every rank drains the identical value, so the
+                        # rollback decision below is bulk-synchronous.
+                        rollback = self.ft_guard.drain() or rollback  # synclint: agreement
+                    if rollback:
+                        lr_arr = jnp.float32(lr * self._rollback(epoch, i)
+                                             * self._elastic_lr_scale)
+                    # A flagged streak means the current state is suspect —
+                    # don't refresh the last-good snapshot/checkpoint from it.
+                    at_save = at_save and self.ft_guard.consecutive == 0
                 if at_save:
-                    # The drained flag is the in-step all-reduced nonfinite
-                    # count: every rank drains the identical value, so the
-                    # rollback decision below is bulk-synchronous.
-                    rollback = self.ft_guard.drain() or rollback  # synclint: agreement
-                if rollback:
-                    lr_arr = jnp.float32(lr * self._rollback(epoch, i)
-                                         * self._elastic_lr_scale)
-                # A flagged streak means the current state is suspect —
-                # don't refresh the last-good snapshot/checkpoint from it.
-                at_save = at_save and self.ft_guard.consecutive == 0
-            if at_save:
-                self._save_step_checkpoint(epoch, completed)
-                meters.restart_clock()  # exclude checkpoint I/O from meter
-            i += 1
+                    self._save_step_checkpoint(epoch, completed)
+                    meters.restart_clock()  # exclude checkpoint I/O from meter
+                i += 1
         if self.ft_guard is not None and self.ft_guard.drain():  # synclint: agreement
             # Trailing flags (buffered past the last cadence point) must be
             # resolved before the epoch-end checkpoint can capture them.
@@ -1163,6 +1173,11 @@ class Trainer:
                               data_wait_ms=(self.stepattr.data_wait_ema_ms
                                             if self.stepattr is not None
                                             else None))
+            if cfg.profile_dir:
+                # the host spans of the run loop, the feeder and the loader
+                # (obs/trace.py), beside the profiler's capture
+                os.makedirs(cfg.profile_dir, exist_ok=True)
+                RECORDER.dump(os.path.join(cfg.profile_dir, "spans.jsonl"))
             self.obs.flush()
             if self._goodput is not None:
                 print(f"=> {self._goodput.format_summary()}", flush=True)

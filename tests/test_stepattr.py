@@ -288,8 +288,12 @@ def test_image_trainer_identity_fence(tmp_path, explicit):
 
 def test_async_feeder_accounts_waits():
     """AsyncFeeder meters how long the consumer blocked on its queue —
-    the data-wait signal when prefetch is on."""
+    the data-wait signal when prefetch is on (``data_wait`` spans in
+    obs/trace.py's recorder)."""
     from pytorch_distributed_tpu.data.loader import AsyncFeeder
+    from pytorch_distributed_tpu.obs.trace import RECORDER
+
+    t0 = time.perf_counter()
 
     def slow_src():
         for i in range(4):
@@ -299,8 +303,11 @@ def test_async_feeder_accounts_waits():
     f = AsyncFeeder(lambda it: it, prefetch=1)
     got = list(f(slow_src()))
     assert got == [0, 1, 2, 3]
-    assert f.wait_ms_last >= 0.0
-    assert f.wait_ms_ema > 0.0  # the slow source made the consumer wait
+    waits = [r for r in RECORDER.records(t0) if r.name == "data_wait"]
+    assert [r.id for r in waits] == [0, 1, 2, 3, 4]  # the last takes the end
+    assert all(r.end >= r.start for r in waits)
+    # the slow source made the consumer wait
+    assert sum(r.end - r.start for r in waits) > 0.02
 
 
 def test_find_stragglers_names_input_starved_ranks(tmp_path):
