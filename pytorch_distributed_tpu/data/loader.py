@@ -11,10 +11,18 @@ Batches have **static shapes** (XLA requirement): the final partial batch is
 zero-padded and carries a 0/1 ``weights`` mask, which the step functions use
 so padding contributes nothing to loss/metrics — this makes evaluation exact
 rather than DistributedSampler-approximate (SURVEY.md §7.4 item 3).
+
+A batch is filled where its samples are: the worker thread that fetched a
+sample writes it into the batch's row itself (``_Rows.place``: dtype check,
+row copy and, in ``u8_wire``, the horizontal flip), so no thread copies or
+flips a whole batch while the workers idle.  Samples that reach the producer
+some other way (pickled from worker processes, decoded by the native batch
+call) are placed by the producer through the same function.
 """
 
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -57,11 +65,14 @@ class DataLoader:
                           (reference-shaped pipeline; default);
         - ``"u8_host"`` — transforms yield uint8; flip+normalize run at batch
                           level in the native C++ library (data/native/);
-        - ``"u8_wire"`` — transforms yield uint8; flip runs host-side, the
-                          batch crosses PCIe/ICI as uint8 (4× fewer bytes)
-                          and normalization happens on device (DeviceFeeder).
+        - ``"u8_wire"`` — transforms yield uint8; the flip runs on the
+                          worker that decoded the sample, as it writes the
+                          row into the batch (``_Rows.place``); the batch
+                          crosses PCIe/ICI as uint8 (4× fewer bytes) and
+                          normalization happens on device (DeviceFeeder).
         ``random_flip`` applies the train-stack horizontal flip in the u8
-        modes (in f32 mode the flip lives in the per-sample transform).
+        modes (in f32 mode the flip lives in the per-sample transform); one
+        draw per batch, ``default_rng((seed, epoch, batch, 1))``.
 
         ``worker_type``: ``"thread"`` (default; right for the native-decode
         path, whose C++ batch decode releases the GIL) or ``"process"`` —
@@ -104,46 +115,53 @@ class DataLoader:
             return self.dataset[int(index)]
         return None  # padding slot
 
-    def _fetch_timed(self, index: int, valid: int):
-        """``_fetch`` and the seconds it took on its worker thread."""
+    def _fetch_timed(self, place, i: int, index: int, valid: int):
+        """On a worker thread: ``_fetch``, then ``place`` the sample in row
+        ``i`` of its batch (``place`` is ``None`` for a native_decode
+        dataset, whose samples are blobs for the producer).  Returns the
+        sample if it is still to be placed, whether this worker placed it,
+        and the seconds both took."""
         t = time.perf_counter()
         sample = self._fetch(index, valid)
-        return sample, time.perf_counter() - t
+        placed = place is not None and sample is not None
+        if placed:
+            place(i, sample)
+            sample = None
+        return sample, placed, time.perf_counter() - t
 
-    def _assemble_native(self, samples):
-        """Batch the ("jpeg", blob, params, label) / ("u8", arr, None, label)
+    def _assemble_native(self, rows: "_Rows", samples):
+        """Place the ("jpeg", blob, params, label) / ("u8", arr, None, label)
         samples of a native_decode dataset: one C++ call decodes, crops and
         resizes every JPEG in the batch (libjpeg, multithreaded, GIL-free).
 
-        Returns ``(images, labels, dead)`` — ``dead`` lists batch slots whose
-        JPEG failed to decode; the caller zeroes their weights so corrupt
-        files drop out of loss/metrics instead of training as black images."""
+        Returns ``dead``, the batch slots whose JPEG failed to decode; the
+        caller zeroes their weights so corrupt files drop out of
+        loss/metrics instead of training as black images."""
         from pytorch_distributed_tpu.data.native import decode_crop_resize_batch
 
-        size = self.dataset.image_size
-        images = np.zeros((self.batch_size, size, size, 3), np.uint8)
-        labels = np.zeros(self.batch_size, dtype=np.int32)
-        blobs, params, slots = [], [], []
+        blobs, params, slots, labels = [], [], [], []
         dead: list = []
         for i, s in enumerate(samples):
             if s is None:
                 continue
             kind, payload, p, label = s
-            labels[i] = label
             if kind == "jpeg":
                 slots.append(i)
                 blobs.append(payload)
                 params.append(p)
+                labels.append(label)
             else:
-                images[i] = payload
+                rows.place(i, (payload, label))
         if blobs:
             params_arr = (
                 np.stack(params) if params[0] is not None else None
             )
             decoded, failed = decode_crop_resize_batch(
-                blobs, size, params=params_arr, return_failed=True
+                blobs, self.dataset.image_size, params=params_arr,
+                return_failed=True
             )
-            images[slots] = decoded
+            for i, image, label in zip(slots, decoded, labels):
+                rows.place(i, (image, label))
             if failed.any():
                 dead = [slots[j] for j in np.nonzero(failed)[0]]
                 import warnings
@@ -153,7 +171,7 @@ class DataLoader:
                     f"out of loss/metrics",
                     stacklevel=2,
                 )
-        return images, labels, dead
+        return dead
 
     def _batch_indices(self, indices, valid, b: int):
         lo, hi = b * self.batch_size, (b + 1) * self.batch_size
@@ -166,64 +184,45 @@ class DataLoader:
             val = np.concatenate([val, np.zeros(pad, dtype=val.dtype)])
         return idx, val
 
-    def _assemble(self, b: int, val, samples) -> Batch:
-        """Samples → one padded/masked batch (shared by both worker modes)."""
-        if getattr(self.dataset, "native_decode", False):
+    def _rows(self, b: int) -> "_Rows":
+        """Batch ``b``'s empty rows, with its flip draw."""
+        flip = None
+        if self.random_flip and self.batch_mode != "f32":
+            flip_rng = np.random.default_rng(
+                (self.seed, self.sampler.epoch, b, 1)
+            )
+            flip = (flip_rng.random(self.batch_size) < 0.5).astype(np.uint8)
+        return _Rows(self.batch_size, self.batch_mode, flip)
+
+    def _finish(self, rows: "_Rows", val, samples=None) -> Batch:
+        """What the producer does alone once a batch's samples are in:
+        the native batch decode of ``samples`` (native_decode datasets
+        only; every other sample is in ``rows`` already), ``u8_host``'s
+        C++ flip+normalize, the ``weights`` mask."""
+        if samples is not None:
             if self.batch_mode == "f32":
                 raise TypeError(
                     "native_decode datasets produce uint8 batches; "
                     "use batch_mode 'u8_host' or 'u8_wire'"
                 )
-            images, labels, dead = self._assemble_native(samples)
+            dead = self._assemble_native(rows, samples)
             if dead:
                 val = val.copy()
                 val[dead] = 0
-        else:
-            proto = next(s for s in samples if s is not None)
-            img_dtype = (
-                np.uint8 if self.batch_mode != "f32" else np.float32
+        images = rows.images
+        if self.batch_mode == "u8_host":
+            from pytorch_distributed_tpu.data.native import normalize_batch
+            from pytorch_distributed_tpu.data.transforms import (
+                IMAGENET_MEAN,
+                IMAGENET_STD,
             )
-            if self.batch_mode != "f32" and proto[0].dtype != np.uint8:
-                raise TypeError(
-                    f"batch_mode {self.batch_mode!r} needs uint8 "
-                    f"samples (use the *_transform_u8 stacks), got "
-                    f"{proto[0].dtype}"
-                )
-            images = np.zeros(
-                (self.batch_size,) + proto[0].shape, dtype=img_dtype
-            )
-            labels = np.zeros(self.batch_size, dtype=np.int32)
-            for i, s in enumerate(samples):
-                if s is not None:
-                    images[i] = s[0]
-                    labels[i] = s[1]
-        if self.batch_mode != "f32":
-            flip_rng = np.random.default_rng(
-                (self.seed, self.sampler.epoch, b, 1)
-            )
-            flip = (
-                (flip_rng.random(self.batch_size) < 0.5).astype(np.uint8)
-                if self.random_flip
-                else None
-            )
-            if self.batch_mode == "u8_host":
-                from pytorch_distributed_tpu.data.native import (
-                    normalize_batch,
-                )
-                from pytorch_distributed_tpu.data.transforms import (
-                    IMAGENET_MEAN,
-                    IMAGENET_STD,
-                )
 
-                images = normalize_batch(
-                    images, IMAGENET_MEAN, IMAGENET_STD, flip=flip
-                )
-            elif flip is not None:  # u8_wire: flip on host, u8 out
-                fidx = np.nonzero(flip)[0]
-                images[fidx] = images[fidx, :, ::-1, :]
+            images = normalize_batch(
+                images, IMAGENET_MEAN, IMAGENET_STD, flip=rows.flip
+            )
         return {
             "images": images,
-            "labels": labels,
+            "labels": rows.labels,
             "weights": val.astype(np.float32),
         }
 
@@ -259,6 +258,8 @@ class DataLoader:
         def workers_cpu_s() -> float:
             return sum(time.clock_gettime(c) for c in clocks)
 
+        native = getattr(self.dataset, "native_decode", False)
+        slots = range(self.batch_size)
         with ThreadPoolExecutor(max_workers=self.num_workers,
                                 initializer=note_worker) as pool:
             for b in range(start, nb):
@@ -266,13 +267,19 @@ class DataLoader:
                 # spans close before the yield (obs/trace.py: the stack of
                 # open spans is the thread's, not the generator's)
                 with span("fetch", id=b - start) as fetch:
+                    rows = self._rows(b)
+                    place = None if native else rows.place
                     cpu = workers_cpu_s()
-                    timed = list(pool.map(self._fetch_timed, idx, val))
+                    timed = list(pool.map(
+                        functools.partial(self._fetch_timed, place),
+                        slots, idx, val))
                     fetch.set(samples=len(timed),
-                              sample_wall_s=sum(t[1] for t in timed),
-                              sample_cpu_s=workers_cpu_s() - cpu)
+                              sample_wall_s=sum(t[2] for t in timed),
+                              sample_cpu_s=workers_cpu_s() - cpu,
+                              placed=sum(t[1] for t in timed))
                 with span("assemble", id=b - start):
-                    batch = self._assemble(b, val, [t[0] for t in timed])
+                    batch = self._finish(
+                        rows, val, [t[0] for t in timed] if native else None)
                 yield batch
 
     def _ensure_pool(self):
@@ -341,6 +348,7 @@ class DataLoader:
         the IPC overhead stays a constant per batch, not per image."""
         pool = self._ensure_pool()
         W = self.num_workers
+        native = getattr(self.dataset, "native_decode", False)
         for b in range(start, nb):
             idx, val = self._batch_indices(indices, valid, b)
             args = [
@@ -350,15 +358,66 @@ class DataLoader:
             bounds = [(len(args) * w // W, len(args) * (w + 1) // W)
                       for w in range(W)]
             chunks = [args[lo:hi] for lo, hi in bounds if hi > lo]
-            # the samples are timed in other processes: no counts here
-            with span("fetch", id=b - start):
-                samples = [
-                    s for chunk in pool.map(_process_fetch_chunk, chunks)
-                    for s in chunk
-                ]
+            # the samples are timed in other processes: no counts here but
+            # `placed`, the rows that workers wrote (none: theirs arrive
+            # pickled, and the producer places each chunk as it returns)
+            with span("fetch", id=b - start) as fetch:
+                rows = self._rows(b)
+                samples = (s for chunk in pool.imap(_process_fetch_chunk,
+                                                    chunks) for s in chunk)
+                if native:
+                    samples = list(samples)
+                else:
+                    for i, sample in enumerate(samples):
+                        if sample is not None:
+                            rows.place(i, sample)
+                    samples = None
+                fetch.set(placed=0)
             with span("assemble", id=b - start):
-                batch = self._assemble(b, val, samples)
+                batch = self._finish(rows, val, samples)
             yield batch
+
+
+class _Rows:
+    """One batch's ``images`` and ``labels`` while its samples land.
+
+    ``place`` is the only code that writes a row, whoever holds the
+    sample: the worker thread that fetched it (thread workers), or the
+    producer (samples pickled by worker processes, rows decoded by the
+    native batch call).  Rows nobody places, the padding of a trailing
+    batch, stay zero."""
+
+    def __init__(self, batch_size: int, batch_mode: str, flip):
+        self.batch_size = batch_size
+        self.batch_mode = batch_mode
+        self.flip = flip  # the batch's draw, or None
+        self.images = None  # the first sample to land brings the shape
+        self.labels = np.zeros(batch_size, dtype=np.int32)
+        self._allocating = threading.Lock()
+
+    def place(self, i: int, sample) -> None:
+        image, label = sample
+        u8 = self.batch_mode != "f32"
+        if u8 and image.dtype != np.uint8:
+            raise TypeError(
+                f"batch_mode {self.batch_mode!r} needs uint8 "
+                f"samples (use the *_transform_u8 stacks), got "
+                f"{image.dtype}"
+            )
+        if self.images is None:
+            with self._allocating:
+                if self.images is None:
+                    self.images = np.zeros(
+                        (self.batch_size,) + image.shape,
+                        dtype=np.uint8 if u8 else np.float32,
+                    )
+        # u8_wire flips here, row by row; u8_host's flip is inside
+        # normalize_batch, f32's inside the sample's own transform
+        if (self.batch_mode == "u8_wire" and self.flip is not None
+                and self.flip[i]):
+            image = image[:, ::-1]
+        self.images[i] = image
+        self.labels[i] = label
 
 
 _LIVE_POOLS: list = []

@@ -113,3 +113,155 @@ def test_process_workers_match_thread_workers():
         np.testing.assert_array_equal(a["images"], b["images"])
         np.testing.assert_array_equal(a["labels"], b["labels"])
         np.testing.assert_array_equal(a["weights"], b["weights"])
+
+
+def _reference_batches(loader, start):
+    """The batches of the loader's current epoch, assembled the plain way:
+    a zero-filled array, one row copy a sample, the flip by fancy index."""
+    from pytorch_distributed_tpu.data.native import normalize_batch
+    from pytorch_distributed_tpu.data.transforms import (
+        IMAGENET_MEAN,
+        IMAGENET_STD,
+    )
+
+    indices, valid = loader.sampler.shard()
+    bsz, epoch = loader.batch_size, loader.sampler.epoch
+    u8 = loader.batch_mode != "f32"
+    for b in range(start, len(loader)):
+        idx = indices[b * bsz:(b + 1) * bsz]
+        val = valid[b * bsz:(b + 1) * bsz]
+        samples = [
+            loader.dataset.get(
+                int(i), np.random.default_rng((loader.seed, epoch, int(i))))
+            for i, v in zip(idx, val) if v
+        ]
+        images = np.zeros((bsz,) + samples[0][0].shape,
+                          np.uint8 if u8 else np.float32)
+        labels = np.zeros(bsz, np.int32)
+        weights = np.zeros(bsz, np.float32)
+        for i, (image, label) in enumerate(samples):
+            images[i], labels[i], weights[i] = image, label, 1.0
+        if u8 and loader.random_flip:
+            flip = np.random.default_rng(
+                (loader.seed, epoch, b, 1)).random(bsz) < 0.5
+            fidx = np.nonzero(flip)[0]
+            images[fidx] = images[fidx, :, ::-1, :]
+        if loader.batch_mode == "u8_host":
+            images = normalize_batch(images, IMAGENET_MEAN, IMAGENET_STD)
+        yield {"images": images, "labels": labels, "weights": weights}
+
+
+@pytest.mark.parametrize("start", [0, 1], ids=["epoch", "resumed"])
+@pytest.mark.parametrize("worker_type", ["thread", "process"])
+@pytest.mark.parametrize("batch_mode", ["f32", "u8_host", "u8_wire"])
+def test_batches_equal_a_plain_assembly(batch_mode, worker_type, start):
+    """Rows placed by whoever fetched them (loader.py ``_Rows.place``) give
+    the bytes of a serial copy-and-flip: every mode and worker type, a
+    padded trailing batch, a resumed epoch, two epochs, flip on and off."""
+    from pytorch_distributed_tpu.data.transforms import (
+        train_transform,
+        train_transform_u8,
+    )
+
+    stack = train_transform(size=16) if batch_mode == "f32" else (
+        train_transform_u8(16))
+    ds = SyntheticImageDataset(length=20, num_classes=5, image_size=32,
+                               transform=stack)
+    loader = DataLoader(
+        ds, batch_size=8, num_workers=3, seed=7, batch_mode=batch_mode,
+        worker_type=worker_type,
+        sampler=DistributedShardSampler(20, shuffle=True, seed=3))
+    try:
+        for epoch in (0, 1):
+            for flip in (False, True):
+                loader.set_epoch(epoch)
+                loader.random_flip = flip
+                got = list(loader.iter_batches(start))
+                want = list(_reference_batches(loader, start))
+                assert len(got) == len(want) == 3 - start
+                assert got[-1]["weights"].tolist() == [1] * 4 + [0] * 4
+                for g, w in zip(got, want):
+                    assert sorted(g) == sorted(w)
+                    for key in w:
+                        assert g[key].dtype == w[key].dtype, key
+                        np.testing.assert_array_equal(g[key], w[key], key)
+        if batch_mode == "u8_wire":  # the draw does flip some rows
+            loader.random_flip = False
+            plain = list(loader.iter_batches(start))
+            assert any((g["images"] != p["images"]).any()
+                       for g, p in zip(got, plain))
+    finally:
+        loader.close()
+
+
+class _OddSamples:
+    """Eight uint8 samples; the third raises, or comes back float32."""
+
+    def __init__(self, fault):
+        self.fault = fault
+
+    def __len__(self):
+        return 8
+
+    def __getitem__(self, index):
+        if index == 2 and self.fault == "raises":
+            raise OSError("sample 2 cannot be read")
+        dtype = (np.float32 if index == 2 and self.fault == "float32"
+                 else np.uint8)
+        return np.full((4, 4, 3), index, dtype), index
+
+
+@pytest.mark.parametrize("worker_type", ["thread", "process"])
+@pytest.mark.parametrize("fault,error", [("raises", OSError),
+                                         ("float32", TypeError)])
+def test_a_bad_sample_surfaces_at_the_iterator(fault, error, worker_type):
+    loader = DataLoader(_OddSamples(fault), batch_size=4, num_workers=2,
+                        batch_mode="u8_wire", worker_type=worker_type)
+    message = ("sample 2 cannot be read" if fault == "raises"
+               else "batch_mode 'u8_wire' needs uint8 samples")
+    try:
+        with pytest.raises(error, match=message):
+            next(iter(loader))
+    finally:
+        loader.close()
+
+
+class _Together:
+    """Sixteen samples at a time leave ``__getitem__`` in the same instant."""
+
+    def __init__(self, n):
+        import threading
+
+        self.n = n
+        self.barrier = threading.Barrier(16)
+
+    def __len__(self):
+        return self.n
+
+    def __getitem__(self, index):
+        self.barrier.wait(10.0)
+        return np.full((8, 8, 3), index % 251, np.uint8), index
+
+
+def test_rows_survive_more_workers_than_cores_racing_to_allocate():
+    """A batch's array is allocated by the first worker to land a row, under
+    a lock: a second allocation would drop the rows already written.  Sixteen
+    workers reach ``place`` together, the interpreter switching every
+    microsecond, 200 batches."""
+    import sys
+
+    ds = _Together(3200)
+    loader = DataLoader(ds, batch_size=16, num_workers=16,
+                        batch_mode="u8_wire",
+                        sampler=DistributedShardSampler(3200, shuffle=False))
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        got = list(loader)
+    finally:
+        sys.setswitchinterval(interval)
+    assert len(got) == 200
+    for b, batch in enumerate(got):
+        want = np.arange(16 * b, 16 * b + 16)
+        np.testing.assert_array_equal(batch["labels"], want)
+        np.testing.assert_array_equal(batch["images"][:, 0, 0, 0], want % 251)

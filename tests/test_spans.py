@@ -187,6 +187,7 @@ def test_loader_records_fetch_counts_and_assemble():
     assert [r.id for r in fetches] == [0, 1] == [r.id for r in assembles]
     for r in fetches:
         assert r.fields["samples"] == 4
+        assert r.fields["placed"] == 4  # each row written by its worker
         assert 0 < r.fields["sample_cpu_s"] <= r.fields["sample_wall_s"]
         assert r.fields["sample_wall_s"] >= 4 * 0.002
     for fetch, assemble in zip(fetches, assembles):
