@@ -16,7 +16,8 @@ is recorded via ``self.sow("losses", …)``; the LM step collects it with
 
 from __future__ import annotations
 
-from typing import Any
+import functools
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
@@ -124,3 +125,252 @@ def moe_specs(params, expert_axis: str = "expert"):
         return P()
 
     return jax.tree_util.tree_map_with_path(spec, params)
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing for the configured decoder (models/decoder.py): sorted
+# (token, choice) pairs and grouped matrix products over the experts held.
+# ---------------------------------------------------------------------------
+
+# Sorted (token, expert) pairs a pass of the grouped products works on: a
+# pass's activations are at most this many rows of d_model + 3 x width
+# floats (51 MB a thousand rows at 2048 | 1408), whatever the imbalance.
+GMM_CHUNK_ROWS = 8192
+
+_TGMM = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _gmm(rows, stack, sizes):
+    """Rows of group g times ``stack[g]``: [C, a] x [G, a, b] -> [C, b]."""
+    return jax.lax.ragged_dot(rows, stack, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _tgmm(rows, cot, sizes):
+    """Per group, rows^T cot: [C, a], [C, b] -> [G, a, b] (float32)."""
+    return jax.lax.ragged_dot_general(rows, cot, sizes, _TGMM,
+                                      preferred_element_type=jnp.float32)
+
+
+def _chunks(token, gate, sizes, chunk_rows: int):
+    """The sorted pairs cut into chunks of ``chunk_rows``: how many chunks
+    hold a pair, and a function from a chunk's number to its token ids,
+    gates, rows per group and the mask of rows that are pairs."""
+    n = sizes.sum()
+    pad = (-token.shape[0]) % chunk_rows
+    token = jnp.pad(token, (0, pad))
+    gate = jnp.pad(gate, (0, pad))
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+
+    def chunk(c):
+        lo = c * chunk_rows
+        tok = jax.lax.dynamic_slice(token, (lo,), (chunk_rows,))
+        g = jax.lax.dynamic_slice(gate, (lo,), (chunk_rows,))
+        in_chunk = (jnp.clip(ends, lo, lo + chunk_rows)
+                    - jnp.clip(starts, lo, lo + chunk_rows))
+        valid = lo + jnp.arange(chunk_rows) < n
+        return lo, tok, g, in_chunk.astype(jnp.int32), valid
+
+    return (n + chunk_rows - 1) // chunk_rows, chunk
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def grouped_swiglu(x, token, gate, sizes, w_gate, w_up, w_down,
+                   chunk_rows: int, dtype):
+    """``y[t] = sum over the sorted pairs (t, e) of gate * SwiGLU_e(x[t])``
+    and the rows the grouped products processed.
+
+    ``token`` [N] and ``gate`` [N] list the pairs sorted by held expert,
+    those on no held expert last; ``sizes`` [G] counts each held expert's
+    pairs.  The pairs are worked through in chunks of ``chunk_rows`` by a
+    loop that runs as many times as there are pairs to work on (a dynamic
+    trip count: group sizes vary from step to step, shapes do not), so no
+    pair is dropped however uneven the routing, and memory is bounded by
+    one chunk.  The loop cannot be differentiated, hence the custom VJP,
+    whose backward pass is the same loop."""
+    return _grouped_fwd(x, token, gate, sizes, w_gate, w_up, w_down,
+                        chunk_rows, dtype)[0]
+
+
+def _grouped_fwd(x, token, gate, sizes, w_gate, w_up, w_down, chunk_rows,
+                 dtype):
+    wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+    n_chunks, chunk = _chunks(token, gate, sizes, chunk_rows)
+
+    def body(c, carry):
+        y, rows = carry
+        _, tok, g, in_chunk, valid = chunk(c)
+        xs = x[tok].astype(dtype)
+        hidden = (jax.nn.silu(_gmm(xs, wg, in_chunk))
+                  * _gmm(xs, wu, in_chunk)).astype(dtype)
+        out = _gmm(hidden, wd, in_chunk) * g[:, None]
+        # rows past the last group are not written by the grouped product
+        out = jnp.where(valid[:, None], out, 0.0)
+        return y.at[tok].add(out), rows + in_chunk.sum()
+
+    y, rows = jax.lax.fori_loop(
+        0, n_chunks, body,
+        (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
+    return (y, rows), (x, token, gate, sizes, w_gate, w_up, w_down)
+
+
+def _grouped_bwd(chunk_rows, dtype, res, cts):
+    x, token, gate, sizes, w_gate, w_up, w_down = res
+    dy = cts[0]
+    wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+    wg_t, wu_t, wd_t = (w.transpose(0, 2, 1) for w in (wg, wu, wd))
+    n_chunks, chunk = _chunks(token, gate, sizes, chunk_rows)
+
+    def body(c, carry):
+        dx, dgate, dwg, dwu, dwd = carry
+        lo, tok, g, in_chunk, valid = chunk(c)
+        keep = valid[:, None]
+        xs = x[tok].astype(dtype)
+        a, b = _gmm(xs, wg, in_chunk), _gmm(xs, wu, in_chunk)
+        sig = jax.nn.sigmoid(a)
+        hidden = (a * sig * b).astype(dtype)
+        dout = jnp.where(keep, dy[tok], 0.0)
+        out = jnp.where(keep, _gmm(hidden, wd, in_chunk), 0.0)
+        dg = jnp.sum(out * dout, -1)
+        dscaled = (dout * g[:, None]).astype(dtype)
+        dhidden = _gmm(dscaled, wd_t, in_chunk)
+        da = (dhidden * b * sig * (1.0 + a * (1.0 - sig))).astype(dtype)
+        db = (dhidden * a * sig).astype(dtype)
+        dxs = jnp.where(keep, _gmm(da, wg_t, in_chunk)
+                        + _gmm(db, wu_t, in_chunk), 0.0)
+        return (dx.at[tok].add(dxs),
+                jax.lax.dynamic_update_slice(dgate, dg, (lo,)),
+                dwg + _tgmm(xs, da, in_chunk),
+                dwu + _tgmm(xs, db, in_chunk),
+                dwd + _tgmm(hidden, dscaled, in_chunk))
+
+    padded = token.shape[0] + (-token.shape[0]) % chunk_rows
+    dx, dgate, dwg, dwu, dwd = jax.lax.fori_loop(
+        0, n_chunks, body,
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros((padded,), jnp.float32),
+         jnp.zeros(w_gate.shape, jnp.float32),
+         jnp.zeros(w_up.shape, jnp.float32),
+         jnp.zeros(w_down.shape, jnp.float32)))
+    return (dx.astype(x.dtype), None,
+            dgate[:token.shape[0]].astype(gate.dtype), None,
+            dwg.astype(w_gate.dtype), dwu.astype(w_up.dtype),
+            dwd.astype(w_down.dtype))
+
+
+grouped_swiglu.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+class _SwiGLU(nn.Module):
+    """``(silu(x W_gate) * (x W_up)) W_down``, no biases."""
+
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            name=name)
+
+        hidden = nn.silu(dense(self.width, "gate_proj")(x)) * dense(
+            self.width, "up_proj")(x)
+        return dense(x.shape[-1], "down_proj")(hidden)
+
+
+class _ExpertStack(nn.Module):
+    """The held experts' SwiGLU weights, stacked on a leading axis."""
+
+    count: int
+    width: int
+
+    @nn.compact
+    def __call__(self, d_model: int):
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                            batch_axis=(0,))
+        return {name: self.param(name, init, (self.count,) + shape,
+                                 jnp.float32)
+                for name, shape in (("gate_proj", (d_model, self.width)),
+                                    ("up_proj", (d_model, self.width)),
+                                    ("down_proj", (self.width, d_model)))}
+
+
+class RoutedExperts(nn.Module):
+    """An expert layer that is told which experts it holds.
+
+    Scores are sigmoids over all ``n_routed`` experts in float32; the
+    ``top_k`` are chosen by score plus a selection bias that is state (the
+    ``router`` collection), not a parameter; gates are the chosen scores,
+    normalised and scaled.  The sum runs over those of the chosen whose
+    expert is one of ``held = (first, count)``; the shared expert (one
+    SwiGLU of ``n_shared`` times the width) is computed in full.  No
+    capacity and no dropped token: see ``grouped_swiglu``.
+
+    Sows the sequence-wise balance loss into ``losses`` and, into
+    ``counters``, the step's counts of tokens by expert (all ``n_routed``:
+    the bias update reads them), ``routed_here`` (pairs on held experts),
+    ``rows_grouped`` (rows the grouped products processed) and
+    ``rows_max`` (the fullest held expert's rows)."""
+
+    n_routed: int
+    top_k: int
+    width: int
+    held: Tuple[int, int]
+    n_shared: int = 0
+    scaling: float = 1.0
+    norm_topk_prob: bool = True
+    seq_aux_alpha: float = 0.0
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        from pytorch_distributed_tpu.obs.trace import scope
+
+        B, L, C = x.shape
+        E, K = self.n_routed, self.top_k
+        first, count = self.held
+        tokens = x.reshape(B * L, C)
+        with scope("moe_route"):
+            bias = self.variable("router", "e_score_correction_bias",
+                                 jnp.zeros, (E,), jnp.float32)
+            scores = jax.nn.sigmoid(nn.Dense(
+                E, use_bias=False, dtype=jnp.float32, name="router")(
+                    tokens.astype(jnp.float32)))                  # [S, E]
+            _, idx = jax.lax.top_k(scores + bias.value, K)        # [S, K]
+            gates = jnp.take_along_axis(scores, idx, -1)
+            if self.norm_topk_prob:
+                gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+            gates = gates * self.scaling
+            chose = jax.nn.one_hot(idx, E, dtype=jnp.float32).sum(1)
+            if self.seq_aux_alpha:
+                f = chose.reshape(B, L, E).sum(1) * (E / (K * L))
+                p = (scores / scores.sum(-1, keepdims=True)).reshape(
+                    B, L, E).mean(1)
+                self.sow("losses", "moe_seq_aux", self.seq_aux_alpha
+                         * jnp.mean(jnp.sum(f * p, -1)))
+            # pairs sorted by held expert; those on absent experts last
+            local = idx.reshape(-1) - first
+            here = (local >= 0) & (local < count)
+            key = jnp.where(here, local, count)
+            order = jnp.argsort(key, stable=True)
+            sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+        with scope("moe_experts"):
+            stack = _ExpertStack(count, self.width, name="experts")(C)
+            routed, rows = grouped_swiglu(
+                tokens.astype(self.dtype), (order // K).astype(jnp.int32),
+                gates.reshape(-1)[order], sizes, stack["gate_proj"],
+                stack["up_proj"], stack["down_proj"],
+                min(GMM_CHUNK_ROWS, B * L * K), self.dtype)
+        for name, value in (("expert_counts", chose.sum(0)),
+                            ("routed_here", here.sum()),
+                            ("rows_grouped", rows),
+                            ("rows_max", sizes.max())):
+            self.sow("counters", name, value)
+        out = routed
+        if self.n_shared:
+            with scope("moe_shared"):
+                out = out + _SwiGLU(self.n_shared * self.width, self.dtype,
+                                    name="shared")(tokens)
+        return out.reshape(B, L, C).astype(x.dtype)

@@ -18,7 +18,11 @@ logsumexp with VMEM accumulators; O(L·BK) memory, every matmul on the MXU.
 the kernels are tested against).
 
 Layout: [B, L, H, D] like parallel/ring.py; block sizes default to the
-128-lane MXU tile.
+128-lane MXU tile.  ``q`` and ``k`` share one head size and ``v`` may have
+another (latent attention: 192 | 128); ``scale`` defaults to
+``D_qk ** -0.5``.  The kernels' matrix products take their operands in the
+type of ``q``, ``k`` and ``v`` (bf16 in, bf16 on the MXU); accumulation and
+the softmax are float32.
 """
 
 from __future__ import annotations
@@ -57,9 +61,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(run)
     def _block():
-        q = q_ref[0].astype(jnp.float32)            # [BQ, D]
-        k = k_ref[0].astype(jnp.float32)            # [BK, D]
-        v = v_ref[0].astype(jnp.float32)            # [BK, D]
+        q = q_ref[0]                                # [BQ, D]
+        k = k_ref[0]                                # [BK, D]
+        v = v_ref[0]                                # [BK, Dv]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -76,7 +80,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         corr = jnp.exp(m_prev - m_new)               # [BQ, 1]
         l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -95,9 +99,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
-               interpret: bool):
+               interpret: bool, scale: Optional[float] = None):
     B, L, H, D = q.shape
-    scale = 1.0 / (D ** 0.5)
+    Dv = v.shape[-1]
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
     bq = min(block_q, L)
     bk = min(block_k, L)
     assert L % bq == 0 and L % bk == 0, (
@@ -106,7 +112,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     # [B, L, H, D] -> [B*H, L, D]
     qr = q.transpose(0, 2, 1, 3).reshape(B * H, L, D)
     kr = k.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * H, L, D)
+    vr = v.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
 
     grid = (B * H, L // bq, L // bk)
     out, lse = pl.pallas_call(
@@ -118,30 +124,30 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0),
+            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bq, 128), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, L, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, L, 128), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),   # running max
             pltpu.VMEM((bq, 128), jnp.float32),   # running denominator
-            pltpu.VMEM((bq, D), jnp.float32),     # output accumulator
+            pltpu.VMEM((bq, Dv), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
     )(qr, kr, vr)
     # Residual lse is [B*H, L] (lane 0 of the kernel's lane-broadcast
     # output) — saving the full 128-lane layout would hold 128x the bytes
     # across the fwd->bwd interval; the backward re-broadcasts cheaply.
-    return out.reshape(B, H, L, D).transpose(0, 2, 1, 3), lse[:, :, 0]
+    return out.reshape(B, H, L, Dv).transpose(0, 2, 1, 3), lse[:, :, 0]
 
 
 def _causal_run(qi, kj, block_q, block_k):
@@ -159,13 +165,13 @@ def _mask_scores(s, qi, kj, block_q, block_k):
 
 def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qi, kj,
                     scale, causal, block_q, block_k):
-    """Shared backward block math: online-recomputed (p, ds) plus the f32
+    """Shared backward block math: online-recomputed (p, ds) plus the
     block views — the single source for both the dq and dk/dv kernels (and
     the same masking the forward kernel applies)."""
-    q = q_ref[0].astype(jnp.float32)             # [BQ, D]
-    k = k_ref[0].astype(jnp.float32)             # [BK, D]
-    v = v_ref[0].astype(jnp.float32)             # [BK, D]
-    do = do_ref[0].astype(jnp.float32)           # [BQ, D]
+    q = q_ref[0]                                 # [BQ, D]
+    k = k_ref[0]                                 # [BK, D]
+    v = v_ref[0]                                 # [BK, Dv]
+    do = do_ref[0]                               # [BQ, Dv]
     lse = lse_ref[0][:, :1]                      # [BQ, 1]
     dlt = dlt_ref[0][:, :1]                      # [BQ, 1]
     s = jax.lax.dot_general(
@@ -180,7 +186,7 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qi, kj,
         preferred_element_type=jnp.float32,
     )                                            # [BQ, BK]
     ds = p * (dp - dlt) * scale
-    return p, ds, q, k, do
+    return p.astype(do.dtype), ds.astype(q.dtype), q, k, do
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
@@ -249,32 +255,36 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 
 
 def _bwd_pallas(res, g, causal: bool, block_q: int, block_k: int,
-                interpret: bool):
+                interpret: bool, scale: Optional[float] = None):
     """Fused Pallas backward: dq pass + dk/dv pass, both with online
     recompute from the saved lse — no [L, L] materialization, all matmuls
     on the MXU (flash-attention-2 decomposition)."""
     q, k, v, out, lse = res               # lse: [B*H, L] f32
     B, L, H, D = q.shape
-    scale = 1.0 / (D ** 0.5)
+    Dv = v.shape[-1]
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
     bq = min(block_q, L)
     bk = min(block_k, L)
     f32 = jnp.float32
     qr = q.transpose(0, 2, 1, 3).reshape(B * H, L, D)
     kr = k.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    gr = g.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    of = out.transpose(0, 2, 1, 3).reshape(B * H, L, D)
+    vr = v.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
+    gr = g.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
+    of = out.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
     delta = jnp.sum(of.astype(f32) * gr.astype(f32), axis=-1)     # [BH, L]
     # Lane-broadcast for block slicing (transient, not a saved residual).
     lse128 = jnp.broadcast_to(lse[:, :, None], (B * H, L, 128))
     dlt128 = jnp.broadcast_to(delta[:, :, None], (B * H, L, 128))
 
-    def spec_q(pos_q):
-        return pl.BlockSpec((1, bq, D), lambda b, x, y: (b, (x, y)[pos_q], 0),
+    def spec_q(pos_q, width=D):
+        return pl.BlockSpec((1, bq, width),
+                            lambda b, x, y: (b, (x, y)[pos_q], 0),
                             memory_space=pltpu.VMEM)
 
-    def spec_k(pos_k):
-        return pl.BlockSpec((1, bk, D), lambda b, x, y: (b, (x, y)[pos_k], 0),
+    def spec_k(pos_k, width=D):
+        return pl.BlockSpec((1, bk, width),
+                            lambda b, x, y: (b, (x, y)[pos_k], 0),
                             memory_space=pltpu.VMEM)
 
     def spec_l(pos_q):
@@ -286,7 +296,7 @@ def _bwd_pallas(res, g, causal: bool, block_q: int, block_k: int,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
         grid=(B * H, L // bq, L // bk),
-        in_specs=[spec_q(0), spec_k(1), spec_k(1), spec_q(0),
+        in_specs=[spec_q(0), spec_k(1), spec_k(1, Dv), spec_q(0, Dv),
                   spec_l(0), spec_l(0)],
         out_specs=[spec_q(0)],
         out_shape=[jax.ShapeDtypeStruct((B * H, L, D), q.dtype)],
@@ -299,35 +309,38 @@ def _bwd_pallas(res, g, causal: bool, block_q: int, block_k: int,
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
         grid=(B * H, L // bk, L // bq),
-        in_specs=[spec_q(1), spec_k(0), spec_k(0), spec_q(1),
+        in_specs=[spec_q(1), spec_k(0), spec_k(0, Dv), spec_q(1, Dv),
                   spec_l(1), spec_l(1)],
-        out_specs=[spec_k(0), spec_k(0)],
+        out_specs=[spec_k(0), spec_k(0, Dv)],
         out_shape=[jax.ShapeDtypeStruct((B * H, L, D), k.dtype),
-                   jax.ShapeDtypeStruct((B * H, L, D), v.dtype)],
+                   jax.ShapeDtypeStruct((B * H, L, Dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, D), f32),
-                        pltpu.VMEM((bk, D), f32)],
+                        pltpu.VMEM((bk, Dv), f32)],
         interpret=interpret,
     )(qr, kr, vr, gr, lse128, dlt128)
 
     def back(x):
-        return x.reshape(B, H, L, D).transpose(0, 2, 1, 3)
+        return x.reshape(B, H, L, x.shape[-1]).transpose(0, 2, 1, 3)
 
     return back(dq), back(dk), back(dv)
 
 
-def _bwd_blockwise(res, g, causal: bool, block_k: int):
+def _bwd_blockwise(res, g, causal: bool, block_k: int,
+                   scale: Optional[float] = None):
     """Memory-efficient backward: recompute P blockwise from saved lse.
     (Plain-XLA reference path, selected via ``bwd_impl="xla"`` — the
     semantics oracle the Pallas backward kernels are tested against.)"""
     q, k, v, out, lse = res  # q,k,v,out: [B,L,H,D]; lse: [B*H, L]
     B, L, H, D = q.shape
-    scale = 1.0 / (D ** 0.5)
+    Dv = v.shape[-1]
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
     f32 = jnp.float32
     qf = q.astype(f32).transpose(0, 2, 1, 3).reshape(B * H, L, D)
     kf = k.astype(f32).transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    vf = v.astype(f32).transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    of = out.astype(f32).transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    gf = g.astype(f32).transpose(0, 2, 1, 3).reshape(B * H, L, D)
+    vf = v.astype(f32).transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
+    of = out.astype(f32).transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
+    gf = g.astype(f32).transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
 
     delta = jnp.sum(of * gf, axis=-1)  # [BH, L] = rowsum(dO ∘ O)
     bk = min(block_k, L)
@@ -353,16 +366,16 @@ def _bwd_blockwise(res, g, causal: bool, block_k: int):
     dq0 = jnp.zeros_like(qf)
     dq, (dks, dvs) = jax.lax.scan(kv_block, dq0, jnp.arange(nk))
     dk = jnp.moveaxis(dks, 0, 1).reshape(B * H, L, D)
-    dv = jnp.moveaxis(dvs, 0, 1).reshape(B * H, L, D)
+    dv = jnp.moveaxis(dvs, 0, 1).reshape(B * H, L, Dv)
 
     def back(x):
-        return x.reshape(B, H, L, D).transpose(0, 2, 1, 3)
+        return x.reshape(B, H, L, x.shape[-1]).transpose(0, 2, 1, 3)
 
     return (back(dq).astype(q.dtype), back(dk).astype(k.dtype),
             back(dv).astype(v.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -372,13 +385,15 @@ def flash_attention(
     block_k: int = 1024,
     interpret: Optional[bool] = None,
     bwd_impl: str = "pallas",
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Fused attention over [B, L, H, D].  ``interpret=None`` auto-selects
-    the Pallas interpreter off-TPU (slow, exact) and compiled mode on TPU.
-    ``bwd_impl``: "pallas" = fused dq/dk/dv kernels (default); "xla" = the
-    blockwise-recompute reference path."""
+    """Fused attention over ``q, k`` [B, L, H, D] and ``v`` [B, L, H, Dv].
+    ``interpret=None`` auto-selects the Pallas interpreter off-TPU (slow,
+    exact) and compiled mode on TPU.  ``bwd_impl``: "pallas" = fused
+    dq/dk/dv kernels (default); "xla" = the blockwise-recompute reference
+    path.  ``scale`` multiplies the scores (default ``D ** -0.5``)."""
     out, _ = _flash_fwd(q, k, v, causal, block_q, block_k,
-                        _resolve_interpret(interpret))
+                        _resolve_interpret(interpret), scale)
     return out
 
 
@@ -389,8 +404,9 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
 
 
 def flash_attention_on_mesh(q, k, v, causal: bool,
-                            mesh: Optional[Mesh]) -> jnp.ndarray:
-    """``flash_attention`` inside a GSPMD program over ``mesh``.
+                            mesh: Optional[Mesh], **kernel_kw) -> jnp.ndarray:
+    """``flash_attention`` inside a GSPMD program over ``mesh``;
+    ``kernel_kw`` (block sizes, ``scale``) goes to the kernel.
 
     A Mosaic kernel has no SPMD partitioning rule (the TPU compiler
     refuses it: "Mosaic kernels cannot be automatically partitioned"), so
@@ -404,7 +420,7 @@ def flash_attention_on_mesh(q, k, v, causal: bool,
     (the explicit-collectives step), this is the bare call."""
     if (mesh is None or mesh.size == 1
             or jax.sharding.get_abstract_mesh().manual_axes):
-        return flash_attention(q, k, v, causal)
+        return flash_attention(q, k, v, causal, **kernel_kw)
 
     def axis(name: str, dim: int) -> Optional[str]:
         fits = name in mesh.axis_names and dim % mesh.shape[name] == 0
@@ -412,7 +428,7 @@ def flash_attention_on_mesh(q, k, v, causal: bool,
 
     spec = P(axis("data", q.shape[0]), None, axis("model", q.shape[2]), None)
     return jax.shard_map(
-        lambda q, k, v: flash_attention(q, k, v, causal),
+        lambda q, k, v: flash_attention(q, k, v, causal, **kernel_kw),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
@@ -430,23 +446,23 @@ def pick_attention_impl(L: int, attn_impl: str = "auto") -> str:
     return "dense"
 
 
-def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, bwd_impl):
+def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, bwd_impl, scale):
     out, lse = _flash_fwd(q, k, v, causal, block_q, block_k,
-                          _resolve_interpret(interpret))
+                          _resolve_interpret(interpret), scale)
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, block_q, block_k, interpret, bwd_impl, res, g):
+def _fa_bwd(causal, block_q, block_k, interpret, bwd_impl, scale, res, g):
     if bwd_impl == "pallas":
         # The dq pass reuses the forward's q-block size; the dk/dv pass
         # accumulates over q blocks with the same tiling.
         return _bwd_pallas(res, g, causal, block_q, block_k,
-                           _resolve_interpret(interpret))
+                           _resolve_interpret(interpret), scale)
     if bwd_impl != "xla":
         raise ValueError(
             f"unknown bwd_impl {bwd_impl!r}: expected 'pallas' or 'xla'"
         )
-    return _bwd_blockwise(res, g, causal, block_k)
+    return _bwd_blockwise(res, g, causal, block_k, scale)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)

@@ -19,6 +19,11 @@ of the TransformerLM with the framework's parallelism menu —
   --tp/--sp/--pp, composes with --fsdp: non-expert leaves and the free
   dims of the expert stacks shard over ``data``)
 - remaining devices form the ``data`` axis (gradient psum)
+- ``--model-config FILE`` a decoder built from a configuration file with
+  the catalog's key names (``models/decoder.py``: latent attention, shared
+  and routed experts, an untied head) in place of the TransformerLM, trained
+  with the file's AdamW over the ``data`` axis; training only (no serving,
+  no vision tower); ``--rehearse`` lays the file's CPU preset over it
 
 Examples (8 simulated chips):
 
@@ -28,6 +33,9 @@ Examples (8 simulated chips):
         --seq-len 8192 -b 8 --steps 20
     python -m pytorch_distributed_tpu.recipes.lm_pretrain --pp 4 \
         --n-layers 8 -b 16 --steps 20
+    python -m pytorch_distributed_tpu.recipes.lm_pretrain --rehearse \
+        --model-config benchmark/configs/kimi-vl-a3b-ep8.json \
+        --seq-len 64 -b 8 --steps 20
 """
 
 from __future__ import annotations
@@ -49,8 +57,55 @@ from pytorch_distributed_tpu.train.lm import (
 from pytorch_distributed_tpu.utils.compile_cache import enable_compile_cache
 
 
+def load_decoder_setup(path: str, rehearse: bool = False) -> dict:
+    """What ``--model-config FILE`` trains with: the model's
+    ``DecoderConfig``, the file's ``optimizer`` group and its fused-loss
+    chunk count (``training.fused_ce_chunks``)."""
+    import json
+
+    from pytorch_distributed_tpu.models.decoder import DecoderConfig, overlay
+
+    with open(path) as f:
+        cfg = json.load(f)
+    if rehearse:
+        cfg = overlay(cfg, cfg.get("rehearse", {}))
+    return {"config": DecoderConfig.from_dict(cfg),
+            "optimizer": cfg["optimizer"],
+            "fused_ce_chunks": cfg.get("training", {}).get(
+                "fused_ce_chunks", 0)}
+
+
+def decoder_tx(optimizer: dict, lr: float, warmup_steps: int = 0,
+               steps: int = 0):
+    """The optax ``tx`` of a ``--model-config`` run: the file's AdamW at
+    ``lr``, constant, or with ``--warmup-steps`` the recipe's linear
+    warm-up and cosine decay to a tenth."""
+    from pytorch_distributed_tpu.train.optim import adamw
+
+    if optimizer.get("name", "adamw") != "adamw":
+        raise SystemExit(f"no optimizer {optimizer['name']!r}: adamw")
+    rate = lr
+    if warmup_steps > 0:
+        import optax
+
+        rate = optax.warmup_cosine_decay_schedule(
+            0.0, lr, warmup_steps, steps, end_value=0.1 * lr)
+    return adamw(optimizer, rate)
+
+
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description="TPU LM pretraining (long context)")
+    p.add_argument("--model-config", type=str, default=None, metavar="FILE",
+                   dest="model_config",
+                   help="build the decoder from this configuration file "
+                        "(models/decoder.py) instead of the TransformerLM, "
+                        "and train it with the file's AdamW (--lr overrides "
+                        "its rate) and fused-loss chunks; --vocab, "
+                        "--d-model, --n-heads, --n-layers, --ep and "
+                        "--moe-top-k are then not read")
+    p.add_argument("--rehearse", action="store_true",
+                   help="with --model-config: lay the file's 'rehearse' "
+                        "group (a CPU preset, every ratio kept) over it")
     p.add_argument("--vocab", type=int, default=1024)
     p.add_argument("--d-model", type=int, default=256)
     p.add_argument("--n-heads", type=int, default=8)
@@ -59,7 +114,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("-b", "--batch-size", type=int, default=32,
                    help="global batch (sequences)")
     p.add_argument("--steps", type=int, default=100)
-    p.add_argument("--lr", type=float, default=1e-2)
+    p.add_argument("--lr", type=float, default=None,
+                   help="learning rate (default 1e-2; with --model-config "
+                        "the file's optimizer.lr)")
     p.add_argument("--warmup-steps", type=int, default=0,
                    help=">0: linear warmup then cosine decay to 10%% of "
                         "--lr over --steps (fixed lr otherwise)")
@@ -310,6 +367,22 @@ def main(argv=None) -> float:
     enable_compile_cache()
     ctx = initialize()
     n = jax.device_count()
+    decoder = None
+    if args.model_config:
+        if max(args.tp, args.sp, args.pp, args.ep) > 1 or args.fsdp:
+            raise SystemExit("--model-config trains over the data axis "
+                             "only: drop --tp/--sp/--pp/--ep/--fsdp")
+        if args.generate:
+            raise SystemExit("--model-config has no decoding path")
+        decoder = load_decoder_setup(args.model_config, args.rehearse)
+        args.vocab = decoder["config"].vocab_size
+        args.fused_ce = args.fused_ce or decoder["fused_ce_chunks"]
+        if args.lr is None:
+            args.lr = decoder["optimizer"]["lr"]
+    elif args.rehearse:
+        raise SystemExit("--rehearse needs --model-config")
+    if args.lr is None:
+        args.lr = 1e-2
     if args.ep > 1 and (args.tp > 1 or args.sp > 1 or args.pp > 1):
         raise SystemExit("--ep is exclusive (MoE model variant); "
                          "--tp composes with --sp or --pp")
@@ -446,12 +519,17 @@ def main(argv=None) -> float:
             axes.append("model")
             shape.append(args.tp)
         mesh = build_mesh(MeshSpec(tuple(axes), tuple(shape)))
-        model = TransformerLM(
-            vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
-            n_layers=args.n_layers, dtype=dtype,
-            mesh=mesh if args.sp > 1 else None, ring=args.sp > 1,
-            sp_impl=args.sp_impl,
-        )
+        if decoder is not None:
+            from pytorch_distributed_tpu.models.decoder import DecoderLM
+
+            model = DecoderLM(decoder["config"], dtype=dtype)
+        else:
+            model = TransformerLM(
+                vocab_size=args.vocab, d_model=args.d_model,
+                n_heads=args.n_heads, n_layers=args.n_layers, dtype=dtype,
+                mesh=mesh if args.sp > 1 else None, ring=args.sp > 1,
+                sp_impl=args.sp_impl,
+            )
         specs = "tp" if args.tp > 1 else None
 
     if args.text_glob:
@@ -519,6 +597,12 @@ def main(argv=None) -> float:
             from pytorch_distributed_tpu.train.lm import warmup_cosine_lr
 
             schedule = warmup_cosine_lr(args.lr, args.warmup_steps, args.steps)
+        tx = None
+        if decoder is not None:
+            # the schedule lives inside the tx (make_lm_train_step): the
+            # host-side one only labels the log
+            tx = decoder_tx(decoder["optimizer"], args.lr,
+                            args.warmup_steps, args.steps)
         # Preemption guard (previously only the image Trainer self-
         # installed one; the LM recipe ran unguarded): --preempt-signals
         # SIGTERM (pod reclaim) by default, SIGINT opt-in for interactive
@@ -569,6 +653,7 @@ def main(argv=None) -> float:
             metrics_port=args.metrics_port,
             alerts=args.alerts,
             step_attr=args.step_attr,
+            tx=tx,
         )
         try:
             final_loss = trainer.fit(args.steps, print_freq=args.print_freq)

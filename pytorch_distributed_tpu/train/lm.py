@@ -144,12 +144,55 @@ def warmup_cosine_lr(base_lr: float, warmup_steps: int, total_steps: int,
     return schedule
 
 
+def head_matrix(model, tree):
+    """The ``[V, d]`` matrix the hidden rows are projected against, out of
+    a tree shaped like the params: the model's own (``head_matrix``, an
+    untied head) or the tied embedding."""
+    own = getattr(model, "head_matrix", None)
+    return own(tree) if own is not None else tree["embed"]["embedding"]
+
+
+def _model_state_collection(model) -> Optional[str]:
+    """The flax collection of a model's non-gradient state (the configured
+    decoder's selection bias), kept in ``TrainState.batch_stats``."""
+    return getattr(model, "state_collection", None)
+
+
+def lm_state_specs(param_specs, *, residual: bool = False,
+                   momentum_specs=None, tx=None, params=None,
+                   model_state=None):
+    """``parallel/tp.state_specs`` for the LM steps, plus what a
+    configured decoder and an optax ``tx`` add: the optimizer state laid
+    out like the params it mirrors (counts replicated), and the model's
+    non-gradient state replicated (``model_state``: its tree, or ``True``
+    for a one-leaf prefix, which ``jit`` takes and ``device_put`` does
+    not)."""
+    from pytorch_distributed_tpu.parallel.tp import replicated_like, state_specs
+
+    specs = state_specs(param_specs, residual=residual,
+                        momentum_specs=momentum_specs)
+    if tx is not None:
+        import optax
+
+        if params is None:
+            raise ValueError("an optax tx needs params= to lay out its state")
+        specs = specs.replace(momentum=optax.tree_map_params(
+            tx, lambda _, spec: spec, jax.eval_shape(tx.init, params),
+            param_specs, transform_non_params=lambda _: P()))
+    if model_state is True:
+        specs = specs.replace(batch_stats=P())
+    elif model_state:
+        specs = specs.replace(batch_stats=replicated_like(model_state))
+    return specs
+
+
 def resolve_fused_ce_mode(
     mode: str,
     param_specs,
     mesh: Mesh,
     vocab_size: Optional[int],
     data_axis: str = "data",
+    model=None,
 ) -> Tuple[str, Optional[str]]:
     """Pick the fused-CE sharding variant (ops/fused_ce.py) for this
     mesh/spec combination → ``(mode, model_axis)``.
@@ -171,7 +214,7 @@ def resolve_fused_ce_mode(
         raise ValueError(
             f"fused_ce_mode must be auto|replicated|dp|tp, got {mode!r}")
     try:
-        embed_spec = param_specs["embed"]["embedding"]
+        embed_spec = head_matrix(model, param_specs)
     except (KeyError, TypeError):
         embed_spec = P()
     mesh_shape = dict(mesh.shape)
@@ -217,6 +260,7 @@ def make_lm_train_step(
     overlap: str = "none",
     bucket_mb: float = 4.0,
     explicit_collectives: bool = False,
+    tx=None,
 ):
     """Jitted LM step; ``param_specs`` is a PartitionSpec pytree from
     parallel/tp.py (``replicated_like`` for pure DP, ``tp_specs`` for TP).
@@ -271,13 +315,48 @@ def make_lm_train_step(
     ``param_specs`` so TP layouts keep their model-axis dims) while the
     update math is untouched — XLA derives the weight-update sharding
     from the layout alone.  Per-device optimizer bytes drop to ~1/N;
-    ``params`` (the concrete param tree) is required to size the specs."""
+    ``params`` (the concrete param tree) is required to size the specs.
+
+    ``tx``: an optional optax ``GradientTransformation``, as
+    ``train/steps.make_train_step`` takes one: its state lives in
+    ``state.momentum`` (laid out like the params; ``params`` is required),
+    and the ``lr`` argument, ``momentum`` and ``weight_decay`` are then
+    inactive: schedule and regularisation live inside ``tx``.  ``None``
+    keeps the built-in SGD and the program it lowers to.
+
+    A model with a ``state_collection`` (models/decoder.py: the experts'
+    selection bias) has that state read from ``state.batch_stats``, updated
+    after the gradients by its own ``update_state`` from the counters the
+    forward pass sowed (no gradient, no optimizer), and its routing
+    counters (``step_counters``) added to the metrics."""
     from pytorch_distributed_tpu.parallel import overlap as overlap_lib
     from pytorch_distributed_tpu.parallel import zero as zero_lib
 
     model = bind_mesh(model, mesh)
     zero_mode = zero_lib.resolve_zero(zero)
     overlap_mode = overlap_lib.resolve_overlap(overlap)
+    state_col = _model_state_collection(model)
+    if tx is not None:
+        import warnings
+
+        warnings.warn(
+            "make_lm_train_step: tx provided — the lr argument (and "
+            "LMTrainer's schedule) plus momentum/weight_decay are "
+            "INACTIVE; configure them inside the optax transformation.",
+            stacklevel=2)
+    if state_col or tx is not None:
+        manual = getattr(model, "has_manual_grads", lambda: False)()
+        bad = [what for what, cond in [
+            ("explicit collectives / overlap",
+             explicit_collectives or overlap_mode == "bucketed"),
+            ("the 1F1B pipeline", manual),
+            (f"accum_steps={accum_steps}", state_col and accum_steps > 1),
+            (f"zero={zero_mode!r}", zero_mode != "none"),
+        ] if cond]
+        if bad:
+            raise ValueError(
+                "an optax tx / a model with non-gradient state run on the "
+                "plain GSPMD LM step only; got " + "; ".join(bad))
     if explicit_collectives or overlap_mode == "bucketed":
         manual = getattr(model, "has_manual_grads", lambda: False)()
         unsupported = [
@@ -336,9 +415,16 @@ def make_lm_train_step(
     if fused_ce_chunks:
         ce_mode, ce_model_axis = resolve_fused_ce_mode(
             fused_ce_mode, param_specs, mesh,
-            getattr(model, "vocab_size", None), data_axis)
+            getattr(model, "vocab_size", None), data_axis, model=model)
+    # what the forward pass may write besides the sown losses
+    mutable = ["losses", "counters"] if state_col else ["losses"]
 
     def step(state: TrainState, tokens: jnp.ndarray, lr: jnp.ndarray):
+        def variables(params):
+            if state_col:
+                return {"params": params, state_col: state.batch_stats}
+            return {"params": params}
+
         def loss_fn(params, toks):
             # named_scope: forward ops carry the phase name into XPlane
             # traces (autodiff derives the backward names from it) —
@@ -362,7 +448,7 @@ def make_lm_train_step(
                 )
 
                 hidden, sown = model.apply(
-                    {"params": params}, toks, mutable=["losses"],
+                    variables(params), toks, mutable=mutable,
                     return_hidden=True,
                 )
                 d = hidden.shape[-1]
@@ -370,7 +456,7 @@ def make_lm_train_step(
                 h = hidden[:, :-1].reshape(-1, d).astype(cdt)
                 t = toks[:, 1:].reshape(-1)
                 w = jnp.ones(t.shape, jnp.float32)
-                e = params["embed"]["embedding"].astype(cdt)
+                e = head_matrix(model, params).astype(cdt)
                 if ce_mode == "tp":
                     loss_sum, correct = fused_ce_sums_tp(
                         h, e, t, w, fused_ce_chunks, mesh,
@@ -387,11 +473,11 @@ def make_lm_train_step(
                 for leaf in jax.tree_util.tree_leaves(
                         sown.get("losses", {})):
                     loss = loss + leaf
-                return loss, correct / ntok
+                return loss, (correct / ntok, sown.get("counters", {}))
             # mutable=["losses"] collects sown auxiliary objectives (the MoE
             # router's load-balancing loss); {} for dense models.
             logits, sown = model.apply(
-                {"params": params}, toks, mutable=["losses"]
+                variables(params), toks, mutable=mutable
             )
             vocab = logits.shape[-1]
             loss = cross_entropy(
@@ -405,17 +491,17 @@ def make_lm_train_step(
                     jnp.float32
                 )
             )
-            return loss, acc
+            return loss, (acc, sown.get("counters", {}))
 
+        seen = {}
         if manual:
             # 1F1B pipeline: gradients come from the schedule's own
             # interleaved scan, not autodiff over the whole step
             # (models/pipeline_lm.py loss_and_grads).
             (loss, acc), grads = model.loss_and_grads(state.params, tokens)
         elif accum_steps == 1:
-            (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                state.params, tokens
-            )
+            (loss, (acc, seen)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(state.params, tokens)
         else:
             B = tokens.shape[0]
             if B % accum_steps:
@@ -430,7 +516,7 @@ def make_lm_train_step(
 
             def body(carry, mb):
                 g_acc, loss_acc, acc_acc = carry
-                (l, a), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                (l, (a, _)), g = jax.value_and_grad(loss_fn, has_aux=True)(
                     state.params, mb
                 )
                 g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
@@ -473,30 +559,44 @@ def make_lm_train_step(
             grads = jax.tree_util.tree_map(
                 lambda g: g.astype(gc_cast).astype(jnp.float32), grads)
         with jax.named_scope("optimizer"):
-            new_params, new_momentum = sgd_update(
-                grads, state.momentum, state.params, lr,
-                momentum=momentum, weight_decay=weight_decay,
-            )
+            if tx is None:
+                new_params, new_momentum = sgd_update(
+                    grads, state.momentum, state.params, lr,
+                    momentum=momentum, weight_decay=weight_decay,
+                )
+            else:
+                import optax
+
+                updates, new_momentum = tx.update(
+                    grads, state.momentum, state.params)
+                new_params = optax.apply_updates(state.params, updates)
         metrics = {"loss": loss, "acc": acc * 100.0}
+        new_model_state = state.batch_stats
+        if state_col:
+            new_model_state = model.update_state(state.batch_stats, seen)
+            metrics.update(model.step_counters(new_model_state, seen))
         if guard_nonfinite:
             bad = nonfinite_flag(loss, gnorm)
             new_params = gate_update(bad, state.params, new_params)
             new_momentum = gate_update(bad, state.momentum, new_momentum)
             new_residual = gate_update(bad, state.residual, new_residual)
+            if state_col:
+                new_model_state = gate_update(bad, state.batch_stats,
+                                              new_model_state)
             metrics["nonfinite"] = bad
-        new_state = TrainState(state.step + 1, new_params, state.batch_stats,
+        new_state = TrainState(state.step + 1, new_params, new_model_state,
                                new_momentum, new_residual)
         if log_norms:
             metrics["grad_norm"] = gnorm
             metrics["param_norm"] = tree_l2_norm(new_params)
         return new_state, metrics
 
-    from pytorch_distributed_tpu.parallel.tp import state_specs
-
     state_shardings = jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s),
-        state_specs(param_specs, residual=gc_mode in qcomm.QUANTIZED_MODES,
-                    momentum_specs=mom_specs),
+        lm_state_specs(param_specs,
+                       residual=gc_mode in qcomm.QUANTIZED_MODES,
+                       momentum_specs=mom_specs, tx=tx, params=params,
+                       model_state=bool(state_col)),
     )
     token_sharding = NamedSharding(mesh, P(data_axis, None))
     return jax.jit(
@@ -661,7 +761,7 @@ def _make_lm_train_step_explicit(
 
 def make_lm_eval_step(model, mesh: Mesh, param_specs, data_axis: str = "data",
                       has_residual: bool = False, momentum_specs=None,
-                      residual_specs=None):
+                      residual_specs=None, tx=None, params=None):
     """Jitted held-out eval step returning exact token-weighted *sums*
     (loss·count, correct, count) — the LM counterpart of the image harness's
     ``make_eval_step`` (reference validate() pattern,
@@ -674,14 +774,20 @@ def make_lm_eval_step(model, mesh: Mesh, param_specs, data_axis: str = "data",
     the sharded optimizer state on every eval call.  ``residual_specs``
     overrides the residual layout: the bucketed-overlap explicit step
     stores residuals stacked per rank and sharded ``P(data_axis)``, not
-    param-shaped."""
+    param-shaped.  ``tx`` / ``params``: as ``make_lm_train_step`` takes
+    them, for the layout of the optimizer state."""
     model = bind_mesh(model, mesh)
+    state_col = _model_state_collection(model)
 
     def step(state: TrainState, tokens: jnp.ndarray):
         # mutable=["losses"]: MoE models sow the router aux loss even in
         # inference; collected and dropped (eval reports data loss only).
-        logits, _ = model.apply({"params": state.params}, tokens,
-                                mutable=["losses"])
+        variables = {"params": state.params}
+        mutable = ["losses"]
+        if state_col:
+            variables[state_col] = state.batch_stats
+            mutable = ["losses", "counters"]
+        logits, _ = model.apply(variables, tokens, mutable=mutable)
         vocab = logits.shape[-1]
         flat_logits = logits[:, :-1].reshape(-1, vocab)
         flat_targets = tokens[:, 1:].reshape(-1)
@@ -692,10 +798,9 @@ def make_lm_eval_step(model, mesh: Mesh, param_specs, data_axis: str = "data",
         )
         return {"loss_sum": loss * count, "correct": correct, "count": count}
 
-    from pytorch_distributed_tpu.parallel.tp import state_specs
-
-    specs = state_specs(param_specs, residual=has_residual,
-                        momentum_specs=momentum_specs)
+    specs = lm_state_specs(param_specs, residual=has_residual,
+                           momentum_specs=momentum_specs, tx=tx,
+                           params=params, model_state=bool(state_col))
     if residual_specs is not None:
         specs = specs.replace(residual=residual_specs)
     state_shardings = jax.tree_util.tree_map(
@@ -762,8 +867,13 @@ class LMTrainer:
         metrics_port: int = 0,
         alerts: Optional[str] = None,
         step_attr: bool = False,
+        tx=None,
     ):
-        """``lr_schedule``: optional ``step -> lr`` callable (e.g.
+        """``tx``: an optional optax ``GradientTransformation`` in place of
+        the built-in SGD (``make_lm_train_step``): ``lr`` and
+        ``lr_schedule`` are then inactive.
+
+        ``lr_schedule``: optional ``step -> lr`` callable (e.g.
         ``warmup_cosine_lr``) overriding the fixed ``lr``;
         ``clip_grad_norm``: in-graph global-norm gradient clipping;
         ``accum_steps``: gradient accumulation inside the compiled step;
@@ -826,10 +936,7 @@ class LMTrainer:
         floor — a step exceeding ``max(hang_timeout, 4×p95)`` emits a
         ``hang`` ft_event and dumps the ring pre-mortem."""
         from pytorch_distributed_tpu.parallel import zero as zero_lib
-        from pytorch_distributed_tpu.parallel.tp import (
-            replicated_like,
-            shard_state,
-        )
+        from pytorch_distributed_tpu.parallel.tp import replicated_like
 
         self.model = model
         self.mesh = mesh
@@ -846,6 +953,10 @@ class LMTrainer:
         tokens0 = jnp.zeros((init_b, dataset.seq_len), jnp.int32)
         variables = model.init(jax.random.PRNGKey(seed), tokens0)
         params = variables["params"]
+        # non-gradient model state (the configured decoder's selection
+        # bias): TrainState.batch_stats carries it
+        model_state = variables.get(_model_state_collection(model), {})
+        self.tx = tx
         self.param_specs = (
             param_specs if param_specs is not None else replicated_like(params)
         )
@@ -882,7 +993,7 @@ class LMTrainer:
         self._step_kwargs = dict(
             clip_grad_norm=clip_grad_norm, accum_steps=accum_steps,
             fused_ce_chunks=fused_ce_chunks, fused_ce_mode=fused_ce_mode,
-            overlap=self.overlap, bucket_mb=self.bucket_mb,
+            overlap=self.overlap, bucket_mb=self.bucket_mb, tx=tx,
             # in-graph norms only when a metrics sink will consume them
             log_norms=bool(metrics_jsonl), guard_nonfinite=nan_guard)
         self._build_for_mesh(mesh, params)
@@ -893,10 +1004,11 @@ class LMTrainer:
         residual = qcomm.init_residual(
             params, self.grad_compress, explicit=explicit,
             n_data=dict(mesh.shape).get("data", 1))
-        state = TrainState.create({"params": params}, sgd_init(params),
-                                  residual=residual)
-        self.state = shard_state(state, self.param_specs, mesh,
-                                 momentum_specs=self._mom_specs)
+        state = TrainState.create(
+            {"params": params, "batch_stats": model_state},
+            sgd_init(params) if tx is None else tx.init(params),
+            residual=residual)
+        self.state = self._place_state(state, mesh)
         if explicit and self.grad_compress in qcomm.QUANTIZED_MODES:
             self.state = self.state.replace(residual=jax.device_put(
                 self.state.residual, NamedSharding(mesh, P("data"))))
@@ -1022,8 +1134,7 @@ class LMTrainer:
             # Host-numpy leaves → re-shard to this trainer's specs (any
             # mesh shape can resume any mesh shape's checkpoint; the
             # momentum re-shards to the wus layout when zero is on).
-            self.state = shard_state(loaded, self.param_specs, mesh,
-                                     momentum_specs=self._mom_specs)
+            self.state = self._place_state(loaded, mesh)
             ft = meta["ft"]
             self._start_step = max(int(ft["global_step"]), int(ft["step"]))
             if self.ft_guard is not None:
@@ -1032,6 +1143,17 @@ class LMTrainer:
                 self.best_ppl = float(meta["best_acc1"])
             print(f"=> resumed {meta['arch']} from '{resume}' at step "
                   f"{self._start_step}", flush=True)
+
+    def _place_state(self, state: TrainState, mesh: Mesh) -> TrainState:
+        """``state`` on ``mesh`` in the layout the jitted steps keep it."""
+        from pytorch_distributed_tpu.parallel.tp import shard_pytree
+
+        specs = lm_state_specs(
+            self.param_specs,
+            residual=bool(jax.tree_util.tree_leaves(state.residual)),
+            momentum_specs=self._mom_specs, tx=self.tx, params=state.params,
+            model_state=state.batch_stats)
+        return shard_pytree(state, specs, mesh)
 
     def _build_for_mesh(self, mesh: Mesh, params) -> None:
         """Build (or rebuild) every mesh-shape-dependent piece against
@@ -1057,7 +1179,7 @@ class LMTrainer:
             make_lm_eval_step(
                 self.model, mesh, self.param_specs,
                 has_residual=quantized,
-                momentum_specs=self._mom_specs,
+                momentum_specs=self._mom_specs, tx=self.tx, params=params,
                 # bucketed overlap trains the explicit step: residuals are
                 # stacked per rank and sharded over data (_build_for_mesh)
                 residual_specs=(
@@ -1404,6 +1526,7 @@ class LMTrainer:
 
     def fit(self, steps: int, print_freq: int = 10) -> float:
         from pytorch_distributed_tpu.obs import scope
+        from pytorch_distributed_tpu.obs.trace import span
 
         if self.watchdog is not None:
             self.watchdog.install()  # idempotent (re-fit after a fit)
@@ -1459,154 +1582,167 @@ class LMTrainer:
             meters.restart_clock()
             i = start
             while i < steps:
-                # print_freq cadence: the cross-process agreement collective
-                # (see utils/preempt.py) must run at the same step on every
-                # rank, and stays off the per-step hot path.
-                if (self.preempt is not None and i % print_freq == 0
-                        and self._preempt_agreed()):
-                    print(f"=> preemption signal: stopping at step {i}",
-                          flush=True)
-                    self.obs.log_event("preempt", step=i)
-                    preempted = True
-                    break
-                if self.chaos is not None:
-                    self.chaos.on_step(self, i)
-                if self.elastic is not None:
-                    # Membership epochs are coordinator-committed and read
-                    # by every rank at the same step — an agreed value,
-                    # not a local liveness probe (synclint would otherwise
-                    # flag the re-mesh below as a divergent collective).
-                    chg = self.elastic.poll(i)  # synclint: agreement
-                    if chg is not None:
-                        # Membership changed: rebuild against the survivor
-                        # set and restart the token stream at the resume
-                        # step (a shrink rewinds to the last-good snapshot;
-                        # the step-indexed batching regenerates the same
-                        # tokens, so retrained steps replay, not drift).
-                        token_iter.close()
-                        completed = i = self._apply_remesh(chg, at_step=i)
-                        token_iter = self._token_iter(i, steps)
-                        tokens_per_step = (self.batch_size
-                                           * self.dataset.seq_len)
-                        lr_val = None  # re-push the LR to the new mesh
-                        meters.restart_clock()
-                        continue
-                # Attribution windows (--step-attr): data_wait wraps
-                # batch acquisition *and* the chaos on_batch hook, so
-                # injected loader delay lands in the measured component.
-                sa = self.stepattr
-                _dw = sa.data_wait if sa is not None else nullcontext
-                with _dw():
-                    tokens = next(token_iter)
-                if self.chaos is not None:
+                # One `step` span an iteration (obs/trace.py), as in
+                # Trainer.train_epoch: its children are the feeder's
+                # `data_wait`, `dispatch` and the `host_sync` drains.
+                with span("step", id=i):
+                    # print_freq cadence: the cross-process agreement collective
+                    # (see utils/preempt.py) must run at the same step on every
+                    # rank, and stays off the per-step hot path.
+                    if (self.preempt is not None and i % print_freq == 0
+                            and self._preempt_agreed()):
+                        print(f"=> preemption signal: stopping at step {i}",
+                              flush=True)
+                        self.obs.log_event("preempt", step=i)
+                        preempted = True
+                        break
+                    if self.chaos is not None:
+                        self.chaos.on_step(self, i)
+                    if self.elastic is not None:
+                        # Membership epochs are coordinator-committed and read
+                        # by every rank at the same step — an agreed value,
+                        # not a local liveness probe (synclint would otherwise
+                        # flag the re-mesh below as a divergent collective).
+                        chg = self.elastic.poll(i)  # synclint: agreement
+                        if chg is not None:
+                            # Membership changed: rebuild against the survivor
+                            # set and restart the token stream at the resume
+                            # step (a shrink rewinds to the last-good snapshot;
+                            # the step-indexed batching regenerates the same
+                            # tokens, so retrained steps replay, not drift).
+                            token_iter.close()
+                            completed = i = self._apply_remesh(chg, at_step=i)
+                            token_iter = self._token_iter(i, steps)
+                            tokens_per_step = (self.batch_size
+                                               * self.dataset.seq_len)
+                            lr_val = None  # re-push the LR to the new mesh
+                            meters.restart_clock()
+                            continue
+                    # Attribution windows (--step-attr): data_wait wraps
+                    # batch acquisition *and* the chaos on_batch hook, so
+                    # injected loader delay lands in the measured component.
+                    sa = self.stepattr
+                    _dw = sa.data_wait if sa is not None else nullcontext
                     with _dw():
-                        tokens = self.chaos.on_batch(i, tokens)
-                val = (self.lr_schedule(i)
-                       if self.lr_schedule is not None else self.lr)
-                if self.ft_guard is not None:
-                    val = val * self.ft_guard.lr_scale
-                val = val * self._elastic_lr_scale
-                if val != lr_val:
-                    lr_val, lr = val, jnp.float32(val)
-                if ((self._comm_ledger_path is not None
-                        or self._mem_ledger_path is not None)
-                        and self._comm_fields is None):
-                    self._emit_ledgers(tokens, lr)
-                if self.flight is not None:
-                    # Ring: step window + collective region (labelled with
-                    # the ledger's dominant entry when the AOT lowering
-                    # ran) — two deque appends, no sync/I/O.
-                    self.flight.step_begin(i)
-                    fc = self._flight_coll or {}
-                    self.flight.coll_enter(i, kind=fc.get("kind"),
-                                           bytes=fc.get("bytes"),
-                                           name=fc.get("name"))
-                if self.chaos is not None:
-                    self.chaos.on_collective(self, i)
-                _dev = sa.device if sa is not None else nullcontext
-                _hs = sa.host_sync if sa is not None else nullcontext
-                with scope("lm_step"), self._wd_watch("lm_step", i), _dev():
-                    self.state, metrics = self.step_fn(self.state, tokens, lr)
-                    if sa is not None:
-                        # The step's blocking transfer: without it, async
-                        # dispatch smears step N's device time into N+1's
-                        # windows.  Only when --step-attr opted in;
-                        # overhead fenced <2% p50 in RESULTS_stepattr.json.
-                        jax.block_until_ready(metrics)  # shardlint: allow-sync
-                if self.flight is not None:
-                    self.flight.coll_exit(i)
-                    self.flight.step_end(i)
-                completed = i + 1
-                with _hs():
-                    dt = meters.update(metrics, self.batch_size)
-                extra = (dict(self._mfu.fields(dt))
-                         if self._mfu is not None else {})
-                if self._comm_fields:
-                    extra.update(self._comm_fields)
-                if sa is not None:
-                    extra.update(sa.fields(dt))
-                # log_step's lazy-flush scalar drain accrues to the *next*
-                # step's host_sync window (its dt covers this wall time).
-                with _hs():
-                    self.obs.log_step(
-                        i, step_time=dt, n_items=tokens_per_step, lr=lr,
-                        scalars=dict(metrics),  # incl. norms when log_norms on
-                        extra=extra or None,
-                    )
-                # booked after the first step's record so the event's
-                # timestamp cannot widen the post-hoc goodput wall span
-                # back across the step-0 compile
-                if sa is not None and not self._stepattr_phases_booked:
-                    self._book_stepattr_phases()
-                if self.hb is not None:
-                    from pytorch_distributed_tpu.obs import (
-                        sample_process_memory,
-                    )
-                    self.hb.beat(i, step_time_ema=self.obs.ema,
-                                 last_ft=self.obs.last_event_kind,
-                                 mem_bytes=sample_process_memory(),
-                                 data_wait_ms=(sa.data_wait_ema_ms
-                                               if sa is not None else None))
+                        tokens = next(token_iter)
+                    if self.chaos is not None:
+                        with _dw():
+                            tokens = self.chaos.on_batch(i, tokens)
+                    val = (self.lr_schedule(i)
+                           if self.lr_schedule is not None else self.lr)
+                    if self.ft_guard is not None:
+                        val = val * self.ft_guard.lr_scale
+                    val = val * self._elastic_lr_scale
+                    if val != lr_val:
+                        lr_val, lr = val, jnp.float32(val)
+                    if ((self._comm_ledger_path is not None
+                            or self._mem_ledger_path is not None)
+                            and self._comm_fields is None):
+                        self._emit_ledgers(tokens, lr)
                     if self.flight is not None:
-                        self.flight.heartbeat(
-                            {"step": i,
-                             "last_ft": self.obs.last_event_kind})
-                meters.maybe_display(i, print_freq)
-                at_save = (self.save_steps > 0
-                           and completed % self.save_steps == 0)
-                if self.ft_guard is not None:
-                    # Lazy-sync policy: flags buffer unconverted and drain
-                    # every check_every steps — forced at a save boundary so
-                    # a snapshot never races an undetected divergence.
-                    rollback = self.ft_guard.observe(
-                        i, metrics.get("nonfinite"))
+                        # Ring: step window + collective region (labelled with
+                        # the ledger's dominant entry when the AOT lowering
+                        # ran) — two deque appends, no sync/I/O.
+                        self.flight.step_begin(i)
+                        fc = self._flight_coll or {}
+                        self.flight.coll_enter(i, kind=fc.get("kind"),
+                                               bytes=fc.get("bytes"),
+                                               name=fc.get("name"))
+                    if self.chaos is not None:
+                        self.chaos.on_collective(self, i)
+                    _dev = sa.device if sa is not None else nullcontext
+                    _hs = sa.host_sync if sa is not None else nullcontext
+                    with span("dispatch") as booked, scope("lm_step"), \
+                            self._wd_watch("lm_step", i), _dev():
+                        self.state, metrics = self.step_fn(
+                            self.state, tokens, lr)
+                        # a model's own counters (``counter_names``: a
+                        # configured decoder's routing) ride on the record
+                        # as unready device scalars: whoever reads the
+                        # record converts them, the loop does not
+                        booked.set(**{k: metrics[k] for k in getattr(
+                            self.model, "counter_names", ())})
+                        if sa is not None:
+                            # The step's blocking transfer: without it, async
+                            # dispatch smears step N's device time into N+1's
+                            # windows.  Only when --step-attr opted in;
+                            # overhead fenced <2% p50 in RESULTS_stepattr.json.
+                            jax.block_until_ready(metrics)  # shardlint: allow-sync
+                    if self.flight is not None:
+                        self.flight.coll_exit(i)
+                        self.flight.step_end(i)
+                    completed = i + 1
+                    with span("host_sync"), _hs():
+                        dt = meters.update(metrics, self.batch_size)
+                    extra = (dict(self._mfu.fields(dt))
+                             if self._mfu is not None else {})
+                    if self._comm_fields:
+                        extra.update(self._comm_fields)
+                    if sa is not None:
+                        extra.update(sa.fields(dt))
+                    # log_step's lazy-flush scalar drain accrues to the *next*
+                    # step's host_sync window (its dt covers this wall time).
+                    with span("host_sync"), _hs():
+                        self.obs.log_step(
+                            i, step_time=dt, n_items=tokens_per_step, lr=lr,
+                            scalars=dict(metrics),  # incl. norms when log_norms on
+                            extra=extra or None,
+                        )
+                    # booked after the first step's record so the event's
+                    # timestamp cannot widen the post-hoc goodput wall span
+                    # back across the step-0 compile
+                    if sa is not None and not self._stepattr_phases_booked:
+                        self._book_stepattr_phases()
+                    if self.hb is not None:
+                        from pytorch_distributed_tpu.obs import (
+                            sample_process_memory,
+                        )
+                        self.hb.beat(i, step_time_ema=self.obs.ema,
+                                     last_ft=self.obs.last_event_kind,
+                                     mem_bytes=sample_process_memory(),
+                                     data_wait_ms=(sa.data_wait_ema_ms
+                                                   if sa is not None else None))
+                        if self.flight is not None:
+                            self.flight.heartbeat(
+                                {"step": i,
+                                 "last_ft": self.obs.last_event_kind})
+                    with span("host_sync"):
+                        meters.maybe_display(i, print_freq)
+                    at_save = (self.save_steps > 0
+                               and completed % self.save_steps == 0)
+                    if self.ft_guard is not None:
+                        # Lazy-sync policy: flags buffer unconverted and drain
+                        # every check_every steps — forced at a save boundary so
+                        # a snapshot never races an undetected divergence.
+                        rollback = self.ft_guard.observe(
+                            i, metrics.get("nonfinite"))
+                        if at_save:
+                            # Agreed: the drained flag is the in-step
+                            # all-reduced nonfinite count — every rank reads
+                            # the identical verdict at the same boundary.
+                            rollback = self.ft_guard.drain() or rollback  # synclint: agreement
+                        if rollback:
+                            self._rollback(i)
+                        # A flagged streak means the current state is suspect —
+                        # don't refresh the last-good snapshot from it.
+                        at_save = at_save and self.ft_guard.consecutive == 0
                     if at_save:
-                        # Agreed: the drained flag is the in-step
-                        # all-reduced nonfinite count — every rank reads
-                        # the identical verdict at the same boundary.
-                        rollback = self.ft_guard.drain() or rollback  # synclint: agreement
-                    if rollback:
-                        self._rollback(i)
-                    # A flagged streak means the current state is suspect —
-                    # don't refresh the last-good snapshot from it.
-                    at_save = at_save and self.ft_guard.consecutive == 0
-                if at_save:
-                    if self._keeper is not None:
-                        self._keeper.update(self.state, completed)
-                    if self.checkpoint_dir:
-                        self._save_checkpoint(completed)
-                        meters.restart_clock()  # exclude ckpt I/O from meter
-                if (
-                    self._eval_fn is not None
-                    and self.eval_every > 0
-                    and (i + 1) % self.eval_every == 0
-                ):
-                    _, final_ppl, _ = self.evaluate()
-                    self.best_ppl = min(self.best_ppl, final_ppl)
-                    meters.restart_clock()  # eval must not pollute the meter
-                else:
-                    final_ppl = None
-                i += 1
+                        if self._keeper is not None:
+                            self._keeper.update(self.state, completed)
+                        if self.checkpoint_dir:
+                            self._save_checkpoint(completed)
+                            meters.restart_clock()  # exclude ckpt I/O from meter
+                    if (
+                        self._eval_fn is not None
+                        and self.eval_every > 0
+                        and (i + 1) % self.eval_every == 0
+                    ):
+                        _, final_ppl, _ = self.evaluate()
+                        self.best_ppl = min(self.best_ppl, final_ppl)
+                        meters.restart_clock()  # eval must not pollute the meter
+                    else:
+                        final_ppl = None
+                    i += 1
             if self.ft_guard is not None and self.ft_guard.drain():  # synclint: agreement
                 # Trailing flags buffered past the last cadence point must
                 # resolve before the end-of-fit checkpoint can capture a

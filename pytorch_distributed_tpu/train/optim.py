@@ -60,3 +60,19 @@ def sgd_update(
     new_params = jax.tree_util.tree_map(lambda t: t[0], flat, is_leaf=lambda t: isinstance(t, tuple))
     new_buf = jax.tree_util.tree_map(lambda t: t[1], flat, is_leaf=lambda t: isinstance(t, tuple))
     return new_params, new_buf
+
+
+def adamw(settings: dict, learning_rate=None):
+    """AdamW as an optax ``tx`` from a configuration file's ``optimizer``
+    group (``lr``, ``b1``, ``b2``, ``eps``, ``weight_decay``): float32
+    moments, decoupled weight decay on matrices only (norm scales and the
+    router's selection bias are not decayed).  ``learning_rate`` (a value or
+    an optax schedule) overrides the group's ``lr``."""
+    import optax
+
+    return optax.adamw(
+        settings["lr"] if learning_rate is None else learning_rate,
+        b1=settings["b1"], b2=settings["b2"], eps=settings["eps"],
+        weight_decay=settings["weight_decay"],
+        mask=lambda params: jax.tree_util.tree_map(
+            lambda p: p.ndim >= 2, params))
