@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from pytorch_distributed_tpu.models.vit import VisionTransformer
 from pytorch_distributed_tpu.parallel.ring import dense_attention, ring_self_attention
 
 
@@ -264,12 +265,14 @@ class TransformerLM(nn.Module):
 
 
 def bind_mesh(model, mesh: Mesh):
-    """The step builders' hook: a ``TransformerLM`` built without a mesh
-    learns the step's mesh here, so that attention can wrap the Pallas
-    kernel for it (``flash_attention_on_mesh``).  A model that already
-    carries a mesh (sequence parallelism), and any other model, is
-    returned as it is."""
-    if isinstance(model, TransformerLM) and model.mesh is None:
+    """The step builders' hook: a ``TransformerLM`` or a
+    ``VisionTransformer`` built without a mesh learns the step's mesh
+    here, so that attention can wrap its Pallas kernels for it
+    (``flash_attention_on_mesh``, ``short_attention_on_mesh``).  A model
+    that already carries a mesh (sequence parallelism), and any other
+    model, is returned as it is."""
+    if (isinstance(model, (TransformerLM, VisionTransformer))
+            and model.mesh is None):
         return model.clone(mesh=mesh)
     return model
 

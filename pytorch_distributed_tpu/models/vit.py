@@ -14,6 +14,9 @@ TPU-first choices:
   MXU);
 - bf16 compute policy with f32 LayerNorm/softmax accumulation and an f32
   head (same policy as the rest of the zoo);
+- attention as one fused Pallas kernel forward and one backward
+  (ops/short_attention.py) wherever the shape allows it: the
+  ``[B, H, L, L]`` scores and probabilities never reach HBM;
 - static shapes throughout: position embeddings take their grid shape from
   the init-time input (no image-size constructor knob to keep in sync); the
   class token rides as sequence position 0.
@@ -26,10 +29,48 @@ like every image model here.
 from __future__ import annotations
 
 import functools
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
+
+from pytorch_distributed_tpu.ops.short_attention import (
+    pick_attention,
+    short_attention_on_mesh,
+)
+
+
+def attention(query, key, value, mask=None, broadcast_dropout=True,
+              dropout_rng=None, dropout_rate=0.0, deterministic=False,
+              dtype=None, precision=None, force_fp32_for_softmax=False, *,
+              mesh: Optional[Mesh] = None):
+    """``attention_fn`` of the encoder's ``MultiHeadDotProductAttention``:
+    the fused one-block kernels where ``pick_attention`` says so, flax's
+    dense attention otherwise, on ``[B, L, H, D]`` projections either way
+    (the parameter tree is the module's and does not change).  No knob:
+    the choice follows from the backend, the shapes, the mask and whether
+    dropout acts on the probabilities.  On several devices the kernels
+    need ``mesh`` to wrap themselves with (a Mosaic call cannot be
+    partitioned); a model that was given none takes the dense path there,
+    unless the caller is already inside a ``shard_map``."""
+    _, length, heads, head_dim = query.shape
+    impl = pick_attention(
+        jax.default_backend(), length, heads, head_dim,
+        dropout=dropout_rate > 0.0 and not deterministic,
+        masked=mask is not None)
+    blind = (mesh is None and jax.device_count() > 1
+             and not jax.sharding.get_abstract_mesh().manual_axes)
+    if impl == "fused" and not blind:
+        query, key, value = nn.dtypes.promote_dtype(
+            query, key, value, dtype=dtype)
+        return short_attention_on_mesh(query, key, value, mesh)
+    return nn.dot_product_attention(
+        query, key, value, mask=mask, broadcast_dropout=broadcast_dropout,
+        dropout_rng=dropout_rng, dropout_rate=dropout_rate,
+        deterministic=deterministic, dtype=dtype, precision=precision,
+        force_fp32_for_softmax=force_fp32_for_softmax)
 
 
 class EncoderBlock(nn.Module):
@@ -37,6 +78,7 @@ class EncoderBlock(nn.Module):
     mlp_dim: int
     dropout: float = 0.0
     dtype: Any = jnp.float32
+    mesh: Optional[Mesh] = None
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -50,6 +92,11 @@ class EncoderBlock(nn.Module):
             # under the bf16 compute policy (same as transformer.py's
             # explicit f32 score path).
             force_fp32_for_softmax=True,
+            # init wants shapes only: flax's own attention there, so that
+            # no kernel is traced, compiled or loaded for a forward whose
+            # output is thrown away
+            attention_fn=(nn.dot_product_attention if self.is_initializing()
+                          else functools.partial(attention, mesh=self.mesh)),
             name="self_attention",
         )(h, h)
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
@@ -79,6 +126,9 @@ class VisionTransformer(nn.Module):
     # spills and the measured MFU collapses (11.9% vs vit_b's 46.5% on
     # v5e); remat trades ~1/3 more matmul FLOPs for staying resident.
     remat: bool = False
+    # The mesh the step runs on (train/steps.py binds it): attention's
+    # kernels wrap themselves in a shard_map over it on several devices.
+    mesh: Optional[Mesh] = None
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -137,7 +187,7 @@ class VisionTransformer(nn.Module):
         for i in range(self.n_layers):
             x = block_cls(
                 self.n_heads, self.mlp_dim, self.dropout, self.dtype,
-                name=f"encoder_{i}",
+                self.mesh, name=f"encoder_{i}",
             )(x, train)
         x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
         # Classify from the class token (torchvision ViT convention).

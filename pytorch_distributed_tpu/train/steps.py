@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
+from pytorch_distributed_tpu.models.transformer import bind_mesh
 from pytorch_distributed_tpu.ops import cross_entropy, qcomm, topk_correct
 from pytorch_distributed_tpu.parallel import overlap as overlap_lib
 from pytorch_distributed_tpu.parallel import zero as zero_lib
@@ -217,6 +218,7 @@ def make_train_step(
     behavior).  Running stats are pmean'd in both so replicas stay consistent.
     """
 
+    model = bind_mesh(model, mesh)  # a ViT's kernels wrap themselves for it
     mode, cast_dtype = qcomm.resolve_mode(grad_compress, wire_dtype)
     zero_mode = zero_lib.resolve_zero(zero)
     overlap_mode = overlap_lib.resolve_overlap(overlap)
@@ -612,6 +614,7 @@ def make_eval_step(
     default.
     """
 
+    model = bind_mesh(model, mesh)
     def step(state: TrainState, batch: Batch) -> Metrics:
         loss_sum, (_, _, c1, c5, count) = _forward_and_sums(
             model, state.params, state.batch_stats, batch, train=False

@@ -87,3 +87,82 @@ def test_gspmd_lm_step_with_flash_compiles_on_four_chips(
         state, tokens, jax.ShapeDtypeStruct((), jnp.float32)).compile()
     assert _mosaic_calls(compiled) == 3
     assert "num_partitions=4" in compiled.as_text()
+
+
+# --- the one-block fused attention of the ViTs (ops/short_attention.py) ---
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((256, 197, 12, 64), jnp.bfloat16),   # vit-b16-b256, the benchmark's cell
+    ((8, 50, 12, 64), jnp.bfloat16),      # vit_b_32
+    ((8, 197, 16, 64), jnp.bfloat16),     # vit_l_16
+    ((8, 197, 12, 64), jnp.float32),      # --precision fp32
+    ((8, 384, 6, 128), jnp.bfloat16),     # the bound's edge, one head a group
+])
+def test_short_attention_fwd_bwd_compiles_for_v5e(v5e_devices, shape, dtype):
+    from pytorch_distributed_tpu.ops import short_attention as sa
+
+    one = NamedSharding(Mesh(np.array(v5e_devices[:1]), ("x",)), P())
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(q, k, v):
+        return sa.short_attention(q, k, v, False).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert _mosaic_calls(compiled) == 2  # forward, one-pass backward
+    B, L, H, D = shape
+    # the compiler's count of the step's traffic (step_roofline reads it)
+    # includes what the kernels declare: eight operand-sized arrays and
+    # their results at the least
+    operand = B * L * H * D * jnp.dtype(dtype).itemsize
+    assert compiled.cost_analysis()["bytes accessed"] >= 12 * operand
+
+
+@pytest.mark.parametrize("explicit", [False, True],
+                         ids=["gspmd", "explicit_collectives"])
+def test_vit_step_with_fused_attention_compiles_on_four_chips(
+        v5e_devices, monkeypatch, explicit):
+    """The image step on four chips with the ViT's kernels in it.  GSPMD:
+    ``make_train_step`` hands the model its mesh, and attention wraps the
+    Mosaic calls in a shard_map over ``data`` (bare, the TPU compiler
+    refuses them: they cannot be partitioned).  Explicit collectives: the
+    step is a shard_map already and the calls stand in it bare.  ViT-B/16's
+    widths, two blocks, 8 images a chip."""
+    from pytorch_distributed_tpu import models
+    from pytorch_distributed_tpu.models import vit
+    from pytorch_distributed_tpu.ops import short_attention as sa
+    from pytorch_distributed_tpu.train.optim import sgd_init
+    from pytorch_distributed_tpu.train.state import TrainState
+    from pytorch_distributed_tpu.train.steps import make_train_step
+
+    # what the chip would see: a TPU backend, compiled kernels
+    monkeypatch.setattr(
+        vit, "pick_attention",
+        lambda backend, *a, **kw: sa.pick_attention("tpu", *a, **kw))
+    monkeypatch.setattr(sa, "_resolve_interpret", lambda interpret: False)
+    mesh = Mesh(np.array(v5e_devices), ("data",))
+    model = models.create_model("vit_b_16", num_classes=1000, n_layers=2,
+                                dtype=jnp.bfloat16)
+    variables = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 224, 224, 3)), train=False))
+    replicated = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P("data"))
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
+        jax.eval_shape(lambda v: TrainState.create(
+            v, sgd_init(v["params"])), variables))
+    batch = {
+        "images": jax.ShapeDtypeStruct((32, 224, 224, 3), jnp.float32,
+                                       sharding=rows),
+        "labels": jax.ShapeDtypeStruct((32,), jnp.int32, sharding=rows),
+        "weights": jax.ShapeDtypeStruct((32,), jnp.float32, sharding=rows)}
+    compiled = make_train_step(
+        model, mesh, explicit_collectives=explicit).lower(
+        state, batch,
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=replicated)).compile()
+    text = compiled.as_text()
+    assert _mosaic_calls(compiled) == 4  # two blocks, forward and backward
+    assert "num_partitions=4" in text
+    # no operation over the scores, whole ([32, 12, 197, 197]) or a
+    # chip's share of them
+    assert ",12,197,197]" not in text
