@@ -24,7 +24,7 @@ HBM peak — ResNet-50 b256 bf16 is **memory-bound** on this chip (arithmetic
 intensity ~29-60 FLOP/byte vs the chip's ~240 balance point), so throughput
 is capped near ~3,080 img/s at current traffic; conv fusions alone account
 for 55.4 GB/step already running at 699 GB/s.  Batch 512, larger scoped
-VMEM, and f32 feeds all measured slower (scripts/bench_variants.py).
+VMEM, and f32 feeds all measured slower.
 """
 
 import json

@@ -26,37 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from pytorch_distributed_tpu.ops.fused_bn import FusedBatchNormAct
-from pytorch_distributed_tpu.ops.fused_conv_bn import conv1x1_bn
 
 ModuleDef = Any
-
-
-def _fuse_ok(fused: bool, conv: ModuleDef, norm: ModuleDef) -> bool:
-    """Shared fold gate: only stock nn.Conv / FusedBatchNormAct semantics
-    may be replaced by the fused ops — a custom ModuleDef (or a partial
-    carrying settings the combinator doesn't forward) keeps the unfused
-    composition, or its settings would be silently dropped."""
-    if not fused:
-        return False
-    if getattr(norm, "func", norm) is not FusedBatchNormAct:
-        return False
-    if getattr(conv, "func", conv) is not nn.Conv:
-        return False
-    if set(getattr(conv, "keywords", {})) - {"dtype"}:
-        return False
-    return not (set(getattr(norm, "keywords", {}))
-                - {"use_running_average", "momentum", "epsilon"})
-
-
-def _fuse_kw(conv: ModuleDef, norm: ModuleDef) -> dict:
-    nkw = getattr(norm, "keywords", {})
-    ckw = getattr(conv, "keywords", {})
-    return dict(
-        use_running_average=bool(nkw.get("use_running_average", False)),
-        momentum=nkw.get("momentum", 0.9),
-        eps=nkw.get("epsilon", 1e-5),
-        dtype=ckw.get("dtype", jnp.float32),
-    )
 
 
 class BasicBlock(nn.Module):
@@ -67,57 +38,22 @@ class BasicBlock(nn.Module):
     base_width: int = 64
     conv: ModuleDef = nn.Conv
     norm: ModuleDef = FusedBatchNormAct
-    # Fold the stride-1 3x3 conv→BN pairs (both mains when strides == 1,
-    # the second always) through ops/fused_conv_bn's whole-plane kernel;
-    # strided slots keep the XLA backward.  Param paths identical either
-    # way (same guarantee as Bottleneck).
-    fused_convbn: bool = False
 
     @nn.compact
     def __call__(self, x):
         residual = x
-        if not _fuse_ok(self.fused_convbn, self.conv, self.norm):
-            y = self.conv(self.filters, (3, 3),
-                          (self.strides, self.strides),
-                          padding=[(1, 1), (1, 1)], use_bias=False)(x)
-            y = self.norm(relu=True)(y)
-            y = self.conv(self.filters, (3, 3), padding=[(1, 1), (1, 1)],
-                          use_bias=False)(y)
-            y = self.norm(scale_init=nn.initializers.zeros)(y)
-            if residual.shape != y.shape:
-                residual = self.conv(self.filters * self.expansion, (1, 1),
-                                     (self.strides, self.strides),
-                                     use_bias=False)(residual)
-                residual = self.norm()(residual)
-            return nn.relu(y + residual)
-
-        fkw = _fuse_kw(self.conv, self.norm)
-        if self.strides == 1:
-            y = conv1x1_bn(self, "Conv_0", "FusedBatchNormAct_0", x,
-                           self.filters, relu=True, kernel_size=(3, 3),
-                           **fkw)
-        else:
-            y = self.conv(self.filters, (3, 3),
-                          (self.strides, self.strides),
-                          padding=[(1, 1), (1, 1)], use_bias=False,
-                          name="Conv_0")(x)
-            y = self.norm(relu=True, name="FusedBatchNormAct_0")(y)
-        y = conv1x1_bn(self, "Conv_1", "FusedBatchNormAct_1", y,
-                       self.filters, relu=False,
-                       scale_init=nn.initializers.zeros,
-                       kernel_size=(3, 3), **fkw)
+        y = self.conv(self.filters, (3, 3),
+                      (self.strides, self.strides),
+                      padding=[(1, 1), (1, 1)], use_bias=False)(x)
+        y = self.norm(relu=True)(y)
+        y = self.conv(self.filters, (3, 3), padding=[(1, 1), (1, 1)],
+                      use_bias=False)(y)
+        y = self.norm(scale_init=nn.initializers.zeros)(y)
         if residual.shape != y.shape:
-            if self.strides == 1:
-                residual = conv1x1_bn(self, "Conv_2", "FusedBatchNormAct_2",
-                                      residual,
-                                      self.filters * self.expansion,
-                                      relu=False, **fkw)
-            else:
-                residual = self.conv(self.filters * self.expansion, (1, 1),
-                                     (self.strides, self.strides),
-                                     use_bias=False,
-                                     name="Conv_2")(residual)
-                residual = self.norm(name="FusedBatchNormAct_2")(residual)
+            residual = self.conv(self.filters * self.expansion, (1, 1),
+                                 (self.strides, self.strides),
+                                 use_bias=False)(residual)
+            residual = self.norm()(residual)
         return nn.relu(y + residual)
 
 
@@ -129,61 +65,27 @@ class Bottleneck(nn.Module):
     base_width: int = 64
     conv: ModuleDef = nn.Conv
     norm: ModuleDef = FusedBatchNormAct
-    # Route the 1x1 stride-1 conv→BN pairs (2-3 of the 4 convs per block)
-    # through the fused-backward op (ops/fused_conv_bn.py) — dy never hits
-    # HBM.  Param paths are IDENTICAL either way (the fused combinator
-    # declares through child scopes), so checkpoints interchange freely.
-    fused_convbn: bool = False
 
     @nn.compact
     def __call__(self, x):
         residual = x
         width = int(self.filters * (self.base_width / 64.0)) * self.groups
         out_ch = self.filters * self.expansion
-        if not _fuse_ok(self.fused_convbn, self.conv, self.norm):
-            y = self.conv(width, (1, 1), use_bias=False)(x)
-            y = self.norm(relu=True)(y)
-            y = self.conv(width, (3, 3), (self.strides, self.strides),
-                          padding=[(1, 1), (1, 1)], use_bias=False,
-                          feature_group_count=self.groups)(y)
-            y = self.norm(relu=True)(y)
-            y = self.conv(out_ch, (1, 1), use_bias=False)(y)
-            # Zero-init the last BN scale so blocks start as identity
-            # (torchvision zero_init_residual analogue; helps large-batch SGD).
-            y = self.norm(scale_init=nn.initializers.zeros)(y)
-            if residual.shape != y.shape:
-                residual = self.conv(out_ch, (1, 1),
-                                     (self.strides, self.strides),
-                                     use_bias=False)(residual)
-                residual = self.norm()(residual)
-            return nn.relu(y + residual)
-
-        # Fused branch: explicit child names reproduce the auto-assigned
-        # paths of the branch above, slot for slot.
-        fkw = _fuse_kw(self.conv, self.norm)
-        y = conv1x1_bn(self, "Conv_0", "FusedBatchNormAct_0", x, width,
-                       relu=True, **fkw)
-        if self.strides == 1 and self.groups == 1:
-            # the middle 3x3 folds too (stride-1 SAME, ungrouped)
-            y = conv1x1_bn(self, "Conv_1", "FusedBatchNormAct_1", y, width,
-                           relu=True, kernel_size=(3, 3), **fkw)
-        else:
-            y = self.conv(width, (3, 3), (self.strides, self.strides),
-                          padding=[(1, 1), (1, 1)], use_bias=False,
-                          feature_group_count=self.groups,
-                          name="Conv_1")(y)
-            y = self.norm(relu=True, name="FusedBatchNormAct_1")(y)
-        y = conv1x1_bn(self, "Conv_2", "FusedBatchNormAct_2", y, out_ch,
-                       relu=False, scale_init=nn.initializers.zeros, **fkw)
+        y = self.conv(width, (1, 1), use_bias=False)(x)
+        y = self.norm(relu=True)(y)
+        y = self.conv(width, (3, 3), (self.strides, self.strides),
+                      padding=[(1, 1), (1, 1)], use_bias=False,
+                      feature_group_count=self.groups)(y)
+        y = self.norm(relu=True)(y)
+        y = self.conv(out_ch, (1, 1), use_bias=False)(y)
+        # Zero-init the last BN scale so blocks start as identity
+        # (torchvision zero_init_residual analogue; helps large-batch SGD).
+        y = self.norm(scale_init=nn.initializers.zeros)(y)
         if residual.shape != y.shape:
-            if self.strides == 1:
-                residual = conv1x1_bn(self, "Conv_3", "FusedBatchNormAct_3",
-                                      residual, out_ch, relu=False, **fkw)
-            else:
-                residual = self.conv(out_ch, (1, 1),
-                                     (self.strides, self.strides),
-                                     use_bias=False, name="Conv_3")(residual)
-                residual = self.norm(name="FusedBatchNormAct_3")(residual)
+            residual = self.conv(out_ch, (1, 1),
+                                 (self.strides, self.strides),
+                                 use_bias=False)(residual)
+            residual = self.norm()(residual)
         return nn.relu(y + residual)
 
 
@@ -245,7 +147,6 @@ class ResNet(nn.Module):
     base_width: int = 64
     dtype: Any = jnp.float32
     stem: str = "conv7"  # "conv7" (torchvision) | "space_to_depth" (same math)
-    fused_convbn: bool = False  # fold BN-backward dx into the 1x1 dgrad/wgrad
     # SyncBN under shard_map: psum BN moments over this mesh axis (torch
     # nn.SyncBatchNorm ≙).  None = per-shard statistics (torch DDP default).
     bn_axis_name: Any = None
@@ -259,8 +160,8 @@ class ResNet(nn.Module):
             epsilon=1e-5,
         )
         if self.bn_axis_name is not None:
-            # Only set when active: the keyword disables the conv+BN fold
-            # gate (_fuse_ok), which has no synced-stats kernel.
+            # Set only for SyncBN: a per-shard model keeps the partial it
+            # always had (FusedBatchNormAct's own default is None).
             norm_kw["axis_name"] = self.bn_axis_name
         norm = functools.partial(FusedBatchNormAct, **norm_kw)
         x = x.astype(self.dtype)
@@ -285,7 +186,6 @@ class ResNet(nn.Module):
                     base_width=self.base_width,
                     conv=conv,
                     norm=norm,
-                    fused_convbn=self.fused_convbn,
                 )(x)
         x = jnp.mean(x, axis=(1, 2))  # global average pool
         x = nn.Dense(self.num_classes, dtype=jnp.float32, name="fc")(x)

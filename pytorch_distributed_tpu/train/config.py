@@ -78,9 +78,6 @@ class Config:
     # ResNet stem variant: "space_to_depth" is the MLPerf-style packed stem
     # (identical math/params, faster MXU tiling); other archs ignore it.
     stem: str = "conv7"
-    # Fold BN-backward dx into the 1x1 dgrad/wgrad via the Pallas fused
-    # kernel (ops/fused_conv_bn.py); ResNet bottleneck family only.
-    fused_convbn: bool = False
     # Cross-replica SyncBN for the explicit-collectives (shard_map) step:
     # psum the BN moments over the data axis so statistics cover the
     # global batch, matching GSPMD's implicit semantics.  ≙ torch
@@ -440,11 +437,6 @@ def build_parser(description: str = "TPU ImageNet Training") -> argparse.Argumen
                    choices=("conv7", "space_to_depth"),
                    help="ResNet stem: torchvision conv7 or the numerically "
                    "identical space-to-depth packing (TPU MXU-friendly)")
-    p.add_argument("--fused-convbn", action="store_true", dest="fused_convbn",
-                   help="fuse BN-backward dx into the bottleneck conv "
-                   "dgrad/wgrad (Pallas, 1x1 + stride-1 3x3; dy never hits "
-                   "HBM); checkpoints stay interchangeable with the "
-                   "unfused model")
     p.add_argument("--fused-ce", default=d.fused_ce_chunks, type=int,
                    metavar="CHUNKS", dest="fused_ce_chunks",
                    help="LM family: fused tied-head+CE loss in CHUNKS row "

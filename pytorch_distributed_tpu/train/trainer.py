@@ -112,27 +112,16 @@ class Trainer:
         self._build_data()
 
         dtype = jnp.bfloat16 if cfg.precision == "bf16" else jnp.float32
-        # --stem / --fused-convbn are ResNet-family knobs; only forwarded
-        # when non-default.
+        # --stem is a ResNet-family knob; only forwarded when non-default.
         extra = {} if cfg.stem == "conv7" else {"stem": cfg.stem}
-        if cfg.fused_convbn:
-            extra["fused_convbn"] = True
         if extra and getattr(
             models._REGISTRY.get(cfg.arch), "func", None
         ) is not models.ResNet:
             raise ValueError(
-                f"--stem/--fused-convbn only apply to the ResNet family; "
+                "--stem only applies to the ResNet family; "
                 f"arch {cfg.arch!r} has no such variant"
             )
         if getattr(cfg, "sync_bn", False) and explicit_collectives:
-            if cfg.fused_convbn:
-                # The fold gate (models/resnet.py _fuse_ok) has no
-                # synced-stats kernel and would silently drop the fold —
-                # make the conflict loud instead.
-                raise ValueError(
-                    "--sync-bn and --fused-convbn are mutually exclusive: "
-                    "the fused conv+BN backward has no cross-replica "
-                    "statistics variant; drop one of the flags")
             # Cross-replica BN moments inside the shard_map step (torch
             # SyncBatchNorm ≙, model-agnostic like torch's): every BN
             # model family threads bn_axis_name into its norm layers.

@@ -32,7 +32,6 @@ python -m pytorch_distributed_tpu.recipes.dataparallel --data "$DATA"
 
 # 7. canonical TPU-native recipe (BASELINE.json north star)
 python -m pytorch_distributed_tpu.recipes.tpu_native --data "$DATA" -a resnet50
-# python -m pytorch_distributed_tpu.recipes.tpu_native --data "$DATA" -a resnet50 --fused-convbn   # BN-dx fold (round 4)
 
 # 8. long-context LM pretraining (beyond reference): composable parallelism
 python -m pytorch_distributed_tpu.recipes.lm_pretrain --tp 4 --seq-len 2048 -b 32 --steps 1000

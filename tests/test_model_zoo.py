@@ -156,6 +156,63 @@ def test_space_to_depth_stem_equivalence():
     )
 
 
+def _bn(i):
+    return [f"FusedBatchNormAct_{i}/bias", f"FusedBatchNormAct_{i}/scale"]
+
+
+def _stats(i):
+    return [f"FusedBatchNormAct_{i}/mean", f"FusedBatchNormAct_{i}/var"]
+
+
+# On 64 input channels: filters=64 (Basic) / 16 (Bottleneck, x4) at stride 1
+# keep the identity shortcut; stride 2 forces the projection.
+RESNET_PATH_CASES = {
+    "basic": (
+        lambda: models.resnet.BasicBlock(filters=64),
+        ["Conv_0/kernel", "Conv_1/kernel", *_bn(0), *_bn(1)],
+        [*_stats(0), *_stats(1)]),
+    "basic_projection": (
+        lambda: models.resnet.BasicBlock(filters=32, strides=2),
+        ["Conv_0/kernel", "Conv_1/kernel", "Conv_2/kernel",
+         *_bn(0), *_bn(1), *_bn(2)],
+        [*_stats(0), *_stats(1), *_stats(2)]),
+    "bottleneck": (
+        lambda: models.resnet.Bottleneck(filters=16),
+        ["Conv_0/kernel", "Conv_1/kernel", "Conv_2/kernel",
+         *_bn(0), *_bn(1), *_bn(2)],
+        [*_stats(0), *_stats(1), *_stats(2)]),
+    "bottleneck_projection": (
+        lambda: models.resnet.Bottleneck(filters=32, strides=2),
+        ["Conv_0/kernel", "Conv_1/kernel", "Conv_2/kernel", "Conv_3/kernel",
+         *_bn(0), *_bn(1), *_bn(2), *_bn(3)],
+        [*_stats(0), *_stats(1), *_stats(2), *_stats(3)]),
+    # first path component only: a block's inside is the cases above
+    "resnet18_top": (
+        lambda: models.create_model("resnet18", num_classes=10),
+        [*(f"BasicBlock_{i}" for i in range(8)), "bn_init", "conv_init",
+         "fc"],
+        [*(f"BasicBlock_{i}" for i in range(8)), "bn_init"]),
+}
+
+
+@pytest.mark.parametrize("case", list(RESNET_PATH_CASES))
+def test_resnet_param_paths(case):
+    """Every ResNet checkpoint on disk is keyed by these names, and only
+    flax's per-class counter produces them: a reordered or renamed call in
+    a block silently orphans them."""
+    from flax import traverse_util
+
+    build, want_params, want_stats = RESNET_PATH_CASES[case]
+    top = case == "resnet18_top"
+    x = jnp.zeros((2, 32, 32, 3) if top else (2, 8, 8, 64))
+    variables = jax.eval_shape(
+        lambda: build().init(jax.random.PRNGKey(0), x))
+    got = {col: sorted({k[0] if top else "/".join(k)
+                        for k in traverse_util.flatten_dict(tree)})
+           for col, tree in variables.items()}
+    assert got == {"params": want_params, "batch_stats": want_stats}
+
+
 def test_adaptive_avg_pool_matches_torch():
     """Non-divisible sizes must follow torch AdaptiveAvgPool2d bin edges
     (regression: earlier fallback collapsed to a global mean)."""

@@ -283,7 +283,11 @@ def test_shardlint_baseline_fences_f32_fallback(get_lowering):
     errors = [f for f in regress if f.severity == "error"]
     assert any(f.where.endswith(":all-reduce") for f in errors), regress
     assert any(f.where.endswith(":total") for f in errors), regress
-    # sanity: the real lowering regenerates its own pinned entry
+    # sanity: the real lowering regenerates its own pinned entry (the
+    # entry pins synclint's schedule digest too: attach it, zero compiles)
+    from pytorch_distributed_tpu.analysis import synclint
+
+    synclint.annotate_reports([rep])
     assert baseline_entry(rep) == entry
 
 

@@ -59,6 +59,57 @@ def test_sharded_step_matches_single_device():
     _leaves_allclose(s8.params, s1.params, rtol=1e-4)
 
 
+def _tiny_bneck(**kw):
+    """The block the benchmark's ResNet-50 cells run, at a size a CPU steps
+    in seconds (the model __graft_entry__.py sends through the dry run)."""
+    from pytorch_distributed_tpu.models.resnet import Bottleneck, ResNet
+
+    return ResNet(stage_sizes=[1, 1], block_cls=Bottleneck, num_classes=10,
+                  num_filters=16, **kw)
+
+
+@pytest.mark.parametrize("formulation", ["gspmd", "explicit"])
+def test_bottleneck_step_parity(formulation):
+    """Two steps of a Bottleneck model, so the second loss has been through
+    the blocks' backward.  gspmd: the 4-way mesh against one device.
+    explicit: the shard_map step with SyncBN against the GSPMD step (whose
+    BN is global-batch by construction; without the axis name the explicit
+    step's BN is per shard and equals neither)."""
+    mesh4 = build_mesh(MeshSpec(("data",), (4,)), jax.devices()[:4])
+    mesh1 = build_mesh(MeshSpec(("data",), (1,)), jax.devices()[:1])
+    plain = _tiny_bneck()
+    variables = plain.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 16, 16, 3)), train=False)
+    np_rng = np.random.default_rng(0)
+    batch = {
+        "images": np_rng.normal(size=(16, 16, 16, 3)).astype(np.float32),
+        "labels": np_rng.integers(0, 10, size=16).astype(np.int32),
+        "weights": np.ones(16, np.float32),
+    }
+
+    def two_steps(step):
+        state = TrainState.create(
+            jax.tree_util.tree_map(jnp.copy, variables),
+            sgd_init(variables["params"]))
+        state, _ = step(state, batch, jnp.float32(0.1))
+        return step(state, batch, jnp.float32(0.1))
+
+    if formulation == "gspmd":
+        got = two_steps(make_train_step(plain, mesh4))
+        want = two_steps(make_train_step(plain, mesh1))
+    else:
+        got = two_steps(make_train_step(
+            _tiny_bneck(bn_axis_name="data"), mesh4,
+            explicit_collectives=True))
+        want = two_steps(make_train_step(plain, mesh4))
+    (s_got, m_got), (s_want, m_want) = got, want
+    np.testing.assert_allclose(
+        float(m_got["loss"]), float(m_want["loss"]), rtol=1e-4)
+    np.testing.assert_allclose(
+        float(m_got["acc1"]), float(m_want["acc1"]), atol=1e-4)
+    _leaves_allclose(s_got.params, s_want.params, rtol=1e-4)
+
+
 class _MLP(__import__("flax").linen.Module):
     """BN-free model: isolates collective plumbing from BN-semantics deltas."""
 

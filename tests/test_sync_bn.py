@@ -13,6 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -142,11 +143,8 @@ def test_pershard_bn_differs_from_syncbn():
 
 
 def test_sync_bn_trainer_gates():
-    """--sync-bn config gates: conflicts with --fused-convbn (no synced
-    fold kernel), rejected for BN-free archs; accepted quietly under
-    GSPMD (documented no-op)."""
-    import pytest
-
+    """--sync-bn config gates: rejected for BN-free archs; accepted
+    quietly under GSPMD (documented no-op)."""
     from pytorch_distributed_tpu.train.config import Config
     from pytorch_distributed_tpu.train.trainer import Trainer
 
@@ -155,9 +153,6 @@ def test_sync_bn_trainer_gates():
         return Config(synthetic=True, synthetic_length=16, batch_size=16,
                       image_size=32, num_classes=4, epochs=1, **kw)
 
-    with pytest.raises(ValueError, match="mutually exclusive"):
-        Trainer(cfg(sync_bn=True, fused_convbn=True, arch="resnet50"),
-                explicit_collectives=True)
     with pytest.raises(ValueError, match="no BatchNorm"):
         Trainer(cfg(sync_bn=True, arch="alexnet"),
                 explicit_collectives=True)
@@ -207,31 +202,17 @@ def test_explicit_syncbn_step_matches_gspmd_flax_bn_model():
             err_msg=jax.tree_util.keystr(path))
 
 
-def test_sync_bn_axis_name_disables_convbn_fold():
-    """fused_convbn + sync BN: the fold gate must reject (no synced-stats
-    Pallas kernel) and fall back to the unfused composition — same
-    numerics as the unfused sync model."""
+@pytest.mark.parametrize("arch", ["resnet18", "resnet50"])
+def test_sync_bn_axis_name_keeps_variable_tree(arch):
+    """A ResNet built with bn_axis_name="data" declares the variable tree
+    of one built without (same paths, shapes, dtypes; BasicBlock and
+    Bottleneck), so checkpoints pass between sync and per-shard runs."""
     kw = dict(num_classes=10, dtype=jnp.float32)
-    m_fold = create_model("resnet50", fused_convbn=True,
-                          bn_axis_name="data", **kw)
-    m_plain = create_model("resnet50", bn_axis_name="data", **kw)
     sample = jnp.zeros((2, 32, 32, 3), jnp.float32)
-    v1 = m_fold.init(jax.random.PRNGKey(0), sample, train=False)
-    v2 = m_plain.init(jax.random.PRNGKey(0), sample, train=False)
-    # identical param trees (fold would rename/restructure nothing, but a
-    # silently-active fold with dropped axis_name would diverge in train
-    # mode under shard_map; structural equality pins the fallback)
-    assert jax.tree_util.tree_structure(v1) == jax.tree_util.tree_structure(v2)
 
-    def fwd(model, v, x):
-        def local(xs):
-            return model.apply(v, xs, train=True, mutable=["batch_stats"])[0]
+    def tree(model):
+        return jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), sample, train=False))
 
-        return shard_map(local, mesh=_mesh(), in_specs=P("data"),
-                         out_specs=P("data"), check_vma=False)(x)
-
-    x = jnp.asarray(np.random.default_rng(3).normal(
-        0, 1, size=(16, 32, 32, 3)), jnp.float32)
-    np.testing.assert_allclose(
-        np.asarray(fwd(m_fold, v1, x)), np.asarray(fwd(m_plain, v2, x)),
-        rtol=1e-5, atol=1e-5)
+    assert (tree(create_model(arch, bn_axis_name="data", **kw))
+            == tree(create_model(arch, **kw)))

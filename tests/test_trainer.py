@@ -83,6 +83,13 @@ def test_bf16_precision_trains(tmp_path):
     assert jax.tree_util.tree_leaves(state.params)[0].dtype == jnp.float32
 
 
+def test_stem_flag_rejected_outside_resnet_family(tmp_path):
+    """--stem names a ResNet variant; any other arch must refuse it loudly
+    instead of building its usual stem."""
+    with pytest.raises(ValueError, match="--stem only applies to the ResNet"):
+        Trainer(_cfg(tmp_path, stem="space_to_depth", arch="alexnet"))
+
+
 def test_parse_config_reference_flag_surface():
     cfg = parse_config(
         ["-a", "resnet50", "-b", "256", "--lr", "0.4", "--wd", "1e-4",
