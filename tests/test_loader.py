@@ -17,6 +17,12 @@ def _loader(n=24, bsz=8, **kw):
     return DataLoader(ds, batch_size=bsz, sampler=DistributedShardSampler(n, shuffle=False), **kw)
 
 
+def _kept(batches):
+    """Every batch of an iterator, each copied as it is drawn: a
+    process-fed batch views shared memory that the next draw rewrites."""
+    return [{k: v.copy() for k, v in batch.items()} for batch in batches]
+
+
 def test_feeder_shards_batches_over_data_axis():
     feeder = DeviceFeeder(data_parallel_mesh())
     batches = list(feeder(iter(_loader())))
@@ -107,7 +113,8 @@ def test_process_workers_match_thread_workers():
         loader = DataLoader(ds, batch_size=8, sampler=sampler,
                             num_workers=2, worker_type=wt)
         loader.set_epoch(1)
-        batches[wt] = list(loader)
+        batches[wt] = _kept(loader)
+        loader.close()
     assert len(batches["thread"]) == len(batches["process"])
     for a, b in zip(batches["thread"], batches["process"]):
         np.testing.assert_array_equal(a["images"], b["images"])
@@ -176,7 +183,7 @@ def test_batches_equal_a_plain_assembly(batch_mode, worker_type, start):
             for flip in (False, True):
                 loader.set_epoch(epoch)
                 loader.random_flip = flip
-                got = list(loader.iter_batches(start))
+                got = _kept(loader.iter_batches(start))
                 want = list(_reference_batches(loader, start))
                 assert len(got) == len(want) == 3 - start
                 assert got[-1]["weights"].tolist() == [1] * 4 + [0] * 4
@@ -187,7 +194,7 @@ def test_batches_equal_a_plain_assembly(batch_mode, worker_type, start):
                         np.testing.assert_array_equal(g[key], w[key], key)
         if batch_mode == "u8_wire":  # the draw does flip some rows
             loader.random_flip = False
-            plain = list(loader.iter_batches(start))
+            plain = _kept(loader.iter_batches(start))
             assert any((g["images"] != p["images"]).any()
                        for g, p in zip(got, plain))
     finally:
@@ -265,3 +272,314 @@ def test_rows_survive_more_workers_than_cores_racing_to_allocate():
         want = np.arange(16 * b, 16 * b + 16)
         np.testing.assert_array_equal(batch["labels"], want)
         np.testing.assert_array_equal(batch["images"][:, 0, 0, 0], want % 251)
+
+
+# ------------------------------------------- worker processes, shared memory
+
+def _stack_for(batch_mode, size=16):
+    from pytorch_distributed_tpu.data.transforms import (
+        train_transform,
+        train_transform_u8,
+    )
+
+    return (train_transform(size=size) if batch_mode == "f32"
+            else train_transform_u8(size))
+
+
+def _twins(batch_mode, n=52, bsz=8, workers=3, **kw):
+    """The same loader fed by threads and by processes: seven batches a
+    epoch, the last padded, more than the ring holds."""
+    ds = SyntheticImageDataset(length=n, num_classes=5, image_size=32,
+                               transform=_stack_for(batch_mode))
+    return [DataLoader(ds, batch_size=bsz, num_workers=workers, seed=7,
+                       batch_mode=batch_mode, worker_type=worker_type,
+                       sampler=DistributedShardSampler(n, shuffle=True,
+                                                       seed=3), **kw)
+            for worker_type in ("thread", "process")]
+
+
+@pytest.mark.parametrize("start", [0, 3], ids=["epoch", "resumed"])
+@pytest.mark.parametrize("batch_mode", ["f32", "u8_host", "u8_wire"])
+def test_process_fed_batches_are_the_thread_fed_bytes(batch_mode, start):
+    """Rows written by worker processes into shared memory are, byte for
+    byte, the rows worker threads write: two epochs, the flip drawn, a
+    padded trailing batch, the ring (three buffers) gone round twice."""
+    from pytorch_distributed_tpu.data.loader import SharedBatch
+
+    threads, processes = _twins(batch_mode, random_flip=True)
+    try:
+        for epoch in (0, 1):
+            threads.set_epoch(epoch)
+            processes.set_epoch(epoch)
+            drawn = 0
+            for want, got in zip(threads.iter_batches(start),
+                                 processes.iter_batches(start)):
+                assert isinstance(got, SharedBatch)
+                assert sorted(got) == sorted(want)
+                for key in want:
+                    assert got[key].dtype == want[key].dtype, key
+                    np.testing.assert_array_equal(got[key], want[key], key)
+                drawn += 1
+            assert drawn == 7 - start
+            assert got["weights"].tolist() == [1] * 4 + [0] * 4
+            assert not got["labels"][4:].any()  # a buffer used before
+            if batch_mode != "u8_host":  # which normalises the zeros
+                assert not got["images"][4:].any()
+    finally:
+        processes.close()
+
+
+def _settled(loader, seconds=60.0):
+    """Wait until the workers have filled every batch they were given."""
+    import time
+
+    tasks = [t for _, _, batch in loader._inflight for t in batch]
+    limit = time.monotonic() + seconds
+    while not all(t.done() for t in tasks):
+        assert time.monotonic() < limit
+        time.sleep(0.01)
+    return len(tasks)
+
+
+def _held_until_the_next_draw(loader):
+    """What ``_iter_process`` promises: a drawn batch keeps its bytes while
+    the workers fill the batches ahead of it, until the next one is drawn;
+    then its buffer goes back to them."""
+    from pytorch_distributed_tpu.data.loader import _AHEAD
+
+    want = _kept(_twins("u8_wire")[0])
+    batches = iter(loader)
+    views = []
+    for b, kept in enumerate(want):
+        batch = next(batches)
+        views.append(batch["images"])
+        ahead = _settled(loader)  # everything in flight has landed
+        assert (ahead > 0) == (b + 1 < len(want))
+        assert len(loader._inflight) == min(_AHEAD, len(want) - 1 - b)
+        for key in kept:
+            np.testing.assert_array_equal(batch[key], kept[key], key)
+    # the ring has _AHEAD + 1 buffers: batch b's is rewritten with b + 3's
+    assert np.shares_memory(views[0], views[_AHEAD + 1])
+    assert not np.shares_memory(views[0], views[1])
+    assert next(batches, None) is None
+
+
+def _fetch_spans_carry_the_workers_counts(loader):
+    """``placed`` of ``samples``: every row was written by a worker, none by
+    the producer; the workers' clocks come back with the rows."""
+    from pytorch_distributed_tpu.obs.trace import RECORDER
+
+    RECORDER.clear()
+    drawn = len(_kept(loader))
+    fetches = [r for r in RECORDER.records() if r.name == "fetch"]
+    assembles = [r for r in RECORDER.records() if r.name == "assemble"]
+    assert [r.id for r in fetches] == list(range(drawn)) == [
+        r.id for r in assembles]
+    for r in fetches[:-1]:
+        assert r.fields["placed"] == r.fields["samples"] == 8
+    assert fetches[-1].fields["placed"] == 4  # the padded batch's samples
+    for r in fetches:
+        assert r.fields["sample_wall_s"] > 0 and r.fields["sample_cpu_s"] > 0
+
+
+def _a_second_iteration_takes_the_ring(loader):
+    first = iter(loader)
+    next(first)
+    want = _kept(_twins("u8_wire")[0])
+    for kept, got in zip(want, loader):  # settles the first one's tasks
+        np.testing.assert_array_equal(got["images"], kept["images"])
+    with pytest.raises(RuntimeError, match="a later iteration"):
+        next(first)
+
+
+@pytest.mark.parametrize("behaviour", [
+    _held_until_the_next_draw, _fetch_spans_carry_the_workers_counts,
+    _a_second_iteration_takes_the_ring], ids=lambda f: f.__name__.strip("_"))
+def test_process_fed_epoch(behaviour):
+    loader = _twins("u8_wire")[1]
+    try:
+        behaviour(loader)
+    finally:
+        loader.close()
+
+
+def test_feeder_copies_a_shared_batch_before_the_next_is_drawn():
+    """``DeviceFeeder._put`` ends its copy of a ``SharedBatch`` before it
+    returns (and on the CPU, whose client aliases host memory, copies it
+    first): what reaches the devices is what thread workers deliver, over
+    more batches than the ring holds."""
+    threads, processes = _twins("u8_wire", n=64, random_flip=True)
+    feeder = DeviceFeeder(data_parallel_mesh())
+    try:
+        want = list(feeder(iter(threads)))
+        got = list(feeder(iter(processes)))  # all eight held to the end
+        assert len(got) == len(want) == 8
+        for g, w in zip(got, want):
+            for key in w:
+                np.testing.assert_array_equal(np.asarray(g[key]),
+                                              np.asarray(w[key]), key)
+    finally:
+        processes.close()
+
+
+class _Fatal:
+    """A transform that takes its process down at the given sample."""
+
+    def __init__(self, at):
+        self.at = at
+
+    def __call__(self, image, rng):
+        import os
+
+        if int(image[0, 0, 0]) == self.at:
+            os._exit(1)
+        return image
+
+
+class _Numbered:
+    """Sample ``i`` is a uint8 image filled with ``i``."""
+
+    def __init__(self, n, transform=None):
+        self.n, self.transform = n, transform
+
+    def __len__(self):
+        return self.n
+
+    def get(self, index, rng):
+        image = np.full((4, 4, 3), index, np.uint8)
+        if self.transform is not None:
+            image = self.transform(image, rng)
+        return image, index
+
+
+def test_rows_survive_more_worker_processes_than_cores():
+    """Twelve worker processes, tasks of one row, 150 batches through three
+    buffers: every row of every batch is the sample the sampler named, so
+    no task wrote into a buffer that was not its own."""
+    import time
+
+    ds = _Numbered(2400)
+    loader = DataLoader(ds, batch_size=16, num_workers=12,
+                        batch_mode="u8_wire", worker_type="process",
+                        sampler=DistributedShardSampler(2400, shuffle=False))
+    t, drawn = time.monotonic(), 0
+    try:
+        for b, batch in enumerate(loader):
+            want = np.arange(16 * b, 16 * b + 16)
+            np.testing.assert_array_equal(batch["labels"], want)
+            np.testing.assert_array_equal(batch["images"][:, 3, 3, 2],
+                                          want % 256)
+            drawn += 1
+            assert time.monotonic() - t < 300.0
+    finally:
+        loader.close()
+    assert drawn == 150
+
+
+def _linked(segment):
+    """Whether the loader's segment still has its name under /dev/shm."""
+    import os
+
+    assert segment.startswith("psm_")
+    return os.path.exists(os.path.join("/dev/shm", segment))
+
+
+def _workers():
+    import multiprocessing
+
+    return {p.pid for p in multiprocessing.active_children()}
+
+
+def test_a_killed_worker_raises_at_the_iterator_and_leaves_nothing():
+    """``os._exit`` inside a transform, mid-epoch: the epoch raises within
+    the test's own limit instead of waiting for rows that never come, the
+    pool and the segment are gone, and the next epoch runs on new ones."""
+    import time
+
+    before = _workers()
+    ds = _Numbered(64, _Fatal(at=37))
+    loader = DataLoader(ds, batch_size=8, num_workers=3,
+                        batch_mode="u8_wire", worker_type="process")
+    t = time.monotonic()
+    try:
+        with pytest.raises(RuntimeError, match="worker process died"):
+            for batch in loader:
+                first = loader._ring.spec[0]
+                assert _linked(first) and time.monotonic() - t < 120.0
+        assert time.monotonic() - t < 120.0
+        assert not _linked(first) and _workers() == before
+        loader.dataset = _Numbered(64)  # no fatal sample: a new pool
+        assert [int(b["labels"][0]) for b in loader] == list(range(0, 64, 8))
+        second = loader._ring.spec[0]
+        assert second != first and _linked(second)
+    finally:
+        loader.close()
+    assert not _linked(second) and _workers() == before
+
+
+@pytest.mark.parametrize("how", ["close", "abandoned", "exit"])
+def test_no_segment_and_no_worker_outlive_the_loader(how):
+    """After ``close()`` mid-epoch, after an iterator and its loader are
+    dropped mid-epoch, and after a process that never closed its loader has
+    exited (the atexit reaper), nothing of the loader is left under
+    ``/dev/shm`` and none of its workers is alive; nothing is warned of."""
+    import gc
+    import os
+    import subprocess
+    import sys
+
+    before = _workers()
+    if how == "exit":
+        done = subprocess.run([sys.executable, "-c", (
+            "import numpy as np, os\n"
+            "from pytorch_distributed_tpu.data import DataLoader, "
+            "SyntheticImageDataset\n"
+            "if __name__ == '__main__':\n"
+            "    ds = SyntheticImageDataset(length=64, num_classes=5, "
+            "image_size=8)\n"
+            "    loader = DataLoader(ds, batch_size=8, num_workers=2, "
+            "worker_type='process')\n"
+            "    it = iter(loader); next(it)\n"
+            "    print('SEGMENT', loader._ring.spec[0], flush=True)\n")],
+            capture_output=True, text=True, timeout=300,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        assert done.returncode == 0, done.stderr[-2000:]
+        segment = done.stdout.split("SEGMENT ")[1].split()[0]
+        assert "resource_tracker" not in done.stderr
+        assert "Error" not in done.stderr, done.stderr[-2000:]
+    else:
+        loader = _loader(n=64, num_workers=2, worker_type="process")
+        batches = iter(loader)
+        held = next(batches)
+        kept = {k: v.copy() for k, v in held.items()}
+        segment = loader._ring.spec[0]
+        assert _linked(segment) and len(_workers() - before) == 2
+        if how == "close":
+            loader.close()
+            for key in kept:  # unlinked, and mapped for as long as held
+                np.testing.assert_array_equal(held[key], kept[key], key)
+        del held, batches, loader
+        gc.collect()
+    assert not _linked(segment) and _workers() == before
+
+
+def test_a_ring_that_dev_shm_cannot_hold_is_refused_with_the_sizes(
+        monkeypatch):
+    import os
+    from types import SimpleNamespace
+
+    monkeypatch.setattr(os, "statvfs", lambda path: SimpleNamespace(
+        f_bavail=1, f_frsize=4096))
+    loader = _loader(worker_type="process")
+    with pytest.raises(OSError, match=(
+            r"4096 bytes free; 3 batch buffers of 8 x \(8, 8, 3\) float32 "
+            r"and a dataset of \d+ bytes need \d+")):
+        next(iter(loader))
+    assert loader._pool is None and loader._ring is None
+
+
+def test_process_workers_refuse_a_native_decode_dataset():
+    ds = SyntheticImageDataset(length=8, num_classes=5, image_size=8)
+    ds.native_decode = True
+    with pytest.raises(ValueError, match="worker_type='thread'"):
+        DataLoader(ds, batch_size=4, worker_type="process")
