@@ -402,10 +402,69 @@ def lm_step_cost(vocab_size: int, d_model: int, n_layers: int, batch: int,
     return _finish(wk, batch, recompute_fwd=recompute)
 
 
+def decoder_step_cost(config: Any, batch: int, seq_len: int,
+                      fused_ce: bool = False) -> StepCost:
+    """Analytic train-step cost for a configured decoder
+    (``models/decoder.DecoderConfig``): latent or plain heads, dense or
+    expert feed-forward, and a stack that runs ``total_ut_steps`` times
+    with a head after every pass.  It counts applications, not parameters:
+    a looped model pays ``passes x layers`` blocks and ``passes`` heads a
+    token while its optimizer runs over one set of weights.
+
+    The yardstick's conventions (``benchmark/flops_ouro.py``,
+    ``flops_kimi_vl_a3b.py``, held together by tests): causal attention is
+    half the square, the routed experts are counted at the uniform
+    expectation (``top_k * held / routed`` a token), every position's row
+    meets the head, the exit gate's ``d`` a row is left out.  ``remat`` and
+    the fused loss add their recomputed forward to the hardware count
+    only."""
+    c = config
+    d, heads, seq = c.hidden_size, c.num_attention_heads, seq_len
+    if c.kv_lora_rank:
+        qk, vd = c.qk_nope_head_dim + c.qk_rope_head_dim, c.v_head_dim
+        proj = (d * heads * qk + d * (c.kv_lora_rank + c.qk_rope_head_dim)
+                + c.kv_lora_rank * heads * (c.qk_nope_head_dim + vd)
+                + heads * vd * d)
+        attn_params = proj + c.kv_lora_rank
+    else:
+        qk = vd = c.head_dim or d // heads
+        proj = attn_params = 4 * d * heads * qk
+    norms = (4 if c.sandwich_norm else 2) * d
+    dense = 3 * d * c.intermediate_size
+    held = c.experts_held[1]
+    expert = 3 * d * c.moe_intermediate_size
+    expert_flops = (expert * c.n_shared_experts + d * c.n_routed_experts
+                    + (expert * c.num_experts_per_tok * held
+                       / c.n_routed_experts if held else 0.0))
+    expert_params = (expert * (c.n_shared_experts + held)
+                     + d * c.n_routed_experts)
+    passes, lead = c.total_ut_steps, c.first_k_dense_replace
+    wk = _Walk()
+    wk.params += 2 * c.vocab_size * d + d           # embedding, head, norm_f
+    if passes > 1:
+        wk.params += d + 1                          # the exit gate
+    for i in range(c.num_hidden_layers):
+        wk.params += attn_params + norms + (dense if i < lead
+                                            else expert_params)
+        ffn = dense if i < lead else expert_flops
+        wk.fwd += passes * seq * (2.0 * (proj + ffn)
+                                  + 2.0 * heads * (qk + vd) * seq / 2)
+        wk.act_elts += passes * seq * d
+    blocks = wk.fwd
+    head = passes * 2.0 * seq * d * c.vocab_size
+    wk.fwd += head
+    return _finish(wk, batch, recompute_fwd=(
+        (blocks if c.remat else 0.0) + (head if fused_ce else 0.0)))
+
+
 def lm_step_cost_for(model: Any, batch: int, seq_len: int,
                      fused_ce_chunks: int = 0) -> StepCost:
     """Build the LM cost from a live model instance (TransformerLM or
-    PipelinedTransformerLM — both carry the config attributes)."""
+    PipelinedTransformerLM — both carry the config attributes — or a
+    configured ``DecoderLM``, from its ``config``)."""
+    if hasattr(model, "config"):
+        return decoder_step_cost(model.config, batch, seq_len,
+                                 fused_ce=bool(fused_ce_chunks))
     n_layers = getattr(model, "n_layers", None)
     if n_layers is None:  # pipeline model: chunks x blocks-per-chunk
         n_layers = int(model.n_chunks) * int(model.n_blocks)

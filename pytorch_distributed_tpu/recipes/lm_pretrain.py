@@ -20,10 +20,14 @@ of the TransformerLM with the framework's parallelism menu —
   dims of the expert stacks shard over ``data``)
 - remaining devices form the ``data`` axis (gradient psum)
 - ``--model-config FILE`` a decoder built from a configuration file with
-  the catalog's key names (``models/decoder.py``: latent attention, shared
-  and routed experts, an untied head) in place of the TransformerLM, trained
-  with the file's AdamW over the ``data`` axis; training only (no serving,
-  no vision tower); ``--rehearse`` lays the file's CPU preset over it
+  the catalog's key names (``models/decoder.py``) in place of the
+  TransformerLM, trained with the file's AdamW over the ``data`` axis.  The
+  file's keys choose the block: latent or plain multi-head attention, dense
+  feed-forward or shared and routed experts, two or four norms a block, and
+  with ``total_ut_steps`` > 1 a stack that runs several times over the same
+  weights, an exit after every pass and the loss weighted over the exits.
+  Training only (no serving, no early exit, no vision tower);
+  ``--rehearse`` lays the file's CPU preset over it
 
 Examples (8 simulated chips):
 
@@ -35,6 +39,9 @@ Examples (8 simulated chips):
         --n-layers 8 -b 16 --steps 20
     python -m pytorch_distributed_tpu.recipes.lm_pretrain --rehearse \
         --model-config benchmark/configs/kimi-vl-a3b-ep8.json \
+        --seq-len 64 -b 8 --steps 20
+    python -m pytorch_distributed_tpu.recipes.lm_pretrain --rehearse \
+        --model-config benchmark/configs/ouro-2.6b.json \
         --seq-len 64 -b 8 --steps 20
 """
 

@@ -327,8 +327,16 @@ def make_lm_train_step(
     A model with a ``state_collection`` (models/decoder.py: the experts'
     selection bias) has that state read from ``state.batch_stats``, updated
     after the gradients by its own ``update_state`` from the counters the
-    forward pass sowed (no gradient, no optimizer), and its routing
-    counters (``step_counters``) added to the metrics."""
+    forward pass sowed (no gradient, no optimizer).  A model with
+    ``counter_names`` has its ``step_counters`` added to the metrics.
+
+    A model with ``n_exits`` > 1 (models/decoder.py: a looped decoder)
+    returns every exit's hidden rows ``[T, B, L, d]`` and sows a float32
+    weight for each (``exits/weight`` [T, B, L], summing to 1 over T): the
+    loss is the mean over positions of the weighted sum of the exits'
+    cross-entropies, one fused call over all ``T*B*(L-1)`` rows, plus
+    whatever the model sowed under ``losses``; the gradient reaches the
+    model through the weights too."""
     from pytorch_distributed_tpu.parallel import overlap as overlap_lib
     from pytorch_distributed_tpu.parallel import zero as zero_lib
 
@@ -417,7 +425,13 @@ def make_lm_train_step(
             fused_ce_mode, param_specs, mesh,
             getattr(model, "vocab_size", None), data_axis, model=model)
     # what the forward pass may write besides the sown losses
-    mutable = ["losses", "counters"] if state_col else ["losses"]
+    counted = bool(getattr(model, "counter_names", ()))
+    mutable = ["losses", "counters", "exits"] if counted else ["losses"]
+    n_exits = getattr(model, "n_exits", 1)
+    if n_exits > 1 and not (fused_ce_chunks and accum_steps == 1):
+        raise ValueError(
+            "a model with several exits trains on the fused loss, "
+            "unaccumulated: pass fused_ce_chunks > 0, accum_steps = 1")
 
     def step(state: TrainState, tokens: jnp.ndarray, lr: jnp.ndarray):
         def variables(params):
@@ -425,14 +439,14 @@ def make_lm_train_step(
                 return {"params": params, state_col: state.batch_stats}
             return {"params": params}
 
-        def loss_fn(params, toks):
+        def loss_fn(params, toks, probe=None):
             # named_scope: forward ops carry the phase name into XPlane
             # traces (autodiff derives the backward names from it) —
             # per-phase self-time instead of anonymous fusions.
             with jax.named_scope("lm_forward"):
-                return loss_impl(params, toks)
+                return loss_impl(params, toks, probe)
 
-        def loss_impl(params, toks):
+        def loss_impl(params, toks, probe):
             if fused_ce_chunks:
                 # Fused tied-head + CE (ops/fused_ce.py): the [B, L, V]
                 # logits tensor never materializes — hidden rows project
@@ -453,9 +467,22 @@ def make_lm_train_step(
                 )
                 d = hidden.shape[-1]
                 cdt = getattr(model, "dtype", jnp.float32)
-                h = hidden[:, :-1].reshape(-1, d).astype(cdt)
+                # one exit: [B, L, d]; several: every exit's rows
+                # [T, B, L, d] in one call, so that the head's gradient
+                # accumulates once
+                h = hidden[..., :-1, :].reshape(-1, d).astype(cdt)
                 t = toks[:, 1:].reshape(-1)
                 w = jnp.ones(t.shape, jnp.float32)
+                ntok = t.shape[0]
+                if n_exits > 1:
+                    # a row's weight is the model's exit distribution, and
+                    # the loss's cotangent on it is what teaches the gate.
+                    # ``probe`` [T] is zero: its own cotangent is each
+                    # exit's mean cross-entropy
+                    t = jnp.tile(t, n_exits)
+                    with jax.named_scope("exit_loss"):
+                        w = (sown["exits"]["weight"][0][..., :-1]
+                             + probe[:, None, None]).reshape(-1)
                 e = head_matrix(model, params).astype(cdt)
                 if ce_mode == "tp":
                     loss_sum, correct = fused_ce_sums_tp(
@@ -468,7 +495,6 @@ def make_lm_train_step(
                 else:
                     loss_sum, correct = fused_ce_sums(
                         h, e, t, w, fused_ce_chunks)
-                ntok = h.shape[0]
                 loss = loss_sum / ntok
                 for leaf in jax.tree_util.tree_leaves(
                         sown.get("losses", {})):
@@ -499,6 +525,11 @@ def make_lm_train_step(
             # interleaved scan, not autodiff over the whole step
             # (models/pipeline_lm.py loss_and_grads).
             (loss, acc), grads = model.loss_and_grads(state.params, tokens)
+        elif n_exits > 1:
+            (loss, (acc, seen)), (grads, exit_losses) = jax.value_and_grad(
+                loss_fn, argnums=(0, 2), has_aux=True)(
+                    state.params, tokens, jnp.zeros((n_exits,), jnp.float32))
+            seen = {**seen, "exit_losses": exit_losses}
         elif accum_steps == 1:
             (loss, (acc, seen)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params, tokens)
@@ -574,6 +605,7 @@ def make_lm_train_step(
         new_model_state = state.batch_stats
         if state_col:
             new_model_state = model.update_state(state.batch_stats, seen)
+        if counted:
             metrics.update(model.step_counters(new_model_state, seen))
         if guard_nonfinite:
             bad = nonfinite_flag(loss, gnorm)

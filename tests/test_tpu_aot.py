@@ -39,16 +39,19 @@ def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
-@pytest.mark.parametrize("shape,causal", [
-    ((2, 1024, 4, 64), True),    # aligned: 256/1024 blocks
-    ((8, 197, 12, 64), False),   # ViT-B/16: one full-dimension block
+@pytest.mark.parametrize("shape,causal,blocks", [
+    ((2, 1024, 4, 64), True, (256, 1024)),    # aligned
+    ((8, 197, 12, 64), False, (256, 1024)),   # ViT-B/16: one full block
+    # the looped decoder's call (models/decoder.py MHA): 16 heads of 128
+    # at 8,192 positions, the blocks the decoder asks for
+    ((2, 8192, 16, 128), True, (1024, 1024)),
 ])
-def test_flash_fwd_bwd_compiles_for_v5e(v5e_devices, shape, causal):
+def test_flash_fwd_bwd_compiles_for_v5e(v5e_devices, shape, causal, blocks):
     one = NamedSharding(Mesh(np.array(v5e_devices[:1]), ("x",)), P())
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
 
     def loss(q, k, v):
-        out = fa.flash_attention(q, k, v, causal, 256, 1024, False)
+        out = fa.flash_attention(q, k, v, causal, *blocks, False)
         return out.astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
