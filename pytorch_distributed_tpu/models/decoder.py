@@ -185,6 +185,29 @@ def dense_attention(q, k, v, scale: float) -> jnp.ndarray:
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
+# The flash kernels' (q, kv) block sizes at L = 8192, gradient of one call
+# (forward + dq + dk/dv) over 32 batch-heads of 128 | 128 and over 64 of
+# 192 | 128: 1024 x 1024 19.32 / 54.96 ms, 512 x 1024 20.68 / 58.34,
+# 512 x 2048 20.96 / out of VMEM, 256 x 1024 (the kernel's default) 23.76 /
+# 64.88, 2048 x 512 24.49 / out of VMEM, 1024 x 512 24.86 / 67.47,
+# 512 x 512 25.81 / 68.11 (my chip run, PR 31): one size for both widths
+FLASH_BLOCKS = (1024, 1024)
+
+
+def attention_blocks(L: int, attn_impl: str) -> Tuple[int, int]:
+    """``(visited, masked)``: the score blocks one forward call of
+    ``causal_attention`` visits a batch-head and those of them it masks;
+    ``(0, 0)`` where it takes the dense path."""
+    from pytorch_distributed_tpu.ops.flash_attention import (
+        blocks_visited,
+        pick_attention_impl,
+    )
+
+    if pick_attention_impl(L, attn_impl) != "flash":
+        return 0, 0
+    return blocks_visited(L, *FLASH_BLOCKS)
+
+
 def causal_attention(q, k, v, scale: float, mesh: Optional[Mesh],
                      attn_impl: str) -> jnp.ndarray:
     """The Pallas flash kernel where the shared policy picks it (a TPU at
@@ -195,12 +218,9 @@ def causal_attention(q, k, v, scale: float, mesh: Optional[Mesh],
     )
 
     if pick_attention_impl(q.shape[1], attn_impl) == "flash":
-        # 1024 x 1024 blocks: at L = 8192 and heads of 192 | 128 the
-        # forward kernel takes 17.4 ms against 27.8 at the kernel's
-        # default 256 x 1024, backward 48.6 against 57.9; 2048 in
-        # either place runs out of VMEM (my chip run, PR 26)
+        bq, bk = FLASH_BLOCKS
         return flash_attention_on_mesh(
-            q, k, v, True, mesh, block_q=1024, block_k=1024, scale=scale)
+            q, k, v, True, mesh, block_q=bq, block_k=bk, scale=scale)
     return dense_attention(q, k, v, scale)
 
 
@@ -385,6 +405,10 @@ class DecoderLM(nn.Module):
     def __call__(self, tokens, train: bool = True,
                  return_hidden: bool = False):
         c = self.config
+        # which attention the blocks below run at this length: a constant
+        # of the compiled program
+        self.sow("counters", "attn_blocks", jnp.array(
+            attention_blocks(tokens.shape[1], self.attn_impl), jnp.int32))
         x = nn.Embed(c.vocab_size, c.hidden_size, dtype=self.dtype,
                      name="embed")(tokens)
         block_cls = nn.remat(DecoderBlock) if c.remat else DecoderBlock
@@ -459,13 +483,19 @@ class DecoderLM(nn.Module):
         ``dispatch`` record, the benchmark's runners read them from the
         step's metrics."""
         exits = range(1, self.n_exits + 1) if self.n_exits > 1 else ()
-        return ((self.ROUTING_COUNTERS if self.config.expert_layers else ())
+        return (("attn_blocks_visited", "attn_blocks_masked")
+                + (self.ROUTING_COUNTERS if self.config.expert_layers else ())
                 + (("block_applications", "exit_entropy") if exits else ())
                 + tuple(f"exit_p_{t}" for t in exits)
                 + tuple(f"loss_exit_{t}" for t in exits))
 
     def step_counters(self, model_state, counters):
-        """The counters a step reports.  Routing, each summed over the
+        """The counters a step reports.  ``attn_blocks_visited`` and
+        ``attn_blocks_masked``: the score blocks one forward call of the
+        causal attention visits a batch-head, and those of them the
+        diagonal crosses (``ops/flash_attention.py`` ``block_schedule``; 0
+        and 0 on the dense path; constants of the compiled step).
+        Routing, each summed over the
         expert layers: ``routed_here`` (pairs on held experts),
         ``rows_grouped`` (rows the grouped products processed),
         ``expert_rows_max`` and ``expert_rows_mean`` (over the held
@@ -475,7 +505,8 @@ class DecoderLM(nn.Module):
         ``exit_p_t`` (the batch's mean of each exit's weight),
         ``exit_entropy``, and ``loss_exit_t``, each exit's own mean
         cross-entropy (``counters["exit_losses"]``, from the step)."""
-        out = {}
+        visited, masked = counters["attn_blocks"][0]
+        out = {"attn_blocks_visited": visited, "attn_blocks_masked": masked}
         if self.config.expert_layers:
             layers = [layer["moe"] for name, layer in counters.items()
                       if name.startswith("layer_")]

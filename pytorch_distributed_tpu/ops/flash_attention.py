@@ -8,17 +8,22 @@ keeps score blocks in VMEM with online softmax, so HBM traffic stays
 O(L·D) and memory O(L·BK) — the single-chip complement of the cross-chip
 ring attention in parallel/ring.py (which this kernel's math mirrors).
 
-Forward: Pallas kernel, grid (batch·heads, q-blocks, kv-blocks), f32
-accumulators in VMEM scratch, causal blocks skipped via predication.
-Backward: fused Pallas kernels in the flash-attention-2 decomposition —
-a dq pass (grid bh × q-blocks × kv-blocks) and a dk/dv pass (grid
-bh × kv-blocks × q-blocks), both recomputing P online from the saved
+Every kernel runs the grid (batch·heads, steps of a ``block_schedule``):
+the schedule lists, at trace time, the (q-block, kv-block) pairs the mask
+leaves, and its int32 tables reach the index maps and the kernel by scalar
+prefetch.  A causal call therefore has no grid step (no copy, no wait) for
+a block above the diagonal, and masks only the blocks the diagonal crosses;
+a call that is not causal visits the rectangle with the same kernels.
+Forward: f32 accumulators in VMEM scratch, online softmax over the
+kv-blocks of a q-block.  Backward: the flash-attention-2 decomposition —
+a dq pass (the forward's order) and a dk/dv pass (the q-blocks of one
+kv-block after another), both recomputing P online from the saved
 logsumexp with VMEM accumulators; O(L·BK) memory, every matmul on the MXU.
 ``bwd_impl="xla"`` selects the plain-XLA blockwise recompute (the oracle
 the kernels are tested against).
 
-Layout: [B, L, H, D] like parallel/ring.py; block sizes default to the
-128-lane MXU tile.  ``q`` and ``k`` share one head size and ``v`` may have
+Layout: [B, L, H, D] like parallel/ring.py; blocks default to 256 × 1024
+(q × kv).  ``q`` and ``k`` share one head size and ``v`` may have
 another (latent attention: 192 | 128); ``scale`` defaults to
 ``D_qk ** -0.5``.  The kernels' matrix products take their operands in the
 type of ``q``, ``k`` and ``v`` (bf16 in, bf16 on the MXU); accumulation and
@@ -28,10 +33,11 @@ the softmax are float32.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -39,41 +45,107 @@ from jax.sharding import Mesh, PartitionSpec as P
 NEG_INF = -1e30
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale: float, causal: bool,
-                block_q: int, block_k: int):
-    """One (bh, qi, kj) grid step: accumulate q-block × kv-block online."""
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-    nk = pl.num_programs(2)
+class BlockSchedule(NamedTuple):
+    """The blocks a kernel visits, in visiting order: one int32 entry a
+    grid step in each table."""
 
-    @pl.when(kj == 0)
+    q_block: np.ndarray
+    kv_block: np.ndarray
+    crossed: np.ndarray    # 1: the diagonal crosses the block (a masked pair)
+    first: np.ndarray      # 1: the first step of its accumulation row
+    last: np.ndarray       # 1: the last step of its accumulation row
+
+
+def block_schedule(L: int, block_q: int, block_k: int, causal: bool,
+                   order: str) -> BlockSchedule:
+    """Which ``(q-block, kv-block)`` pairs of an ``L x L`` score matrix a
+    kernel visits, and which of them it masks.
+
+    Causal: the blocks holding an unmasked pair (``k <= q``), so none above
+    the diagonal; ``crossed`` marks those that also hold a masked pair.
+    Not causal: the whole rectangle, none marked.  ``order="q"`` visits the
+    live kv-blocks of one q-block after another (the forward and the dq
+    pass accumulate over them), ``order="kv"`` the live q-blocks of one
+    kv-block after another (the dk/dv pass); ``first`` / ``last`` bracket
+    each such row, and every row has a live block."""
+    if order not in ("q", "kv"):
+        raise ValueError(f"unknown order {order!r}: expected 'q' or 'kv'")
+    qi, kj = np.meshgrid(np.arange(L // block_q), np.arange(L // block_k),
+                         indexing="ij")
+    q_lo, k_lo = qi * block_q, kj * block_k
+    if causal:
+        live = k_lo <= q_lo + block_q - 1
+        crossed = live & (k_lo + block_k - 1 > q_lo)
+    else:
+        live = np.ones(qi.shape, bool)
+        crossed = np.zeros(qi.shape, bool)
+    if order == "q":
+        row, col = np.nonzero(live)
+        q_block, kv_block = row, col
+    else:
+        row, col = np.nonzero(live.T)
+        q_block, kv_block = col, row
+    turn = row[1:] != row[:-1]
+    return BlockSchedule(*(np.asarray(t, np.int32) for t in (
+        q_block, kv_block, crossed[q_block, kv_block],
+        np.r_[True, turn], np.r_[turn, True])))
+
+
+def _scores(q, k, scale, masked_at, block_q, block_k):
+    """A block's float32 scores ``[BQ, BK]``; ``masked_at`` is the
+    ``(q-block, kv-block)`` index of a block the diagonal crosses, ``None``
+    below the diagonal."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    if masked_at is not None:
+        qi, kj = masked_at
+        qpos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        kpos = kj * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(kpos <= qpos, s, NEG_INF)
+    return s
+
+
+def _this_step(refs, causal: bool):
+    """Split a kernel's references into the schedule's tables, which come
+    first, and the rest; of this grid step's entries return ``first``,
+    ``last`` and ``on_block(body)``, which runs ``body(masked_at)`` masked
+    where the diagonal crosses the step's block and unmasked below it."""
+    n = len(BlockSchedule._fields)
+    sched = BlockSchedule(*refs[:n])
+    step = pl.program_id(1)
+    at = (sched.q_block[step], sched.kv_block[step])
+    crossed = sched.crossed[step]
+
+    def on_block(body):
+        if not causal:
+            return body(None)
+        pl.when(crossed == 1)(functools.partial(body, at))
+        pl.when(crossed == 0)(functools.partial(body, None))
+
+    return sched.first[step] == 1, sched.last[step] == 1, on_block, refs[n:]
+
+
+def _fwd_kernel(*refs, scale: float, causal: bool,
+                block_q: int, block_k: int):
+    """One (bh, step) grid step: accumulate the schedule's q-block x
+    kv-block online."""
+    first, last, on_block, refs = _this_step(refs, causal)
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Causal: whole block masked out when the kv block starts after the
-    # q block ends; cheap predication, no wasted MXU work.
-    run = True
-    if causal:
-        run = kj * block_k <= qi * block_q + (block_q - 1)
-
-    @pl.when(run)
-    def _block():
-        q = q_ref[0]                                # [BQ, D]
-        k = k_ref[0]                                # [BK, D]
-        v = v_ref[0]                                # [BK, Dv]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                    # [BQ, BK]
-        if causal:
-            qpos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
+    def block(masked_at):
+        v = v_ref[0]                                 # [BK, Dv]
+        s = _scores(q_ref[0], k_ref[0], scale, masked_at,
+                    block_q, block_k)                # [BQ, BK]
         m_prev = m_scr[:, :1]                        # [BQ, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)                       # [BQ, BK]
@@ -86,7 +158,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(kj == nk - 1)
+    on_block(block)
+
+    @pl.when(last)
     def _final():
         l = l_scr[:, :1]
         safe_l = jnp.maximum(l, 1e-30)
@@ -98,41 +172,70 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         )
 
 
+def _blocks(L: int, block_q: int, block_k: int):
+    bq = min(block_q, L)
+    bk = min(block_k, L)
+    assert L % bq == 0 and L % bk == 0, (
+        f"sequence length {L} must divide block sizes ({bq}, {bk})"
+    )
+    return bq, bk
+
+
+def blocks_visited(L: int, block_q: int, block_k: int,
+                   causal: bool = True):
+    """``(visited, masked)``: the score blocks one forward call visits a
+    batch-head, and those of them it masks."""
+    sched = block_schedule(L, *_blocks(L, block_q, block_k), causal, "q")
+    return len(sched.crossed), int(sched.crossed.sum())
+
+
+def _scheduled_call(kernel, sched: BlockSchedule, batch_heads: int,
+                    in_specs, out_specs, out_shape, scratch_shapes,
+                    interpret: bool):
+    """``pallas_call`` over the grid (batch-heads, the schedule's steps),
+    the schedule's tables prefetched as scalars: the index maps of
+    ``_spec`` and the kernel read them."""
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(sched),
+            grid=(batch_heads, len(sched.q_block)),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        interpret=interpret,
+    )
+
+
+def _spec(side: str, rows: int, width: int) -> pl.BlockSpec:
+    """A ``[1, rows, width]`` block of a ``[B*H, L, width]`` array: the
+    step's q-block (``side="q"``) or its kv-block."""
+    table = BlockSchedule._fields.index(f"{side}_block")
+    return pl.BlockSpec((1, rows, width),
+                        lambda b, s, *tables: (b, tables[table][s], 0),
+                        memory_space=pltpu.VMEM)
+
+
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
                interpret: bool, scale: Optional[float] = None):
     B, L, H, D = q.shape
     Dv = v.shape[-1]
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    bq = min(block_q, L)
-    bk = min(block_k, L)
-    assert L % bq == 0 and L % bk == 0, (
-        f"sequence length {L} must divide block sizes ({bq}, {bk})"
-    )
+    bq, bk = _blocks(L, block_q, block_k)
     # [B, L, H, D] -> [B*H, L, D]
     qr = q.transpose(0, 2, 1, 3).reshape(B * H, L, D)
     kr = k.transpose(0, 2, 1, 3).reshape(B * H, L, D)
     vr = v.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
 
-    grid = (B * H, L // bq, L // bk)
-    out, lse = pl.pallas_call(
+    sched = block_schedule(L, bq, bk, causal, "q")
+    out, lse = _scheduled_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 128), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        sched, B * H,
+        in_specs=[_spec("q", bq, D), _spec("kv", bk, D),
+                  _spec("kv", bk, Dv)],
+        out_specs=[_spec("q", bq, Dv), _spec("q", bq, 128)],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, L, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, L, 128), jnp.float32),
@@ -143,28 +246,15 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((bq, Dv), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
-    )(qr, kr, vr)
+    )(*sched, qr, kr, vr)
     # Residual lse is [B*H, L] (lane 0 of the kernel's lane-broadcast
     # output) — saving the full 128-lane layout would hold 128x the bytes
     # across the fwd->bwd interval; the backward re-broadcasts cheaply.
     return out.reshape(B, H, L, Dv).transpose(0, 2, 1, 3), lse[:, :, 0]
 
 
-def _causal_run(qi, kj, block_q, block_k):
-    """Whole-block predicate: any (q, k) pair in the block is unmasked."""
-    return kj * block_k <= qi * block_q + (block_q - 1)
-
-
-def _mask_scores(s, qi, kj, block_q, block_k):
-    qpos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    kpos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return jnp.where(kpos <= qpos, s, NEG_INF)
-
-
-def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qi, kj,
-                    scale, causal, block_q, block_k):
+def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
+                    scale, masked_at, block_q, block_k):
     """Shared backward block math: online-recomputed (p, ds) plus the
     block views — the single source for both the dq and dk/dv kernels (and
     the same masking the forward kernel applies)."""
@@ -174,12 +264,7 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qi, kj,
     do = do_ref[0]                               # [BQ, Dv]
     lse = lse_ref[0][:, :1]                      # [BQ, 1]
     dlt = dlt_ref[0][:, :1]                      # [BQ, 1]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale                                    # [BQ, BK]
-    if causal:
-        s = _mask_scores(s, qi, kj, block_q, block_k)
+    s = _scores(q, k, scale, masked_at, block_q, block_k)    # [BQ, BK]
     p = jnp.exp(s - lse)                         # [BQ, BK]
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -189,56 +274,47 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qi, kj,
     return p.astype(do.dtype), ds.astype(q.dtype), q, k, do
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
-                   dq_scr, *, scale: float, causal: bool,
+def _bwd_dq_kernel(*refs, scale: float, causal: bool,
                    block_q: int, block_k: int):
-    """dq pass: grid (bh, qi, kj), accumulate dq_i over kv blocks."""
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-    nk = pl.num_programs(2)
+    """dq pass: grid (bh, step), the schedule in q order: accumulate dq_i
+    over its live kv blocks."""
+    first, last, on_block, refs = _this_step(refs, causal)
+    operands, (dq_ref, dq_scr) = refs[:6], refs[6:]
 
-    @pl.when(kj == 0)
+    @pl.when(first)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    run = _causal_run(qi, kj, block_q, block_k) if causal else True
-
-    @pl.when(run)
-    def _block():
+    def block(masked_at):
         _, ds, _, k, _ = _recompute_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qi, kj,
-            scale, causal, block_q, block_k)
+            *operands, scale, masked_at, block_q, block_k)
         dq_scr[:] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(kj == nk - 1)
+    on_block(block)
+
+    @pl.when(last)
     def _final():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
-                    causal: bool, block_q: int, block_k: int):
-    """dk/dv pass: grid (bh, kj, qi), accumulate over q blocks."""
-    kj = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+def _bwd_dkv_kernel(*refs, scale: float, causal: bool,
+                    block_q: int, block_k: int):
+    """dk/dv pass: grid (bh, step), the schedule in kv order: accumulate
+    dk_j, dv_j over the q blocks at or below the diagonal."""
+    first, last, on_block, refs = _this_step(refs, causal)
+    operands, (dk_ref, dv_ref, dk_scr, dv_scr) = refs[:6], refs[6:]
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    # q block entirely before the kv block contributes nothing.
-    run = _causal_run(qi, kj, block_q, block_k) if causal else True
-
-    @pl.when(run)
-    def _block():
+    def block(masked_at):
         p, ds, q, _, do = _recompute_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qi, kj,
-            scale, causal, block_q, block_k)
+            *operands, scale, masked_at, block_q, block_k)
         dv_scr[:] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -248,7 +324,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
             preferred_element_type=jnp.float32,
         )                                            # [BK, D]
 
-    @pl.when(qi == nq - 1)
+    on_block(block)
+
+    @pl.when(last)
     def _final():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -264,8 +342,7 @@ def _bwd_pallas(res, g, causal: bool, block_q: int, block_k: int,
     Dv = v.shape[-1]
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    bq = min(block_q, L)
-    bk = min(block_k, L)
+    bq, bk = _blocks(L, block_q, block_k)
     f32 = jnp.float32
     qr = q.transpose(0, 2, 1, 3).reshape(B * H, L, D)
     kr = k.transpose(0, 2, 1, 3).reshape(B * H, L, D)
@@ -276,48 +353,32 @@ def _bwd_pallas(res, g, causal: bool, block_q: int, block_k: int,
     # Lane-broadcast for block slicing (transient, not a saved residual).
     lse128 = jnp.broadcast_to(lse[:, :, None], (B * H, L, 128))
     dlt128 = jnp.broadcast_to(delta[:, :, None], (B * H, L, 128))
+    operands = (qr, kr, vr, gr, lse128, dlt128)
+    in_specs = [_spec("q", bq, D), _spec("kv", bk, D), _spec("kv", bk, Dv),
+                _spec("q", bq, Dv), _spec("q", bq, 128), _spec("q", bq, 128)]
+    static = dict(scale=scale, causal=causal, block_q=bq, block_k=bk)
 
-    def spec_q(pos_q, width=D):
-        return pl.BlockSpec((1, bq, width),
-                            lambda b, x, y: (b, (x, y)[pos_q], 0),
-                            memory_space=pltpu.VMEM)
-
-    def spec_k(pos_k, width=D):
-        return pl.BlockSpec((1, bk, width),
-                            lambda b, x, y: (b, (x, y)[pos_k], 0),
-                            memory_space=pltpu.VMEM)
-
-    def spec_l(pos_q):
-        return pl.BlockSpec((1, bq, 128), lambda b, x, y: (b, (x, y)[pos_q], 0),
-                            memory_space=pltpu.VMEM)
-
-    # dq: grid (bh, qi, kj) — q-side blocks keyed by grid pos 0, kv by 1.
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk),
-        grid=(B * H, L // bq, L // bk),
-        in_specs=[spec_q(0), spec_k(1), spec_k(1, Dv), spec_q(0, Dv),
-                  spec_l(0), spec_l(0)],
-        out_specs=[spec_q(0)],
+    sched = block_schedule(L, bq, bk, causal, "q")
+    dq = _scheduled_call(
+        functools.partial(_bwd_dq_kernel, **static), sched, B * H,
+        in_specs=in_specs,
+        out_specs=[_spec("q", bq, D)],
         out_shape=[jax.ShapeDtypeStruct((B * H, L, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((bq, D), f32)],
         interpret=interpret,
-    )(qr, kr, vr, gr, lse128, dlt128)[0]
+    )(*sched, *operands)[0]
 
-    # dk/dv: grid (bh, kj, qi) — kv blocks keyed by grid pos 0, q by 1.
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk),
-        grid=(B * H, L // bk, L // bq),
-        in_specs=[spec_q(1), spec_k(0), spec_k(0, Dv), spec_q(1, Dv),
-                  spec_l(1), spec_l(1)],
-        out_specs=[spec_k(0), spec_k(0, Dv)],
+    sched = block_schedule(L, bq, bk, causal, "kv")
+    dk, dv = _scheduled_call(
+        functools.partial(_bwd_dkv_kernel, **static), sched, B * H,
+        in_specs=in_specs,
+        out_specs=[_spec("kv", bk, D), _spec("kv", bk, Dv)],
         out_shape=[jax.ShapeDtypeStruct((B * H, L, D), k.dtype),
                    jax.ShapeDtypeStruct((B * H, L, Dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, D), f32),
                         pltpu.VMEM((bk, Dv), f32)],
         interpret=interpret,
-    )(qr, kr, vr, gr, lse128, dlt128)
+    )(*sched, *operands)
 
     def back(x):
         return x.reshape(B, H, L, x.shape[-1]).transpose(0, 2, 1, 3)

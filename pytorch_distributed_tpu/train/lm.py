@@ -547,11 +547,10 @@ def make_lm_train_step(
 
             def body(carry, mb):
                 g_acc, loss_acc, acc_acc = carry
-                (l, (a, _)), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                    state.params, mb
-                )
+                (l, (a, counters)), g = jax.value_and_grad(
+                    loss_fn, has_aux=True)(state.params, mb)
                 g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
-                return (g_acc, loss_acc + l, acc_acc + a), None
+                return (g_acc, loss_acc + l, acc_acc + a), counters
 
             init = (
                 jax.tree_util.tree_map(
@@ -559,7 +558,9 @@ def make_lm_train_step(
                 jnp.float32(0.0),
                 jnp.float32(0.0),
             )
-            (grads, loss, acc), _ = jax.lax.scan(body, init, micro)
+            (grads, loss, acc), seen = jax.lax.scan(body, init, micro)
+            # a model's counters: the last microbatch's
+            seen = jax.tree_util.tree_map(lambda c: c[-1], seen)
             inv = 1.0 / accum_steps  # means-of-equal-size-microbatch-means
             grads = jax.tree_util.tree_map(
                 lambda g, p: (g * inv).astype(p.dtype), grads, state.params)

@@ -374,7 +374,8 @@ def test_tx_none_lowers_the_transformer_lm_step_as_before():
 
 def test_flash_kernel_on_float32_lowers_as_before():
     """One head size, the default scale, float32 in: forward and the two
-    backward kernels lower to the text the commit before PR 26 gave."""
+    backward kernels lower to the text PR 31 gave them (the block
+    schedule by scalar prefetch; PR 26's text until then)."""
     from pytorch_distributed_tpu.ops.flash_attention import flash_attention
 
     q = jnp.zeros((1, 256, 2, 64), jnp.float32)
@@ -384,7 +385,7 @@ def test_flash_kernel_on_float32_lowers_as_before():
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "7e729fcbea1d9dbee48a764b6de339fedd487c13b30f8555ba80a256f2b7a5a8")
+        "7bb5b9f706683e764df07cc55eadf31843d60056e538d0eb1064172afef90e00")
 
 
 def test_the_two_copies_of_the_reference_are_identical():

@@ -218,6 +218,49 @@ def test_step_counts_passes_times_layers_block_applications(f32, f32_step):
     assert "routed_here" not in metrics
 
 
+def test_step_reports_no_attention_blocks_on_the_dense_path(f32, f32_step):
+    """The CPU's ``auto`` policy takes explicit scores: no block schedule."""
+    metrics, _ = f32_step
+    assert f32["model"].counter_names[:2] == (
+        "attn_blocks_visited", "attn_blocks_masked")
+    assert int(metrics["attn_blocks_visited"]) == 0
+    assert int(metrics["attn_blocks_masked"]) == 0
+
+
+@pytest.mark.parametrize("passes", [1, 2])
+def test_step_reports_the_schedules_blocks_under_flash(monkeypatch, passes):
+    """``attn_impl="flash"`` (the Pallas interpreter here) on 16 x 32
+    blocks at L = 64: the step's counters are the schedule's counts, for a
+    looped decoder and for a plain one, and its loss is the dense path's."""
+    from pytorch_distributed_tpu.models import decoder
+    from pytorch_distributed_tpu.ops.flash_attention import blocks_visited
+
+    monkeypatch.setattr(decoder, "FLASH_BLOCKS", (16, 32))
+    assert blocks_visited(L, 16, 32) == (6, 4)
+    over = dict(total_ut_steps=passes, num_hidden_layers=1,
+                layer_types=["full_attention"])
+    tokens, tx = _tokens(), optax.sgd(1.0)
+    params = _init(_model(**over))
+    metrics = {}
+    for impl in ("flash", "dense"):
+        model = DecoderLM(DecoderConfig.from_dict({**PRESET, **over}),
+                          attn_impl=impl)
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            step = make_lm_train_step(
+                model, data_parallel_mesh(jax.devices()[:1]),
+                replicated_like(params), tx=tx, params=params,
+                fused_ce_chunks=CHUNKS)
+        state = TrainState.create({"params": params}, tx.init(params))
+        _, metrics[impl] = step(jax.tree_util.tree_map(jnp.copy, state),
+                                tokens, jnp.float32(0.0))
+    assert int(metrics["flash"]["attn_blocks_visited"]) == 6
+    assert int(metrics["flash"]["attn_blocks_masked"]) == 4
+    assert int(metrics["dense"]["attn_blocks_visited"]) == 0
+    np.testing.assert_allclose(metrics["flash"]["loss"],
+                               metrics["dense"]["loss"], atol=1e-4)
+
+
 # ------------------------------------------------------ the loss over exits
 
 def test_fused_weighted_loss_equals_the_unfused_sum_over_exits(f32, f32_step):
@@ -415,8 +458,10 @@ def _digest(step, state, tokens):
 
 def test_the_kimi_presets_step_lowers_as_before():
     """The configured decoder with latent attention and experts, bf16,
-    AdamW, the fused loss: the lowered text's digest as the commit before
-    PR 30 gave it."""
+    AdamW, the fused loss: the lowered text's digest.  It held through PR
+    30 and through PR 31's kernels (``66cff231...``: the CPU takes the
+    dense path); PR 31's two counters, ``attn_blocks_visited`` and
+    ``attn_blocks_masked`` among the step's metrics, then moved it."""
     from test_decoder import PRESET as KIMI
 
     model = DecoderLM(DecoderConfig.from_dict(KIMI), dtype=jnp.bfloat16)
@@ -434,7 +479,7 @@ def test_the_kimi_presets_step_lowers_as_before():
             model, mesh, replicated_like(state.params), tx=tx,
             params=state.params, fused_ce_chunks=2)
     assert _digest(step, state, tokens) == (
-        "66cff2316e795f7d6824d2b09669d335dab49ee32f8caba94c19a034d232afcd")
+        "97685ce6631d8fb353713ba87be537306a3abb819dadbd5f599cd2a953102985")
 
 
 def test_the_transformer_lms_fused_step_lowers_as_before():

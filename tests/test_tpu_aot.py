@@ -45,17 +45,22 @@ def _mosaic_calls(compiled) -> int:
     # the looped decoder's call (models/decoder.py MHA): 16 heads of 128
     # at 8,192 positions, the blocks the decoder asks for
     ((2, 8192, 16, 128), True, (1024, 1024)),
+    # the latent-attention decoder's (MLA): 192-wide queries and keys,
+    # 128-wide values (the last entry), the same blocks
+    ((4, 8192, 16, 192, 128), True, (1024, 1024)),
 ])
 def test_flash_fwd_bwd_compiles_for_v5e(v5e_devices, shape, causal, blocks):
     one = NamedSharding(Mesh(np.array(v5e_devices[:1]), ("x",)), P())
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    x = jax.ShapeDtypeStruct(shape[:4], jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct(shape[:3] + shape[-1:], jnp.bfloat16,
+                             sharding=one)
 
     def loss(q, k, v):
         out = fa.flash_attention(q, k, v, causal, *blocks, False)
         return out.astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        x, x, x).compile()
+        x, x, v).compile()
     assert _mosaic_calls(compiled) == 3  # forward, dq, dk/dv
 
 
