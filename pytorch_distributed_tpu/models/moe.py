@@ -297,22 +297,62 @@ class _ExpertStack(nn.Module):
                                     ("down_proj", (self.width, d_model)))}
 
 
-class RoutedExperts(nn.Module):
-    """An expert layer that is told which experts it holds.
+class _RouterMLP(nn.Module):
+    """A router that is an MLP with a state, all of it float32 (products at
+    the highest precision: a score's rounding moves a token to another
+    expert).  ``r = W_d x``; from the second expert layer on
+    ``r += gamma * r_before``, the state of the router before it (``gamma``
+    learned, 0 at the start); scores are ``softmax(W_3 gelu(W_2 gelu(W_1
+    norm(r) + b_1) + b_2))``.  Returns the scores [S, E] and ``r``, the
+    state for the next router."""
 
-    Scores are sigmoids over all ``n_routed`` experts in float32; the
-    ``top_k`` are chosen by score plus a selection bias that is state (the
-    ``router`` collection), not a parameter; gates are the chosen scores,
-    normalised and scaled.  The sum runs over those of the chosen whose
-    expert is one of ``held = (first, count)``; the shared expert (one
-    SwiGLU of ``n_shared`` times the width) is computed in full.  No
-    capacity and no dropped token: see ``grouped_swiglu``.
+    n_routed: int
+    hidden: int
+    eps: float
+
+    @nn.compact
+    def __call__(self, x, before):
+        from pytorch_distributed_tpu.models.decoder import RMSNorm
+
+        def dense(features, name, use_bias):
+            return nn.Dense(features, use_bias=use_bias, dtype=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST, name=name)
+
+        r = dense(self.hidden, "down_proj", False)(x.astype(jnp.float32))
+        if before is not None:
+            r = r + self.param("gamma", nn.initializers.zeros,
+                               (self.hidden,), jnp.float32) * before
+        h = RMSNorm(self.eps, name="norm")(r)
+        for name in ("fc1", "fc2"):
+            h = nn.gelu(dense(self.hidden, name, True)(h), approximate=False)
+        return jax.nn.softmax(dense(self.n_routed, "out_proj", False)(h)), r
+
+
+class RoutedExperts(nn.Module):
+    """An expert layer that is told which experts it holds, and which of
+    two scorers it was given.
+
+    ``router_hidden`` = 0: scores are sigmoids of one linear map over all
+    ``n_routed`` experts, in float32.  ``router_hidden`` > 0: scores are
+    the softmax of a ``_RouterMLP`` of that width, which takes the state of
+    the router before it (the call's second argument) and hands on its own
+    (the call then returns the rows and that state).  Either
+    way the ``top_k`` are chosen by score plus a selection bias that is
+    state (the ``router`` collection), not a parameter; gates are the chosen
+    scores, normalised if ``norm_topk_prob`` (never with one expert a token:
+    the gate would be 1 and the router would learn nothing) and scaled.
+    The sum runs over those of the chosen whose expert is one of ``held =
+    (first, count)``; the shared expert (one SwiGLU of ``n_shared`` times
+    the width, if any) is computed in full.  No capacity and no dropped
+    token: see ``grouped_swiglu``.
 
     Sows the sequence-wise balance loss into ``losses`` and, into
     ``counters``, the step's counts of tokens by expert (all ``n_routed``:
     the bias update reads them), ``routed_here`` (pairs on held experts),
     ``rows_grouped`` (rows the grouped products processed) and
-    ``rows_max`` (the fullest held expert's rows)."""
+    ``rows_max`` (the fullest held expert's rows); with the MLP scorer also
+    ``gate_mean`` (the mean gate) and ``router_entropy`` (the mean entropy
+    of a token's scores)."""
 
     n_routed: int
     top_k: int
@@ -322,10 +362,12 @@ class RoutedExperts(nn.Module):
     scaling: float = 1.0
     norm_topk_prob: bool = True
     seq_aux_alpha: float = 0.0
+    router_hidden: int = 0
+    eps: float = 1e-5
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_state=None):
         from pytorch_distributed_tpu.obs.trace import scope
 
         B, L, C = x.shape
@@ -335,9 +377,15 @@ class RoutedExperts(nn.Module):
         with scope("moe_route"):
             bias = self.variable("router", "e_score_correction_bias",
                                  jnp.zeros, (E,), jnp.float32)
-            scores = jax.nn.sigmoid(nn.Dense(
-                E, use_bias=False, dtype=jnp.float32, name="router")(
-                    tokens.astype(jnp.float32)))                  # [S, E]
+            if self.router_hidden:
+                with scope("router_mlp"):
+                    scores, router_state = _RouterMLP(
+                        E, self.router_hidden, self.eps, name="router")(
+                            tokens, router_state)
+            else:
+                scores = jax.nn.sigmoid(nn.Dense(
+                    E, use_bias=False, dtype=jnp.float32, name="router")(
+                        tokens.astype(jnp.float32)))              # [S, E]
             _, idx = jax.lax.top_k(scores + bias.value, K)        # [S, K]
             gates = jnp.take_along_axis(scores, idx, -1)
             if self.norm_topk_prob:
@@ -368,9 +416,14 @@ class RoutedExperts(nn.Module):
                             ("rows_grouped", rows),
                             ("rows_max", sizes.max())):
             self.sow("counters", name, value)
+        if self.router_hidden:
+            self.sow("counters", "gate_mean", gates.mean())
+            self.sow("counters", "router_entropy", -jnp.mean(jnp.sum(
+                scores * jnp.log(scores + 1e-30), -1)))
         out = routed
         if self.n_shared:
             with scope("moe_shared"):
                 out = out + _SwiGLU(self.n_shared * self.width, self.dtype,
                                     name="shared")(tokens)
-        return out.reshape(B, L, C).astype(x.dtype)
+        out = out.reshape(B, L, C).astype(x.dtype)
+        return (out, router_state) if self.router_hidden else out

@@ -405,17 +405,21 @@ def lm_step_cost(vocab_size: int, d_model: int, n_layers: int, batch: int,
 def decoder_step_cost(config: Any, batch: int, seq_len: int,
                       fused_ce: bool = False) -> StepCost:
     """Analytic train-step cost for a configured decoder
-    (``models/decoder.DecoderConfig``): latent or plain heads, dense or
-    expert feed-forward, and a stack that runs ``total_ut_steps`` times
+    (``models/decoder.DecoderConfig``): latent, compressed-latent or plain
+    heads, dense or expert feed-forward (a linear or an MLP router), an
+    untied or a tied head, and a stack that runs ``total_ut_steps`` times
     with a head after every pass.  It counts applications, not parameters:
     a looped model pays ``passes x layers`` blocks and ``passes`` heads a
     token while its optimizer runs over one set of weights.
 
     The yardstick's conventions (``benchmark/flops_ouro.py``,
-    ``flops_kimi_vl_a3b.py``, held together by tests): causal attention is
+    ``flops_kimi_vl_a3b.py``, ``flops_zaya1.py``, held together by tests):
+    causal attention is
     half the square, the routed experts are counted at the uniform
     expectation (``top_k * held / routed`` a token), every position's row
-    meets the head, the exit gate's ``d`` a row is left out.  ``remat`` and
+    meets the head, the exit gate's ``d`` a row and the depthwise
+    convolution's few multiply-adds a channel are left out, a tied head is
+    one product forward and one matrix of parameters.  ``remat`` and
     the fused loss add their recomputed forward to the hardware count
     only."""
     c = config
@@ -426,26 +430,44 @@ def decoder_step_cost(config: Any, batch: int, seq_len: int,
                 + c.kv_lora_rank * heads * (c.qk_nope_head_dim + vd)
                 + heads * vd * d)
         attn_params = proj + c.kv_lora_rank
+    elif c.cca_time0:
+        qk = vd = c.head_dim
+        kv_heads = c.num_key_value_heads or heads
+        stacked = (heads + kv_heads) * qk
+        # W_q, W_k, the values' two halves, W_o, and a matrix a head and
+        # a tap of the second convolution
+        proj = (2 * d * heads * qk + 2 * d * kv_heads * qk
+                + c.cca_time1 * stacked * qk)
+        attn_params = (proj + (c.cca_time0 + 2) * stacked  # taps, biases
+                       + kv_heads)                          # temperatures
     else:
         qk = vd = c.head_dim or d // heads
         proj = attn_params = 4 * d * heads * qk
-    norms = (4 if c.sandwich_norm else 2) * d
+    # a scaled join has four vectors where a plain one has none
+    norms = (4 if c.sandwich_norm else 10 if c.cca_time0 else 2) * d
     dense = 3 * d * c.intermediate_size
     held = c.experts_held[1]
     expert = 3 * d * c.moe_intermediate_size
-    expert_flops = (expert * c.n_shared_experts + d * c.n_routed_experts
+    r = c.router_hidden_size
+    router = (d * r + 2 * r * r + r * c.n_routed_experts if r
+              else d * c.n_routed_experts)
+    expert_flops = (expert * c.n_shared_experts + router
                     + (expert * c.num_experts_per_tok * held
                        / c.n_routed_experts if held else 0.0))
-    expert_params = (expert * (c.n_shared_experts + held)
-                     + d * c.n_routed_experts)
+    # an MLP router's norm and two biases; its gamma from the second on
+    expert_params = (expert * (c.n_shared_experts + held) + router
+                     + (3 * r if r else 0))
     passes, lead = c.total_ut_steps, c.first_k_dense_replace
     wk = _Walk()
-    wk.params += 2 * c.vocab_size * d + d           # embedding, head, norm_f
+    # embedding, head (the embedding again if tied), norm_f
+    wk.params += (1 if c.tie_word_embeddings else 2) * c.vocab_size * d + d
     if passes > 1:
         wk.params += d + 1                          # the exit gate
     for i in range(c.num_hidden_layers):
         wk.params += attn_params + norms + (dense if i < lead
                                             else expert_params)
+        if r and i > lead:
+            wk.params += r
         ffn = dense if i < lead else expert_flops
         wk.fwd += passes * seq * (2.0 * (proj + ffn)
                                   + 2.0 * heads * (qk + vd) * seq / 2)

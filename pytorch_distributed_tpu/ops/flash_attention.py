@@ -25,7 +25,11 @@ the kernels are tested against).
 Layout: [B, L, H, D] like parallel/ring.py; blocks default to 256 × 1024
 (q × kv).  ``q`` and ``k`` share one head size and ``v`` may have
 another (latent attention: 192 | 128); ``scale`` defaults to
-``D_qk ** -0.5``.  The kernels' matrix products take their operands in the
+``D_qk ** -0.5``.  ``k`` and ``v`` may have fewer heads than ``q``
+([B, L, G, D], G dividing H: grouped-query attention): query head ``h``
+reads key-value head ``h // (H / G)`` through the blocks' index maps, so no
+copy of K or V is made; the dk/dv pass runs a query head at a time and its
+float32 results are summed over each group outside the kernel.  The kernels' matrix products take their operands in the
 type of ``q``, ``k`` and ``v`` (bf16 in, bf16 on the MXU); accumulation and
 the softmax are float32.
 """
@@ -207,13 +211,33 @@ def _scheduled_call(kernel, sched: BlockSchedule, batch_heads: int,
     )
 
 
-def _spec(side: str, rows: int, width: int) -> pl.BlockSpec:
+def _spec(side: str, rows: int, width: int, group: int = 1) -> pl.BlockSpec:
     """A ``[1, rows, width]`` block of a ``[B*H, L, width]`` array: the
-    step's q-block (``side="q"``) or its kv-block."""
+    step's q-block (``side="q"``) or its kv-block.  ``group`` > 1: the
+    array has ``B*H / group`` rows, one for every ``group`` batch-heads of
+    the grid (a key-value head shared by a group of query heads)."""
     table = BlockSchedule._fields.index(f"{side}_block")
-    return pl.BlockSpec((1, rows, width),
-                        lambda b, s, *tables: (b, tables[table][s], 0),
-                        memory_space=pltpu.VMEM)
+    if group == 1:
+        def index(b, s, *tables):
+            return b, tables[table][s], 0
+    else:
+        def index(b, s, *tables):
+            return b // group, tables[table][s], 0
+    return pl.BlockSpec((1, rows, width), index, memory_space=pltpu.VMEM)
+
+
+def _heads_first(x):
+    """[B, L, H, D] -> [B*H, L, D]."""
+    B, L, H, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B * H, L, D)
+
+
+def _group(q, k) -> int:
+    """Query heads a key-value head: ``H / G``."""
+    H, G = q.shape[2], k.shape[2]
+    if H % G:
+        raise ValueError(f"{G} key-value heads do not divide {H} query heads")
+    return H // G
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
@@ -223,18 +247,16 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     bq, bk = _blocks(L, block_q, block_k)
-    # [B, L, H, D] -> [B*H, L, D]
-    qr = q.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    kr = k.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
+    group = _group(q, k)
+    qr, kr, vr = _heads_first(q), _heads_first(k), _heads_first(v)
 
     sched = block_schedule(L, bq, bk, causal, "q")
     out, lse = _scheduled_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
         sched, B * H,
-        in_specs=[_spec("q", bq, D), _spec("kv", bk, D),
-                  _spec("kv", bk, Dv)],
+        in_specs=[_spec("q", bq, D), _spec("kv", bk, D, group),
+                  _spec("kv", bk, Dv, group)],
         out_specs=[_spec("q", bq, Dv), _spec("q", bq, 128)],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, L, Dv), q.dtype),
@@ -344,17 +366,15 @@ def _bwd_pallas(res, g, causal: bool, block_q: int, block_k: int,
         scale = 1.0 / (D ** 0.5)
     bq, bk = _blocks(L, block_q, block_k)
     f32 = jnp.float32
-    qr = q.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    kr = k.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
-    gr = g.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
-    of = out.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
+    group = _group(q, k)
+    qr, kr, vr, gr, of = (_heads_first(x) for x in (q, k, v, g, out))
     delta = jnp.sum(of.astype(f32) * gr.astype(f32), axis=-1)     # [BH, L]
     # Lane-broadcast for block slicing (transient, not a saved residual).
     lse128 = jnp.broadcast_to(lse[:, :, None], (B * H, L, 128))
     dlt128 = jnp.broadcast_to(delta[:, :, None], (B * H, L, 128))
     operands = (qr, kr, vr, gr, lse128, dlt128)
-    in_specs = [_spec("q", bq, D), _spec("kv", bk, D), _spec("kv", bk, Dv),
+    in_specs = [_spec("q", bq, D), _spec("kv", bk, D, group),
+                _spec("kv", bk, Dv, group),
                 _spec("q", bq, Dv), _spec("q", bq, 128), _spec("q", bq, 128)]
     static = dict(scale=scale, causal=causal, block_q=bq, block_k=bk)
 
@@ -368,13 +388,18 @@ def _bwd_pallas(res, g, causal: bool, block_q: int, block_k: int,
         interpret=interpret,
     )(*sched, *operands)[0]
 
+    # a group's query heads each give their part of dk and dv, in float32
+    # where there are several to sum
     sched = block_schedule(L, bq, bk, causal, "kv")
     dk, dv = _scheduled_call(
         functools.partial(_bwd_dkv_kernel, **static), sched, B * H,
         in_specs=in_specs,
         out_specs=[_spec("kv", bk, D), _spec("kv", bk, Dv)],
-        out_shape=[jax.ShapeDtypeStruct((B * H, L, D), k.dtype),
-                   jax.ShapeDtypeStruct((B * H, L, Dv), v.dtype)],
+        out_shape=[
+            jax.ShapeDtypeStruct((B * H, L, D),
+                                 k.dtype if group == 1 else f32),
+            jax.ShapeDtypeStruct((B * H, L, Dv),
+                                 v.dtype if group == 1 else f32)],
         scratch_shapes=[pltpu.VMEM((bk, D), f32),
                         pltpu.VMEM((bk, Dv), f32)],
         interpret=interpret,
@@ -383,7 +408,13 @@ def _bwd_pallas(res, g, causal: bool, block_q: int, block_k: int,
     def back(x):
         return x.reshape(B, H, L, x.shape[-1]).transpose(0, 2, 1, 3)
 
-    return back(dq), back(dk), back(dv)
+    def back_grouped(x, like):
+        if group == 1:
+            return back(x)
+        x = x.reshape(B, H // group, group, L, x.shape[-1]).sum(2)
+        return x.transpose(0, 2, 1, 3).astype(like.dtype)
+
+    return back(dq), back_grouped(dk, k), back_grouped(dv, v)
 
 
 def _bwd_blockwise(res, g, causal: bool, block_k: int,
@@ -392,6 +423,8 @@ def _bwd_blockwise(res, g, causal: bool, block_k: int,
     (Plain-XLA reference path, selected via ``bwd_impl="xla"`` — the
     semantics oracle the Pallas backward kernels are tested against.)"""
     q, k, v, out, lse = res  # q,k,v,out: [B,L,H,D]; lse: [B*H, L]
+    if _group(q, k) != 1:
+        raise ValueError("bwd_impl='xla' has no grouped key-value heads")
     B, L, H, D = q.shape
     Dv = v.shape[-1]
     if scale is None:
@@ -448,8 +481,9 @@ def flash_attention(
     bwd_impl: str = "pallas",
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Fused attention over ``q, k`` [B, L, H, D] and ``v`` [B, L, H, Dv].
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU (slow,
+    """Fused attention over ``q`` [B, L, H, D], ``k`` [B, L, G, D] and
+    ``v`` [B, L, G, Dv], G dividing H (G = H: one key-value head a query
+    head).  ``interpret=None`` auto-selects the Pallas interpreter off-TPU (slow,
     exact) and compiled mode on TPU.  ``bwd_impl``: "pallas" = fused
     dq/dk/dv kernels (default); "xla" = the blockwise-recompute reference
     path.  ``scale`` multiplies the scores (default ``D ** -0.5``)."""
@@ -487,7 +521,9 @@ def flash_attention_on_mesh(q, k, v, causal: bool,
         fits = name in mesh.axis_names and dim % mesh.shape[name] == 0
         return name if fits else None
 
-    spec = P(axis("data", q.shape[0]), None, axis("model", q.shape[2]), None)
+    # the key-value heads: they divide the query heads, so an axis that
+    # divides them divides both
+    spec = P(axis("data", q.shape[0]), None, axis("model", k.shape[2]), None)
     return jax.shard_map(
         lambda q, k, v: flash_attention(q, k, v, causal, **kernel_kw),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -430,7 +430,7 @@ def test_the_trainers_cost_counts_applications_as_the_benchmarks_file():
 @pytest.mark.parametrize("key,value", [
     ("num_key_value_heads", 2), ("use_sliding_window", True),
     ("sliding_window", 4096), ("attention_bias", True),
-    ("tie_word_embeddings", True), ("norm_placement", "post"),
+    ("lm_head_bias", True), ("norm_placement", "post"),
     ("layer_types", ["full_attention", "sliding_attention",
                      "full_attention"]),
     # the scan over the passes carries no routing state

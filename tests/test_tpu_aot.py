@@ -174,3 +174,79 @@ def test_vit_step_with_fused_attention_compiles_on_four_chips(
     # no operation over the scores, whole ([32, 12, 197, 197]) or a
     # chip's share of them
     assert ",12,197,197]" not in text
+
+
+# --- grouped key-value heads, and the tied head's one gradient buffer ---
+
+def test_grouped_flash_fwd_bwd_compiles_for_v5e(v5e_devices):
+    """The compressed-latent decoder's call (models/decoder.py CCA): 8
+    query heads over 2 key-value heads of 128 at 8,192 positions.  The
+    same three kernels; K, V and their gradients keep 2 heads."""
+    one = NamedSharding(Mesh(np.array(v5e_devices[:1]), ("x",)), P())
+    q = jax.ShapeDtypeStruct((2, 8192, 8, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((2, 8192, 2, 128), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, True, 1024, 1024, False)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    assert _mosaic_calls(compiled) == 3  # forward, dq, dk/dv
+    out = jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert [x.shape for x in out] == [q.shape, kv.shape, kv.shape]
+
+
+def test_tied_step_sums_the_embeddings_gradient_into_one_buffer(
+        v5e_devices):
+    """The tied embedding's gradient has two parts: the head's, which the
+    fused loss accumulates in a float32 [V, d] buffer, and the lookup's, a
+    scatter-add of the first block's input gradient.  Compiled for the
+    chip, the scatter-add lands in the head's buffer: the step's memory
+    ledger (``obs/memory.py``) holds no [V, d] array of zeros for it to
+    land in (at the cell's size that is 1.07 GB the step does not take:
+    PERF.md 6, PR 32), and the sum is float32."""
+    import warnings
+
+    from pytorch_distributed_tpu.models.decoder import (
+        DecoderConfig,
+        DecoderLM,
+    )
+    from pytorch_distributed_tpu.obs.memory import ledger_from_compiled
+    from pytorch_distributed_tpu.parallel.tp import replicated_like
+    from pytorch_distributed_tpu.train.lm import make_lm_train_step
+    from pytorch_distributed_tpu.train.optim import adamw
+    from pytorch_distributed_tpu.train.state import TrainState
+    from test_zaya1 import PRESET
+
+    vocab, d = 8192, 256    # no other array of this size in the step
+    cfg = {**PRESET, "vocab_size": vocab, "hidden_size": d}
+    mesh = Mesh(np.array(v5e_devices[:1]), ("data",))
+    model = DecoderLM(DecoderConfig.from_dict(cfg), dtype=jnp.bfloat16)
+    tokens = jnp.zeros((2, 128), jnp.int32)
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    tx = adamw({"lr": 1e-3, "b1": 0.9, "b2": 0.95, "eps": 1e-8,
+                "weight_decay": 0.1})
+    state = jax.eval_shape(lambda v: TrainState.create(
+        {"params": v["params"], "batch_stats": v["router"]},
+        tx.init(v["params"])), variables)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        step = make_lm_train_step(
+            model, mesh, replicated_like(state.params), tx=tx,
+            params=state.params, fused_ce_chunks=2)
+    one = NamedSharding(mesh, P())
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), state)
+    compiled = step.lower(
+        state, jax.ShapeDtypeStruct(tokens.shape, jnp.int32,
+                                    sharding=NamedSharding(
+                                        mesh, P("data", None))),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=one)).compile()
+    ledger = ledger_from_compiled(compiled)
+    temps = [b for b in ledger.buffers
+             if b.defined_at >= 0 and b.dims == [vocab, d]]
+    scatter = [b for b in temps if "scatter-add" in b.op_name]
+    assert len(scatter) == 1 and scatter[0].dtype == "f32", temps
+    assert not any("broadcast" in b.op_name for b in temps), [
+        (b.name, b.op_name) for b in temps]
