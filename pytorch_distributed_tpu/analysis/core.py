@@ -576,7 +576,7 @@ def _recipe_eval_image():
     return step, (state, _image_batch()), (), mesh
 
 
-def _lm_setup(mesh, specs=None, **step_kw):
+def _lm_setup(mesh, specs=None, vocab=None, **step_kw):
     import jax
     import jax.numpy as jnp
 
@@ -587,7 +587,7 @@ def _lm_setup(mesh, specs=None, **step_kw):
     from pytorch_distributed_tpu.train.state import TrainState
 
     model = TransformerLM(
-        vocab_size=_LM["vocab"], d_model=_LM["d_model"],
+        vocab_size=vocab or _LM["vocab"], d_model=_LM["d_model"],
         n_heads=_LM["n_heads"], n_layers=1)
     tokens = jnp.zeros((_LM["batch"], _LM["seq"]), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), tokens)["params"]
@@ -602,13 +602,17 @@ def _lm_setup(mesh, specs=None, **step_kw):
     return model, specs, state, tokens, step
 
 
-def _recipe_lm_train(fused_ce_mode: Optional[str]):
+def _recipe_lm_train(fused_ce_mode: Optional[str],
+                     vocab: Optional[int] = None):
+    """The GSPMD DP LM step, unfused or on the fused loss.  ``vocab``:
+    another vocabulary than the sweep's (tests/test_memory.py ranks the
+    loss variants' peaks at one whose logits outweigh the hidden rows)."""
     import jax.numpy as jnp
 
     mesh = _mesh(("data",), (4,))
     kw = {} if fused_ce_mode is None else dict(
         fused_ce_chunks=2, fused_ce_mode=fused_ce_mode)
-    _, _, state, tokens, step = _lm_setup(mesh, **kw)
+    _, _, state, tokens, step = _lm_setup(mesh, vocab=vocab, **kw)
     return step, (state, tokens, jnp.float32(0.1)), (0,), mesh
 
 

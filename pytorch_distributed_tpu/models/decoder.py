@@ -630,7 +630,19 @@ class DecoderLM(nn.Module):
             self.sow("counters", "exit_p", jnp.mean(p, (1, 2)))
             self.sow("counters", "exit_entropy", jnp.mean(entropy))
         if return_hidden:
-            return exits if c.total_ut_steps > 1 else last
+            # The rows the fused loss reads are written once: left to
+            # itself the compiler folds the final norm into the loss's
+            # input a second time, from the layers' float32 streams, and
+            # keeps those alive across the loss's loop, beside the head's
+            # gradient accumulator that loop holds.  That is the depth-first
+            # schedule's doing, which a program differentiating through
+            # the loss gets from the compiler's default (0.5 GB of the
+            # ZAYA1 cell's comparison program); the train step, on the
+            # ``list`` schedule, compiles to the same bytes with and
+            # without the barrier, so it goes once nothing compiles the
+            # loss's gradient under the default (PERF.md 6 and 7, PR 33).
+            return jax.lax.optimization_barrier(
+                exits if c.total_ut_steps > 1 else last)
         with scope("lm_head"):
             return jnp.einsum("bld,vd->blv", last.astype(self.dtype),
                               head.astype(self.dtype),

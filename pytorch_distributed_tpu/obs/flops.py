@@ -8,10 +8,11 @@ bytes estimate — and divides achieved FLOP/s by the chip's peak:
 
 - **MFU** uses *model* FLOPs: the algorithmically necessary work (the
   PaLM-appendix convention).  Recompute taxes do not inflate it.
-- **HFU** uses *hardware* FLOPs: model FLOPs plus rematerialization /
-  fused-CE chunk-recompute work the chips actually execute.  HFU ≥ MFU;
-  the gap IS the recompute tax (e.g. ViT ``remat=True`` trades ~1/3 extra
-  matmuls for activation residency — models/vit.py).
+- **HFU** uses *hardware* FLOPs: model FLOPs plus the rematerialization
+  work the chips actually execute.  HFU ≥ MFU; the gap IS the recompute
+  tax (e.g. ViT ``remat=True`` trades ~1/3 extra matmuls for activation
+  residency — models/vit.py).  The fused loss (ops/fused_ce.py) adds
+  none: it takes its gradient in the pass that has the logits.
 
 Counting conventions (chosen to match XLA's ``cost_analysis()`` so the
 analytic model can be cross-checked, tests/test_efficiency.py):
@@ -171,7 +172,7 @@ class StepCost:
     """Per-optimizer-step cost of one registered model family config.
 
     ``model_flops``    algorithmic FLOPs (MFU numerator);
-    ``hardware_flops`` incl. remat / fused-CE recompute (HFU numerator);
+    ``hardware_flops`` incl. remat recompute (HFU numerator);
     ``bytes``          rough HBM traffic (params+grads+optimizer r/w and
                        activations twice) — an arithmetic-intensity hint,
                        not cross-checked;
@@ -359,10 +360,11 @@ def lm_step_cost(vocab_size: int, d_model: int, n_layers: int, batch: int,
                  moe_top_k: int = 1) -> StepCost:
     """Analytic train-step cost for the transformer-LM family.
 
-    ``fused_ce``: the chunked tied-head+CE backward (ops/fused_ce.py)
-    recomputes each chunk's logits block instead of stashing the [T, V]
-    tensor — +2·T·D·V hardware FLOPs, identical model FLOPs; the
-    replicated/dp/tp sharding variants all do the same global arithmetic.
+    ``fused_ce``: the chunked tied-head+CE (ops/fused_ce.py) projects the
+    ``seq_len - 1`` loss rows only, and runs the model's three head
+    products a chunk (logits, dh, dE) in one loop: no hardware FLOPs
+    beyond the model's; the replicated/dp/tp sharding variants all do the
+    same global arithmetic.
     ``remat``: block rematerialization (+1x block-stack forward, hardware
     only).  The pipeline schedules (gpipe/1f1b/interleaved) run the same
     math as the plain stack, so no schedule parameter: FLOPs don't change,
@@ -394,12 +396,7 @@ def lm_step_cost(vocab_size: int, d_model: int, n_layers: int, batch: int,
     # path projects only the seq_len-1 loss rows.
     head_rows = (seq_len - 1) if fused_ce else seq_len
     wk.dense(head_rows, d, vocab_size, params=False)
-    recompute = 0.0
-    if remat:
-        recompute += block_fwd
-    if fused_ce:
-        recompute += 2.0 * (seq_len - 1) * d * vocab_size
-    return _finish(wk, batch, recompute_fwd=recompute)
+    return _finish(wk, batch, recompute_fwd=block_fwd if remat else 0.0)
 
 
 def decoder_step_cost(config: Any, batch: int, seq_len: int,
@@ -419,9 +416,10 @@ def decoder_step_cost(config: Any, batch: int, seq_len: int,
     expectation (``top_k * held / routed`` a token), every position's row
     meets the head, the exit gate's ``d`` a row and the depthwise
     convolution's few multiply-adds a channel are left out, a tied head is
-    one product forward and one matrix of parameters.  ``remat`` and
-    the fused loss add their recomputed forward to the hardware count
-    only."""
+    one product forward and one matrix of parameters.  ``remat`` adds the
+    blocks' recomputed forward to the hardware count only; ``fused_ce``
+    moves no count (the fused loss recomputes nothing, and every
+    position's row is counted either way)."""
     c = config
     d, heads, seq = c.hidden_size, c.num_attention_heads, seq_len
     if c.kv_lora_rank:
@@ -473,10 +471,8 @@ def decoder_step_cost(config: Any, batch: int, seq_len: int,
                                   + 2.0 * heads * (qk + vd) * seq / 2)
         wk.act_elts += passes * seq * d
     blocks = wk.fwd
-    head = passes * 2.0 * seq * d * c.vocab_size
-    wk.fwd += head
-    return _finish(wk, batch, recompute_fwd=(
-        (blocks if c.remat else 0.0) + (head if fused_ce else 0.0)))
+    wk.fwd += passes * 2.0 * seq * d * c.vocab_size   # the heads
+    return _finish(wk, batch, recompute_fwd=blocks if c.remat else 0.0)
 
 
 def lm_step_cost_for(model: Any, batch: int, seq_len: int,

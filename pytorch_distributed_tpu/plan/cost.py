@@ -67,16 +67,20 @@ def hw_for(chip: Optional[str] = None) -> HW:
 
 
 def step_cost_for(plan: Plan) -> flops.StepCost:
-    """The fenced per-step FLOPs model at the plan's recompute knobs."""
+    """The fenced per-step FLOPs model at the plan's recompute knob
+    (``remat``).  The fused loss is none: it runs the head's three
+    products like the unfused one (ops/fused_ce.py), so a plan's time does
+    not read ``fused_ce_mode`` and fused plans tie with their plain twins,
+    fewest knobs first; the mode moves memory (``mem_cost_for``).  The loss
+    row a sequence that the fused head skips is left out of the tie: 1 /
+    seq of the head is below what this model resolves."""
     spec = plan.spec
     if spec.family == "image":
         return flops.image_step_cost(spec.arch, spec.batch, spec.image_size,
                                      spec.num_classes, remat=plan.remat)
     return flops.lm_step_cost(spec.vocab, spec.d_model, spec.n_layers,
                               spec.batch, spec.seq,
-                              mlp_ratio=spec.mlp_ratio,
-                              fused_ce=plan.fused_ce_mode != "none",
-                              remat=plan.remat)
+                              mlp_ratio=spec.mlp_ratio, remat=plan.remat)
 
 
 def bucketed_overlap(grad_bytes: float, bucket_mb: float = 4.0,

@@ -241,6 +241,25 @@ def resolve_fused_ce_mode(
     return mode, (vocab_axis if mode == "tp" else None)
 
 
+def step_compiler_options(mesh: Mesh) -> Optional[Dict[str, Any]]:
+    """Options the TPU compiler gets with the train step: its ``list``
+    memory scheduler, whatever its estimates say.
+
+    Left to its default the compiler schedules the step three ways (list,
+    depth first, post order) and keeps the one whose *estimated* peak is
+    smallest, an estimate taken before its later passes.  Depth first
+    applies every optimizer update after the whole backward pass, all
+    gradients live at once, and is estimated a quarter of a GiB under
+    ``list`` (which applies a leaf's update as its gradient arrives) for
+    the two routed cells' steps, where it then compiles to 2.4 and 3.8 GB
+    more (PERF.md 6, PR 33).  A mesh of CPU devices gets no option: its
+    compiler has none of that name.  ``tests/test_tpu_aot.py`` compiles a
+    tied step for a described v5e both ways and holds the pin to the gain."""
+    if mesh.devices.flat[0].platform != "tpu":
+        return None
+    return {"xla_memory_scheduler": "list"}
+
+
 def make_lm_train_step(
     model,
     mesh: Mesh,
@@ -328,7 +347,10 @@ def make_lm_train_step(
     selection bias) has that state read from ``state.batch_stats``, updated
     after the gradients by its own ``update_state`` from the counters the
     forward pass sowed (no gradient, no optimizer).  A model with
-    ``counter_names`` has its ``step_counters`` added to the metrics.
+    ``counter_names`` has its ``step_counters`` added to the metrics, and
+    a step on the fused loss ``loss_head_products``: the head products a
+    chunk of the differentiated loss runs (ops/fused_ce.py
+    ``GRAD_HEAD_PRODUCTS``), a constant of the compiled step.
 
     A model with ``n_exits`` > 1 (models/decoder.py: a looped decoder)
     returns every exit's hidden rows ``[T, B, L, d]`` and sows a float32
@@ -472,8 +494,14 @@ def make_lm_train_step(
                 # accumulates once
                 h = hidden[..., :-1, :].reshape(-1, d).astype(cdt)
                 t = toks[:, 1:].reshape(-1)
-                w = jnp.ones(t.shape, jnp.float32)
+                # The rows' weights hold the mean's 1 / ntok, so the sums
+                # that come back are the means and the loss's cotangent is
+                # exactly 1: the fused loss took its gradients in the pass
+                # that had the logits and only scales them by that
+                # cotangent, and a scaling by 1 folds away (no second
+                # rounding of the bf16 dh, no scaled copy of dE).
                 ntok = t.shape[0]
+                w = jnp.full(t.shape, 1.0 / ntok, jnp.float32)
                 if n_exits > 1:
                     # a row's weight is the model's exit distribution, and
                     # the loss's cotangent on it is what teaches the gate.
@@ -481,25 +509,24 @@ def make_lm_train_step(
                     # exit's mean cross-entropy
                     t = jnp.tile(t, n_exits)
                     with jax.named_scope("exit_loss"):
-                        w = (sown["exits"]["weight"][0][..., :-1]
-                             + probe[:, None, None]).reshape(-1)
+                        w = ((sown["exits"]["weight"][0][..., :-1]
+                              + probe[:, None, None]) / ntok).reshape(-1)
                 e = head_matrix(model, params).astype(cdt)
                 if ce_mode == "tp":
-                    loss_sum, correct = fused_ce_sums_tp(
+                    loss, acc = fused_ce_sums_tp(
                         h, e, t, w, fused_ce_chunks, mesh,
                         data_axis=data_axis, model_axis=ce_model_axis)
                 elif ce_mode == "dp":
-                    loss_sum, correct = fused_ce_sums_dp(
+                    loss, acc = fused_ce_sums_dp(
                         h, e, t, w, fused_ce_chunks, mesh,
                         data_axis=data_axis)
                 else:
-                    loss_sum, correct = fused_ce_sums(
+                    loss, acc = fused_ce_sums(
                         h, e, t, w, fused_ce_chunks)
-                loss = loss_sum / ntok
                 for leaf in jax.tree_util.tree_leaves(
                         sown.get("losses", {})):
                     loss = loss + leaf
-                return loss, (correct / ntok, sown.get("counters", {}))
+                return loss, (acc, sown.get("counters", {}))
             # mutable=["losses"] collects sown auxiliary objectives (the MoE
             # router's load-balancing loss); {} for dense models.
             logits, sown = model.apply(
@@ -608,6 +635,14 @@ def make_lm_train_step(
             new_model_state = model.update_state(state.batch_stats, seen)
         if counted:
             metrics.update(model.step_counters(new_model_state, seen))
+        if fused_ce_chunks:
+            from pytorch_distributed_tpu.ops.fused_ce import (
+                GRAD_HEAD_PRODUCTS,
+            )
+
+            # the head products a chunk of the differentiated loss runs: a
+            # constant of the compiled step, like ``attn_blocks_visited``
+            metrics["loss_head_products"] = jnp.int32(GRAD_HEAD_PRODUCTS)
         if guard_nonfinite:
             bad = nonfinite_flag(loss, gnorm)
             new_params = gate_update(bad, state.params, new_params)
@@ -638,6 +673,7 @@ def make_lm_train_step(
                       NamedSharding(mesh, P())),
         out_shardings=(state_shardings, NamedSharding(mesh, P())),
         donate_argnums=(0,),
+        compiler_options=step_compiler_options(mesh),
     )
 
 
@@ -1690,11 +1726,15 @@ class LMTrainer:
                         self.state, metrics = self.step_fn(
                             self.state, tokens, lr)
                         # a model's own counters (``counter_names``: a
-                        # configured decoder's routing) ride on the record
-                        # as unready device scalars: whoever reads the
-                        # record converts them, the loop does not
+                        # configured decoder's routing) and the fused
+                        # loss's ride on the record as unready device
+                        # scalars: whoever reads the record converts them,
+                        # the loop does not
                         booked.set(**{k: metrics[k] for k in getattr(
                             self.model, "counter_names", ())})
+                        if "loss_head_products" in metrics:
+                            booked.set(loss_head_products=metrics[
+                                "loss_head_products"])
                         if sa is not None:
                             # The step's blocking transfer: without it, async
                             # dispatch smears step N's device time into N+1's

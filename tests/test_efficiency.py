@@ -95,9 +95,10 @@ def test_lm_flops_parity_vs_cost_analysis():
 
 
 def test_step_cost_taxes_and_reporter():
-    """Remat and fused-CE recompute inflate hardware FLOPs only (HFU < MFU
-    denominator relationship), and the reporter turns seconds into
-    percentages with the expected arithmetic."""
+    """Remat's recompute inflates hardware FLOPs only (HFU < MFU
+    denominator relationship), the fused loss recomputes nothing (its
+    gradient is taken in the pass that has the logits), and the reporter
+    turns seconds into percentages with the expected arithmetic."""
     from pytorch_distributed_tpu.obs.flops import (
         MFUReporter,
         image_step_cost,
@@ -108,7 +109,8 @@ def test_step_cost_taxes_and_reporter():
     fused = lm_step_cost(256, 64, 2, 8, 32, fused_ce=True)
     remat = lm_step_cost(256, 64, 2, 8, 32, remat=True)
     assert plain.hardware_flops == plain.model_flops
-    assert fused.hardware_flops > fused.model_flops
+    assert fused.hardware_flops == fused.model_flops
+    assert fused.breakdown["recompute"] == 0.0
     assert remat.hardware_flops > remat.model_flops
     # fused-CE trims the head to the loss rows: model FLOPs drop slightly
     assert fused.model_flops < plain.model_flops

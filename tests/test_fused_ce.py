@@ -66,6 +66,150 @@ def test_fused_ce_matches_naive(chunks):
     np.testing.assert_allclose(float(cf), float(cn))
 
 
+@pytest.mark.parametrize("scale", [1.0, 1.0 / 7.0],
+                         ids=["cotangent_1", "cotangent_odd"])
+@pytest.mark.parametrize("chunks", [1, 2, 4, 8])
+def test_forward_pass_gradients_match_unfused(chunks, scale):
+    """The gradients the forward rule took beside the logits, scaled by
+    the loss's cotangent in the backward rule: ``dh``, ``dE`` and the
+    weights' cotangent against the unfused ``cross_entropy`` path (its
+    per-row losses, weighted), with uneven weights and a row count (21)
+    that no chunk count here divides."""
+    from pytorch_distributed_tpu.ops.loss import cross_entropy
+
+    h, e, t, w = _op_inputs(seed=21, n=21)
+
+    def unfused(h, e, w):
+        logits = h @ e.T
+        rows = jax.vmap(
+            lambda row, tgt: cross_entropy(row[None], tgt[None]))(logits, t)
+        return jnp.sum(rows * w) * scale
+
+    def fused(h, e, w):
+        return fused_ce_sums(h, e, t, w, chunks)[0] * scale
+
+    want_v, want = jax.value_and_grad(unfused, argnums=(0, 1, 2))(h, e, w)
+    got_v, got = jax.value_and_grad(fused, argnums=(0, 1, 2))(h, e, w)
+    np.testing.assert_allclose(float(got_v), float(want_v), rtol=1e-6)
+    for a, b, name in zip(got, want, ("dh", "dE", "dw")):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6, err_msg=name)
+
+
+def test_bf16_rows_leave_in_their_own_types():
+    """bf16 operands (the LM cells' policy): ``dh`` leaves in the rows'
+    type and ``dE`` in the head's, after a float32 scaling."""
+    h, e, t, w = _op_inputs(seed=5)
+    hb, eb = h.astype(jnp.bfloat16), e.astype(jnp.bfloat16)
+    gh, ge, gw = jax.grad(
+        lambda h, e, w: fused_ce_sums(h, e, t, w, 4)[0] / 7.0,
+        argnums=(0, 1, 2))(hb, eb, w)
+    assert (gh.dtype, ge.dtype, gw.dtype) == (
+        jnp.bfloat16, jnp.bfloat16, jnp.float32)
+    want = jax.grad(
+        lambda h, e, w: _naive_sums(h, e, t, w)[0] / 7.0,
+        argnums=(0, 1, 2))(hb.astype(jnp.float32), eb.astype(jnp.float32), w)
+    for a, b in zip((gh, ge, gw), want):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   rtol=2e-2, atol=2e-3)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (the scan's
+    body, a custom_vjp's call, a shard_map's) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _head_products(jaxpr):
+    return sum(eqn.primitive.name == "dot_general" for eqn in _eqns(jaxpr))
+
+
+def _holds_f32(jaxpr, shape):
+    return any(v.aval.shape == shape and v.aval.dtype == jnp.float32
+               for eqn in _eqns(jaxpr) for v in eqn.outvars)
+
+
+def _variant(name):
+    """``(fn(h, e, t, w, chunks), vocab shard a device's dE carry has)``
+    on the 8 host devices."""
+    from pytorch_distributed_tpu.ops.fused_ce import (
+        fused_ce_sums_dp,
+        fused_ce_sums_tp,
+    )
+    from pytorch_distributed_tpu.parallel import MeshSpec, build_mesh
+
+    if name == "replicated":
+        return fused_ce_sums, 1
+    if name == "dp":
+        mesh = build_mesh(MeshSpec(("data",), (8,)), jax.devices()[:8])
+        return (lambda *a: fused_ce_sums_dp(*a, mesh)), 8
+    mesh = build_mesh(MeshSpec(("data", "model"), (2, 4)), jax.devices()[:8])
+    return (lambda *a: fused_ce_sums_tp(*a, mesh)), 4
+
+
+@pytest.mark.parametrize("variant", ["replicated", "dp", "tp"])
+def test_the_gradient_runs_three_head_products_a_chunk(variant):
+    """Under differentiation one loop: a chunk's logits, ``dh`` and ``dE``
+    (``GRAD_HEAD_PRODUCTS``, what the step reports as
+    ``loss_head_products``), and no loop that computes the logits again.
+    The plain call, what an eval step gets, runs the one product and
+    carries no float32 ``[V, D]`` (or vocab shard of it): it does not pay
+    for a gradient."""
+    from pytorch_distributed_tpu.ops.fused_ce import GRAD_HEAD_PRODUCTS
+
+    fn, shards = _variant(variant)
+    h, e, t, w = _op_inputs(seed=3, v=64)
+    acc = (64 // shards, D)
+
+    plain = jax.make_jaxpr(lambda h, e, w: fn(h, e, t, w, 3))(h, e, w)
+    assert _head_products(plain.jaxpr) == 1
+    assert not _holds_f32(plain.jaxpr, acc)
+    assert sum(eqn.primitive.name == "scan"
+               for eqn in _eqns(plain.jaxpr)) == 1
+
+    grad = jax.make_jaxpr(jax.grad(
+        lambda h, e, w: fn(h, e, t, w, 3)[0] / 7.0, argnums=(0, 1, 2)))(
+            h, e, w)
+    assert GRAD_HEAD_PRODUCTS == 3
+    assert _head_products(grad.jaxpr) == GRAD_HEAD_PRODUCTS
+    assert _holds_f32(grad.jaxpr, acc)
+    assert sum(eqn.primitive.name == "scan"
+               for eqn in _eqns(grad.jaxpr)) == 1
+
+
+@pytest.mark.parametrize("chunks", [0, 2])
+def test_the_fit_loop_books_loss_head_products_on_dispatch(chunks):
+    """The step's metrics carry ``loss_head_products`` (the head products
+    a chunk of the differentiated loss runs: a constant of the compiled
+    step) where the loss is the fused one, and ``LMTrainer.fit`` books it
+    on its ``dispatch`` records; the unfused step has no such counter."""
+    from pytorch_distributed_tpu.obs.trace import RECORDER
+    from pytorch_distributed_tpu.ops.fused_ce import GRAD_HEAD_PRODUCTS
+    from pytorch_distributed_tpu.train.lm import (
+        LMTrainer,
+        SyntheticTokenDataset,
+    )
+
+    mesh = data_parallel_mesh()
+    model = TransformerLM(vocab_size=64, d_model=32, n_heads=4, n_layers=1)
+    ds = SyntheticTokenDataset(32, 16, 64, seed=0)
+    trainer = LMTrainer(model, mesh, ds, batch_size=8, lr=1e-2,
+                        fused_ce_chunks=chunks)
+    RECORDER.clear()
+    trainer.fit(2, print_freq=100)
+    booked = [r.fields for r in RECORDER.records() if r.name == "dispatch"]
+    assert len(booked) == 2
+    if chunks:
+        assert [int(f["loss_head_products"]) for f in booked] == [
+            GRAD_HEAD_PRODUCTS] * 2
+    else:
+        assert all("loss_head_products" not in f for f in booked)
+
+
 def test_fused_ce_pads_indivisible_rows():
     """N not divisible by num_chunks: weight-0 padding keeps values and
     grads exact (the LM's N = B*(L-1) is rarely chunk-aligned)."""

@@ -197,33 +197,26 @@ def test_grouped_flash_fwd_bwd_compiles_for_v5e(v5e_devices):
     assert [x.shape for x in out] == [q.shape, kv.shape, kv.shape]
 
 
-def test_tied_step_sums_the_embeddings_gradient_into_one_buffer(
-        v5e_devices):
-    """The tied embedding's gradient has two parts: the head's, which the
-    fused loss accumulates in a float32 [V, d] buffer, and the lookup's, a
-    scatter-add of the first block's input gradient.  Compiled for the
-    chip, the scatter-add lands in the head's buffer: the step's memory
-    ledger (``obs/memory.py``) holds no [V, d] array of zeros for it to
-    land in (at the cell's size that is 1.07 GB the step does not take:
-    PERF.md 6, PR 32), and the sum is float32."""
+def _tied_step_compiled(devices, vocab, d, batch, seq, chunks):
+    """``make_lm_train_step`` of the tied top-1 decoder (tests/test_zaya1.py
+    ``PRESET`` at another vocabulary and width) with AdamW and the fused
+    loss, compiled for one described chip from shapes alone."""
     import warnings
 
     from pytorch_distributed_tpu.models.decoder import (
         DecoderConfig,
         DecoderLM,
     )
-    from pytorch_distributed_tpu.obs.memory import ledger_from_compiled
     from pytorch_distributed_tpu.parallel.tp import replicated_like
     from pytorch_distributed_tpu.train.lm import make_lm_train_step
     from pytorch_distributed_tpu.train.optim import adamw
     from pytorch_distributed_tpu.train.state import TrainState
     from test_zaya1 import PRESET
 
-    vocab, d = 8192, 256    # no other array of this size in the step
     cfg = {**PRESET, "vocab_size": vocab, "hidden_size": d}
-    mesh = Mesh(np.array(v5e_devices[:1]), ("data",))
+    mesh = Mesh(np.array(devices[:1]), ("data",))
     model = DecoderLM(DecoderConfig.from_dict(cfg), dtype=jnp.bfloat16)
-    tokens = jnp.zeros((2, 128), jnp.int32)
+    tokens = jnp.zeros((batch, seq), jnp.int32)
     variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
     tx = adamw({"lr": 1e-3, "b1": 0.9, "b2": 0.95, "eps": 1e-8,
                 "weight_decay": 0.1})
@@ -234,15 +227,30 @@ def test_tied_step_sums_the_embeddings_gradient_into_one_buffer(
         warnings.simplefilter("ignore")
         step = make_lm_train_step(
             model, mesh, replicated_like(state.params), tx=tx,
-            params=state.params, fused_ce_chunks=2)
+            params=state.params, fused_ce_chunks=chunks)
     one = NamedSharding(mesh, P())
     state = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), state)
-    compiled = step.lower(
+    return step.lower(
         state, jax.ShapeDtypeStruct(tokens.shape, jnp.int32,
                                     sharding=NamedSharding(
                                         mesh, P("data", None))),
         jax.ShapeDtypeStruct((), jnp.float32, sharding=one)).compile()
+
+
+def test_tied_step_sums_the_embeddings_gradient_into_one_buffer(
+        v5e_devices):
+    """The tied embedding's gradient has two parts: the head's, which the
+    fused loss accumulates in a float32 [V, d] buffer, and the lookup's, a
+    scatter-add of the first block's input gradient.  Compiled for the
+    chip, the scatter-add lands in the head's buffer: the step's memory
+    ledger (``obs/memory.py``) holds no [V, d] array of zeros for it to
+    land in (at the cell's size that is 1.07 GB the step does not take:
+    PERF.md 6, PR 32), and the sum is float32."""
+    from pytorch_distributed_tpu.obs.memory import ledger_from_compiled
+
+    vocab, d = 8192, 256    # no other array of this size in the step
+    compiled = _tied_step_compiled(v5e_devices, vocab, d, 2, 128, 2)
     ledger = ledger_from_compiled(compiled)
     temps = [b for b in ledger.buffers
              if b.defined_at >= 0 and b.dims == [vocab, d]]
@@ -250,3 +258,31 @@ def test_tied_step_sums_the_embeddings_gradient_into_one_buffer(
     assert len(scatter) == 1 and scatter[0].dtype == "f32", temps
     assert not any("broadcast" in b.op_name for b in temps), [
         (b.name, b.op_name) for b in temps]
+
+
+def test_the_steps_memory_scheduler_is_accepted_and_holds_the_tied_peak(
+        v5e_devices, monkeypatch):
+    """``make_lm_train_step`` hands a TPU mesh's compiler
+    ``xla_memory_scheduler=list`` (``step_compiler_options``; a CPU mesh
+    gets no option).  The chip's compiler takes the option (an unknown one
+    is refused at compile), and the tied step's temporaries under it are
+    below what the compiler's own choice among its three schedules gives:
+    that choice goes by an estimate, and for this step, whose loss holds
+    the head's float32 gradient from its one loop on, it is the depth-first
+    schedule, which applies every update after the whole backward pass
+    (at the cell's size 16.31 GB against 13.89: PERF.md 6, PR 33).  Should
+    the compiler come to choose as well by itself, this fails and the pin
+    can go."""
+    from pytorch_distributed_tpu.train import lm
+
+    cpu = Mesh(np.array(jax.devices()[:1]), ("data",))
+    tpu = Mesh(np.array(v5e_devices[:1]), ("data",))
+    assert lm.step_compiler_options(cpu) is None
+    assert lm.step_compiler_options(tpu) == {"xla_memory_scheduler": "list"}
+
+    size = dict(vocab=32768, d=512, batch=2, seq=1024, chunks=4)
+    pinned = _tied_step_compiled(v5e_devices, **size).memory_analysis()
+    monkeypatch.setattr(lm, "step_compiler_options", lambda mesh: None)
+    chosen = _tied_step_compiled(v5e_devices, **size).memory_analysis()
+    assert pinned.temp_size_in_bytes < 0.9 * chosen.temp_size_in_bytes, (
+        pinned.temp_size_in_bytes, chosen.temp_size_in_bytes)
