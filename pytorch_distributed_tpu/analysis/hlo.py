@@ -197,6 +197,34 @@ def instruction_operands(ins: Instruction) -> List[str]:
     return _OPERAND_REF_RE.findall(region)
 
 
+_METADATA_BLOCK_RE = re.compile(r"metadata=\{[^}]*\}")
+_CALLED_RE = re.compile(
+    r"\b(?P<how>body|condition|to_apply|calls|true_computation"
+    r"|false_computation|branch_computations|called_computations)="
+    r"(?:%?(?P<one>[\w.\-]+)|\{(?P<many>[^}]*)\})")
+
+
+def called_computations(ins: Instruction) -> List[Tuple[str, str]]:
+    """``(attribute, computation)`` for every computation an instruction
+    names after its operands: a ``while``'s ``body`` and ``condition``, a
+    ``call``'s or a fusion's ``calls``, a reduce's ``to_apply``, a
+    conditional's branches.  What runs inside those computations runs on
+    this instruction's behalf, so an attribution that the callee's own
+    metadata does not settle falls back on the caller's
+    (obs/trace.py ``scope_map``)."""
+    m = _INSTR_RE.match(ins.line)
+    if m is None:
+        return []
+    # a metadata string may hold anything: taken out before the search
+    tail = _METADATA_BLOCK_RE.sub("", m.group("rhs"))
+    out: List[Tuple[str, str]] = []
+    for c in _CALLED_RE.finditer(tail):
+        names = ([c.group("one")] if c.group("one") else
+                 [n.strip().lstrip("%") for n in c.group("many").split(",")])
+        out.extend((c.group("how"), n) for n in names if n)
+    return out
+
+
 _PARAM_NUM_RE = re.compile(r"parameter\((\d+)\)")
 
 

@@ -8,8 +8,10 @@ is three ``.item()`` calls per batch plus a 500 ms nvidia-smi CSV).
 - ``trace``     — ``span()``/``RECORDER``: host spans of the run loop, the
   feeder and the loader, kept in a bounded in-memory ring and mirrored as
   ``ptd:<name>`` TraceAnnotations; ``scope()``: TraceAnnotation +
-  named_scope for in-graph names; ``ProfileWindow``: epoch/step-windowed
-  profiler capture.
+  named_scope for in-graph names; ``compiled_scopes()``: which of those
+  names and which phase each instruction of a compiled step belongs to
+  (what a TPU capture's events join to); ``ProfileWindow``: epoch/step-
+  windowed profiler capture.
 - ``heartbeat`` — per-process ``{pid, step, t, ema, last_ft}`` beats to a
   shared run directory + cross-process straggler detection that tells
   *slow* ranks from *dead* ones (stdlib-only monitor).

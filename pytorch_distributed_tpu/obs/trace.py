@@ -18,6 +18,18 @@ self-time attribute to named regions — ``pp_stage_fwd`` instead of
 ``fusion.1234`` — which is what turns ``scripts/profile_trace.py`` output
 into per-stage evidence.
 
+``compiled_scopes(program)`` is the other end of ``scope()``: the names go
+into the HLO's ``op_name`` metadata, the optimized module keeps them, and a
+capture's ``XLA Ops`` event is named by that module's instruction
+(``%fusion.2066 = ...``), so the two join on the instruction's name.  A
+step builder keeps a ``StepProgram`` (its jitted step, and the shapes the
+step's body noted while it was traced); ``compiled_scopes`` compiles the
+step from those shapes once more (the persistent cache answers) and turns
+the text into ``{instruction: ScopeOf(scopes, phase)}`` with
+``analysis/hlo.py``'s parsers.  ``benchmark/scope_times.py`` joins that to
+a capture; ``Trainer.fit`` and ``LMTrainer.fit`` write it as
+``scopes.json`` beside ``spans.jsonl``.
+
 ``ProfileWindow`` drives ``jax.profiler.start_trace``/``stop_trace`` from
 epoch/step windows so a trace can capture steady state, not just the
 warm-up epoch the seed hard-coded.  ``capture(dir)`` is its one-shot
@@ -32,11 +44,16 @@ import collections
 import contextlib
 import itertools
 import json
+import os
+import re
 import threading
 import time
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Set, Tuple
 
 import jax
+
+from pytorch_distributed_tpu.analysis import hlo
+from pytorch_distributed_tpu.obs.comms import phase_of_op_name
 
 SPAN_PREFIX = "ptd:"  # a span's name inside a profiler capture
 
@@ -161,11 +178,220 @@ def span(name: str, id: Optional[int] = None, **fields):
     return _OpenSpan(name, id, fields)
 
 
+SCOPE_NAMES: Set[str] = set()  # every name a scope() was entered under
+
+
 @contextlib.contextmanager
 def scope(name: str):
-    """Host TraceAnnotation + in-graph named_scope under one name."""
+    """Host TraceAnnotation + in-graph named_scope under one name, which
+    is noted in ``SCOPE_NAMES``: ``compiled_scopes`` looks for these names
+    in an instruction's ``op_name`` path."""
+    SCOPE_NAMES.add(name)
     with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
         yield
+
+
+# ------------------------------------------------ the compiled step's scopes
+
+class ScopeOf(NamedTuple):
+    """Where one instruction of a compiled step belongs."""
+
+    scopes: Tuple[str, ...]  # the scope() names in its op_name, outermost first
+    phase: str               # forward | backward | recompute | optimizer |
+    #                          unknown (or a pipeline tick's own name)
+
+
+_PATH_SPLIT = re.compile(r"[/()]")  # jvp(lm_head), .../checkpoint/blockB/mul
+_LOC_NAME = re.compile(r'loc\("([^"]*)"')  # a lowering's locations
+
+
+def _names_in(paths: Iterable[str], names: Set[str]) -> Set[str]:
+    found: Set[str] = set()
+    for path in set(paths):
+        found.update(p for p in _PATH_SPLIT.split(path) if p in names)
+    return found
+
+
+def scope_of_op_name(op_name: str, names: Set[str]) -> ScopeOf:
+    """``jit(step)/transpose(jvp(lm_forward))/DecoderLM/.../attn/mul`` ->
+    ``(("lm_forward", "attn"), "backward")``.  ``rematted_computation`` in
+    the path is the forward pass run again inside the backward one (its
+    path holds ``transpose(`` too, so it is looked for first); ``grad_clip``
+    and ``grad_sync`` count with the optimizer."""
+    scopes = tuple(p for p in _PATH_SPLIT.split(op_name) if p in names)
+    if "rematted_computation" in op_name:
+        return ScopeOf(scopes, "recompute")
+    phase = phase_of_op_name(op_name)
+    return ScopeOf(scopes, "optimizer" if phase in ("grad_clip", "grad_sync")
+                   else phase)
+
+
+def scope_map(hlo_text: str, names: Set[str]) -> Dict[str, ScopeOf]:
+    """Every instruction the chip executes by itself (those inside fused
+    computations have no event in a capture and are left out) with the
+    scopes and the phase its ``op_name`` gives.
+
+    An instruction whose ``op_name`` holds none of ``names`` (or that has
+    none) takes scopes and phase from the instruction that calls its
+    computation (a ``while``'s body and condition, a ``call``, a
+    conditional's branches), transitively.  The phase comes with the scopes
+    because the compiler's own names would read as forward: the TPU
+    compiler renames a ``ragged_dot`` Mosaic call's ``op_name`` to
+    ``ragged-dot-none``, in the backward loops too.  Pure text, no jax."""
+    instrs = hlo.parse_instructions(hlo_text)
+    called_by: Dict[str, hlo.Instruction] = {}
+    for ins in instrs:
+        for _, computation in hlo.called_computations(ins):
+            called_by.setdefault(computation, ins)
+
+    alone: Dict[str, bool] = {}  # computation -> run instruction by instruction
+
+    def runs_alone(computation: str) -> bool:
+        if computation not in alone:
+            caller = called_by.get(computation)
+            alone[computation] = caller is None or (
+                caller.opcode != "fusion" and runs_alone(caller.computation))
+        return alone[computation]
+
+    own = {ins.name: (ins, scope_of_op_name(
+               hlo.parse_op_metadata(ins.line)[0], names))
+           for ins in instrs if runs_alone(ins.computation)}
+    out: Dict[str, ScopeOf] = {}
+
+    def resolve(name: str) -> ScopeOf:
+        if name not in out:
+            ins, mine = own[name]
+            caller = called_by.get(ins.computation)
+            out[name] = (resolve(caller.name)
+                         if not mine.scopes and caller is not None else mine)
+        return out[name]
+
+    for name in own:
+        resolve(name)
+    return out
+
+
+class StepProgram:
+    """What a step builder keeps so that its compiled step can name its own
+    instructions: the jitted step, under the name of its module
+    (``jit_global_step``, ``jit_step``), and the shapes and dtypes of its
+    arguments, which the step function's body notes while jit traces it.
+    Nothing is wrapped and nothing runs per call."""
+
+    def __init__(self, name: str):
+        self.jitted = None        # set by the builder, once jitted
+        self.in_shardings = None  # as the builder gave them to jax.jit
+        self.args = None          # ShapeDtypeStructs, once traced
+        self.scopes: Optional[Dict[str, ScopeOf]] = None
+        self.recompiled = False   # the cached executable had other names
+        STEP_PROGRAMS[name] = self  # a process's latest step of this name
+
+    def note(self, *args) -> None:
+        """Called from the step function's body: the arguments are
+        tracers, which have a shape and a dtype.  The jit's
+        ``in_shardings`` go with them: lowered from bare shapes the module
+        numbers its private functions otherwise, the compile cache misses,
+        and the new executable's instructions need not be numbered like
+        those of the one that ran (PERF.md 6, PR 34)."""
+        shardings = (jax.tree.broadcast(self.in_shardings, args)
+                     if self.in_shardings is not None
+                     else jax.tree.map(lambda _: None, args))
+        self.args = jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=s, weak_type=x.weak_type),
+            args, shardings)
+
+    def jit(self, fn, **kwargs):
+        self.in_shardings = kwargs.get("in_shardings")
+        self.jitted = jax.jit(fn, **kwargs)
+        return self.jitted
+
+
+STEP_PROGRAMS: Dict[str, StepProgram] = {}
+
+
+@contextlib.contextmanager
+def _no_persistent_cache():
+    """One compile that neither reads nor writes the persistent cache (the
+    cache latches its on/off at first use: reset on both sides)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    cc.reset_cache()
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def compiled_scopes(program: str) -> Dict[str, ScopeOf]:
+    """``{instruction: ScopeOf}`` of this process's step ``program`` (an
+    ``XLA Modules`` name without its fingerprint: ``jit_step``).  On first
+    use the step is lowered from the shapes and shardings its body noted
+    and compiled, which the compile cache answers with the executable that
+    ran (the same module: the same key), and the text is kept.
+
+    The map has to be of *this process's* names, and the persistent cache
+    does not promise that: JAX leaves metadata out of the cache key, so a
+    program that differs from a cached one only in its scope names is
+    served the cached executable, whose text carries the old names.  So
+    every scope name in this process's lowering has to occur in the
+    compiled text; where one does not, the step is compiled once more with
+    the persistent cache out of the way (same input, same compiler: the
+    same instruction names) and ``StepProgram.recompiled`` says so.  Names
+    are looked for in the whole text, fused instructions included: a scope
+    whose operations were all fused under another scope's root is in the
+    text and rightly not in the map.
+
+    ``LookupError``: no step of that name was built, or it never ran."""
+    step = STEP_PROGRAMS.get(program)
+    if step is None or step.args is None or step.jitted is None:
+        raise LookupError(f"no traced step program named {program!r} in this "
+                          f"process (built: {sorted(STEP_PROGRAMS)})")
+    if step.scopes is None:
+        names = set(SCOPE_NAMES)
+        lowered = step.jitted.lower(*step.args)
+        traced = _names_in(
+            _LOC_NAME.findall(lowered.as_text(debug_info=True)), names)
+        text = lowered.compile().as_text()
+        compiled = _names_in(
+            (hlo.parse_op_metadata(ins.line)[0]
+             for ins in hlo.parse_instructions(text)), names)
+        if not traced <= compiled:
+            # the process memoises a lowering's executable: dropped first
+            jax.clear_caches()
+            with _no_persistent_cache():
+                text = step.jitted.lower(*step.args).compile().as_text()
+            step.recompiled = True
+        step.scopes = scope_map(text, names)
+    return step.scopes
+
+
+def dump_scopes(step, path: str) -> int:
+    """Write the scope map of a builder's jitted ``step`` (found under its
+    module's name, ``jit_<function>``) as one JSON object, ``{instruction:
+    {"scopes": [...], "phase": ...}}``; returns the count (0 and no file
+    where the step never ran or is no builder's)."""
+    try:
+        scopes = compiled_scopes("jit_" + getattr(step, "__name__", ""))
+    except LookupError:
+        return 0
+    with open(path, "w") as f:
+        json.dump({name: s._asdict() for name, s in scopes.items()}, f)
+    return len(scopes)
+
+
+def dump_beside_capture(profile_dir: str, step) -> None:
+    """What a trainer leaves in its ``--profile-dir`` beside the capture:
+    ``spans.jsonl``, the run loop's, the feeder's and the loader's host
+    spans, and ``scopes.json``, which scope and phase each instruction of
+    the compiled ``step`` belongs to (a capture names its device events by
+    the instruction)."""
+    os.makedirs(profile_dir, exist_ok=True)
+    RECORDER.dump(os.path.join(profile_dir, "spans.jsonl"))
+    dump_scopes(step, os.path.join(profile_dir, "scopes.json"))
 
 
 @contextlib.contextmanager

@@ -78,11 +78,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_tpu.obs.trace import scope
+
 
 # The head products a chunk of the differentiated loss runs: its logits,
-# dh and dE (the undifferentiated loss runs the first alone).  The train
-# step reports it as ``loss_head_products``; tests/test_fused_ce.py holds
-# every variant's jaxpr to it.
+# dh and dE (the undifferentiated loss runs the first alone).
+# tests/test_fused_ce.py holds every variant's jaxpr to it.
 GRAD_HEAD_PRODUCTS = 3
 
 
@@ -162,9 +163,10 @@ def _loss_loop(num_chunks: int, h, t, w, sums):
         _, _, dl, dc = sums(*blk)
         return (carry[0] + dl, carry[1] + dc), None
 
-    out, _ = jax.lax.scan(
-        body, (jnp.float32(0.0), jnp.float32(0.0)),
-        _chunks(num_chunks, h, t, w))
+    with scope("fused_ce"):
+        out, _ = jax.lax.scan(
+            body, (jnp.float32(0.0), jnp.float32(0.0)),
+            _chunks(num_chunks, h, t, w))
     return out
 
 
@@ -178,12 +180,14 @@ def _grad_loop(num_chunks: int, h, t, w, de_shape, grads):
         ce, dl, dc, dh_b, de_b = grads(*blk)
         return (loss + dl, correct + dc, de_acc + de_b), (dh_b, ce)
 
-    (loss, correct, de), (dh, ce) = jax.lax.scan(
-        body,
-        (jnp.float32(0.0), jnp.float32(0.0),
-         jnp.zeros(de_shape, jnp.float32)),
-        _chunks(num_chunks, h, t, w))
-    return loss, correct, dh.reshape((-1,) + h.shape[1:]), de, ce.reshape(-1)
+    with scope("fused_ce"):
+        (loss, correct, de), (dh, ce) = jax.lax.scan(
+            body,
+            (jnp.float32(0.0), jnp.float32(0.0),
+             jnp.zeros(de_shape, jnp.float32)),
+            _chunks(num_chunks, h, t, w))
+        return (loss, correct, dh.reshape((-1,) + h.shape[1:]), de,
+                ce.reshape(-1))
 
 
 def _scale(res, cts):
@@ -194,8 +198,9 @@ def _scale(res, cts):
     cotangent leave in their inputs' dtypes."""
     dh, de, ce, e_like, w_like = res
     g = cts[0]
-    return ((dh * g).astype(dh.dtype), (de * g).astype(e_like.dtype), None,
-            (ce * g).astype(w_like.dtype))
+    with scope("fused_ce"):
+        return ((dh * g).astype(dh.dtype), (de * g).astype(e_like.dtype),
+                None, (ce * g).astype(w_like.dtype))
 
 
 def fused_ce_sums(h, e, targets, weights, num_chunks: int):

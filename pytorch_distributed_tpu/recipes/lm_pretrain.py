@@ -357,6 +357,15 @@ def build_parser() -> argparse.ArgumentParser:
                         "summing to step_time exactly — into every "
                         "metrics record; analyze with "
                         "scripts/obs_roofline.py")
+    p.add_argument("--profile-dir", type=str, default=None,
+                   dest="profile_dir", metavar="DIR",
+                   help="write an XPlane trace of the run there, with the "
+                        "loop's host spans (spans.jsonl) and the compiled "
+                        "step's scope map (scopes.json: which scope() and "
+                        "phase each device instruction belongs to)")
+    p.add_argument("--profile-steps", type=str, default=None,
+                   dest="profile_steps", metavar="I[:J]",
+                   help="trace only that step range (past compilation)")
     p.add_argument("--eval-every", type=int, default=0,
                    help="run held-out eval (loss/ppl) every N steps; "
                         "0 = end-of-run only")
@@ -661,6 +670,8 @@ def main(argv=None) -> float:
             alerts=args.alerts,
             step_attr=args.step_attr,
             tx=tx,
+            profile_dir=args.profile_dir,
+            profile_steps=args.profile_steps,
         )
         try:
             final_loss = trainer.fit(args.steps, print_freq=args.print_freq)

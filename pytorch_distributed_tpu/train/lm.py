@@ -16,6 +16,7 @@ meters, msgpack checkpoints) and adds:
 
 from __future__ import annotations
 
+import functools
 from contextlib import nullcontext
 from typing import Any, Dict, Optional, Tuple
 
@@ -25,6 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pytorch_distributed_tpu.models.transformer import bind_mesh
+from pytorch_distributed_tpu.obs.trace import StepProgram, scope
 from pytorch_distributed_tpu.ops import cross_entropy, qcomm
 from pytorch_distributed_tpu.train.meters import StepMeters
 from pytorch_distributed_tpu.train.optim import sgd_init, sgd_update
@@ -347,10 +349,7 @@ def make_lm_train_step(
     selection bias) has that state read from ``state.batch_stats``, updated
     after the gradients by its own ``update_state`` from the counters the
     forward pass sowed (no gradient, no optimizer).  A model with
-    ``counter_names`` has its ``step_counters`` added to the metrics, and
-    a step on the fused loss ``loss_head_products``: the head products a
-    chunk of the differentiated loss runs (ops/fused_ce.py
-    ``GRAD_HEAD_PRODUCTS``), a constant of the compiled step.
+    ``counter_names`` has its ``step_counters`` added to the metrics.
 
     A model with ``n_exits`` > 1 (models/decoder.py: a looped decoder)
     returns every exit's hidden rows ``[T, B, L, d]`` and sows a float32
@@ -363,6 +362,8 @@ def make_lm_train_step(
     from pytorch_distributed_tpu.parallel import zero as zero_lib
 
     model = bind_mesh(model, mesh)
+    # the step under its module's name, for obs/trace.py compiled_scopes
+    program = StepProgram("jit_step")
     zero_mode = zero_lib.resolve_zero(zero)
     overlap_mode = overlap_lib.resolve_overlap(overlap)
     state_col = _model_state_collection(model)
@@ -456,16 +457,18 @@ def make_lm_train_step(
             "unaccumulated: pass fused_ce_chunks > 0, accum_steps = 1")
 
     def step(state: TrainState, tokens: jnp.ndarray, lr: jnp.ndarray):
+        program.note(state, tokens, lr)
+
         def variables(params):
             if state_col:
                 return {"params": params, state_col: state.batch_stats}
             return {"params": params}
 
         def loss_fn(params, toks, probe=None):
-            # named_scope: forward ops carry the phase name into XPlane
-            # traces (autodiff derives the backward names from it) —
-            # per-phase self-time instead of anonymous fusions.
-            with jax.named_scope("lm_forward"):
+            # scope(): forward ops carry the phase name into the compiled
+            # module's metadata (autodiff derives the backward names from
+            # it): per-phase self-time instead of anonymous fusions.
+            with scope("lm_forward"):
                 return loss_impl(params, toks, probe)
 
         def loss_impl(params, toks, probe):
@@ -508,7 +511,7 @@ def make_lm_train_step(
                     # ``probe`` [T] is zero: its own cotangent is each
                     # exit's mean cross-entropy
                     t = jnp.tile(t, n_exits)
-                    with jax.named_scope("exit_loss"):
+                    with scope("exit_loss"):
                         w = ((sown["exits"]["weight"][0][..., :-1]
                               + probe[:, None, None]) / ntok).reshape(-1)
                 e = head_matrix(model, params).astype(cdt)
@@ -600,7 +603,7 @@ def make_lm_train_step(
                  if (log_norms or clip_grad_norm > 0.0 or guard_nonfinite)
                  else None)
         if clip_grad_norm > 0.0:
-            with jax.named_scope("grad_clip"):
+            with scope("grad_clip"):
                 scale = jnp.minimum(
                     1.0, clip_grad_norm / jnp.maximum(gnorm, 1e-12))
                 grads = jax.tree_util.tree_map(
@@ -611,13 +614,13 @@ def make_lm_train_step(
         if gc_mode in qcomm.QUANTIZED_MODES:
             # GSPMD numerics emulation: fake-quantize the (already synced)
             # global gradient with error feedback — see module warning.
-            with jax.named_scope("grad_sync"):
+            with scope("grad_sync"):
                 grads, new_residual = qcomm.compress_emulated(
                     grads, state.residual, gc_mode)
         elif gc_cast is not None:
             grads = jax.tree_util.tree_map(
                 lambda g: g.astype(gc_cast).astype(jnp.float32), grads)
-        with jax.named_scope("optimizer"):
+        with scope("optimizer"):
             if tx is None:
                 new_params, new_momentum = sgd_update(
                     grads, state.momentum, state.params, lr,
@@ -635,14 +638,6 @@ def make_lm_train_step(
             new_model_state = model.update_state(state.batch_stats, seen)
         if counted:
             metrics.update(model.step_counters(new_model_state, seen))
-        if fused_ce_chunks:
-            from pytorch_distributed_tpu.ops.fused_ce import (
-                GRAD_HEAD_PRODUCTS,
-            )
-
-            # the head products a chunk of the differentiated loss runs: a
-            # constant of the compiled step, like ``attn_blocks_visited``
-            metrics["loss_head_products"] = jnp.int32(GRAD_HEAD_PRODUCTS)
         if guard_nonfinite:
             bad = nonfinite_flag(loss, gnorm)
             new_params = gate_update(bad, state.params, new_params)
@@ -667,7 +662,7 @@ def make_lm_train_step(
                        model_state=bool(state_col)),
     )
     token_sharding = NamedSharding(mesh, P(data_axis, None))
-    return jax.jit(
+    return program.jit(
         step,
         in_shardings=(state_shardings, token_sharding,
                       NamedSharding(mesh, P())),
@@ -733,7 +728,7 @@ def _make_lm_train_step_explicit(
 
     def local_step(state: TrainState, tokens: jnp.ndarray, lr: jnp.ndarray):
         def loss_fn(p, toks):
-            with jax.named_scope("lm_forward"):
+            with scope("lm_forward"):
                 logits, sown = model.apply({"params": p}, toks,
                                            mutable=["losses"])
                 vocab = logits.shape[-1]
@@ -754,7 +749,7 @@ def _make_lm_train_step_explicit(
         new_residual = state.residual
         # Equal-size shards: mean-of-shard-means == global mean, so the
         # synced gradient is psum/n of the local d(mean loss)/dp.
-        with jax.named_scope("grad_sync"):
+        with scope("grad_sync"):
             if overlap_mode == "bucketed":
                 grads, new_residual = overlap_lib.bucketed_psum(
                     grads, state.residual, data_axis, mode=gc_mode,
@@ -777,14 +772,14 @@ def _make_lm_train_step_explicit(
                  if (log_norms or clip_grad_norm > 0.0 or guard_nonfinite)
                  else None)
         if clip_grad_norm > 0.0:
-            with jax.named_scope("grad_clip"):
+            with scope("grad_clip"):
                 scale = jnp.minimum(
                     1.0, clip_grad_norm / jnp.maximum(gnorm, 1e-12))
                 grads = jax.tree_util.tree_map(
                     lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype),
                     grads,
                 )
-        with jax.named_scope("optimizer"):
+        with scope("optimizer"):
             new_params, new_momentum = sgd_update(
                 grads, state.momentum, state.params, lr,
                 momentum=momentum, weight_decay=weight_decay,
@@ -812,14 +807,22 @@ def _make_lm_train_step_explicit(
         momentum=replicated,
         residual=(NamedSharding(mesh, P(data_axis)) if quantized
                   else replicated))
-    stepped = shard_map(
+    sharded_step = shard_map(
         local_step,
         mesh=mesh,
         in_specs=(state_spec, P(data_axis, None), P()),
         out_specs=(state_spec, P()),
         check_vma=False,
     )
-    return jax.jit(
+    # the step under its module's name, for obs/trace.py compiled_scopes
+    program = StepProgram("jit_local_step")
+
+    @functools.wraps(local_step)  # the module keeps its name
+    def stepped(state, tokens, lr):
+        program.note(state, tokens, lr)  # the global shapes, not a shard's
+        return sharded_step(state, tokens, lr)
+
+    return program.jit(
         stepped,
         in_shardings=(state_sharding, NamedSharding(mesh, P(data_axis, None)),
                       replicated),
@@ -937,6 +940,8 @@ class LMTrainer:
         alerts: Optional[str] = None,
         step_attr: bool = False,
         tx=None,
+        profile_dir: Optional[str] = None,
+        profile_steps: Optional[str] = None,
     ):
         """``tx``: an optional optax ``GradientTransformation`` in place of
         the built-in SGD (``make_lm_train_step``): ``lr`` and
@@ -1012,6 +1017,10 @@ class LMTrainer:
         self.dataset = dataset
         self.batch_size = batch_size
         self.lr = lr
+        # ``fit`` traces into it (the whole run, or ``profile_steps``
+        # 'I:J') and leaves ``spans.jsonl`` and ``scopes.json`` there
+        self.profile_dir = profile_dir
+        self.profile_steps = profile_steps
         self.is_primary = is_primary
         self.checkpoint_dir = checkpoint_dir
         self.preempt = preempt
@@ -1594,9 +1603,13 @@ class LMTrainer:
         return (self._put_tokens(b) for b in host_iter)
 
     def fit(self, steps: int, print_freq: int = 10) -> float:
-        from pytorch_distributed_tpu.obs import scope
-        from pytorch_distributed_tpu.obs.trace import span
+        from pytorch_distributed_tpu.obs.trace import (
+            ProfileWindow,
+            dump_beside_capture,
+            span,
+        )
 
+        profiler = ProfileWindow(self.profile_dir, steps=self.profile_steps)
         if self.watchdog is not None:
             self.watchdog.install()  # idempotent (re-fit after a fit)
         if self._exporter is not None and not self._exporter.running:
@@ -1649,8 +1662,10 @@ class LMTrainer:
                 self._hang_wd.start()
         try:
             meters.restart_clock()
+            profiler.epoch_begin(0)
             i = start
             while i < steps:
+                profiler.step_begin(0, i)
                 # One `step` span an iteration (obs/trace.py), as in
                 # Trainer.train_epoch: its children are the feeder's
                 # `data_wait`, `dispatch` and the `host_sync` drains.
@@ -1726,15 +1741,11 @@ class LMTrainer:
                         self.state, metrics = self.step_fn(
                             self.state, tokens, lr)
                         # a model's own counters (``counter_names``: a
-                        # configured decoder's routing) and the fused
-                        # loss's ride on the record as unready device
-                        # scalars: whoever reads the record converts them,
-                        # the loop does not
+                        # configured decoder's routing) ride on the record
+                        # as unready device scalars: whoever reads the
+                        # record converts them, the loop does not
                         booked.set(**{k: metrics[k] for k in getattr(
                             self.model, "counter_names", ())})
-                        if "loss_head_products" in metrics:
-                            booked.set(loss_head_products=metrics[
-                                "loss_head_products"])
                         if sa is not None:
                             # The step's blocking transfer: without it, async
                             # dispatch smears step N's device time into N+1's
@@ -1836,6 +1847,9 @@ class LMTrainer:
             raise
         finally:
             token_iter.close()  # unblocks the producer on early exit
+            profiler.epoch_end()
+            if self.profile_dir:
+                dump_beside_capture(self.profile_dir, self.step_fn)
             if self._hang_wd is not None:
                 self._hang_wd.stop()
             if flight_sig is not None:

@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from pytorch_distributed_tpu.models.transformer import bind_mesh
+from pytorch_distributed_tpu.obs.trace import StepProgram, scope
 from pytorch_distributed_tpu.ops import cross_entropy, qcomm, topk_correct
 from pytorch_distributed_tpu.parallel import overlap as overlap_lib
 from pytorch_distributed_tpu.parallel import zero as zero_lib
@@ -74,10 +75,11 @@ def _forward_and_sums(model, params, batch_stats, batch: Batch, train: bool,
                       dropout_rng=None):
     """Weighted-sum loss/metric numerators + weight count (exact over padding)."""
     variables = {"params": params, "batch_stats": batch_stats}
-    # named_scope: forward ops carry this name into XPlane traces (autodiff
-    # derives the backward op names from it), so profiler self-time
-    # attributes to phases instead of anonymous fusions.
-    with jax.named_scope("forward"):
+    # scope(): forward ops carry this name into the compiled module's
+    # metadata (autodiff derives the backward op names from it), so a
+    # capture's time reads by phase (obs/trace.py compiled_scopes) instead
+    # of by anonymous fusions.
+    with scope("forward"):
         if train:
             rngs = {"dropout": dropout_rng} if dropout_rng is not None else None
             logits, mutated = model.apply(
@@ -88,7 +90,7 @@ def _forward_and_sums(model, params, batch_stats, batch: Batch, train: bool,
         else:
             logits = model.apply(variables, batch["images"], train=False)
             new_stats = batch_stats
-    with jax.named_scope("loss_and_metrics"):
+    with scope("loss_and_metrics"):
         w = batch["weights"].astype(jnp.float32)
         count = jnp.sum(w)
         loss_sum = cross_entropy(logits, batch["labels"], weights=w) * count
@@ -219,6 +221,9 @@ def make_train_step(
     """
 
     model = bind_mesh(model, mesh)  # a ViT's kernels wrap themselves for it
+    # the step under its module's name, for obs/trace.py compiled_scopes
+    program = StepProgram("jit_local_step" if explicit_collectives
+                          else "jit_global_step")
     mode, cast_dtype = qcomm.resolve_mode(grad_compress, wire_dtype)
     zero_mode = zero_lib.resolve_zero(zero)
     overlap_mode = overlap_lib.resolve_overlap(overlap)
@@ -253,7 +258,7 @@ def make_train_step(
 
     def sync_grads(grads, count, residual):
         # grads arrive as *local weighted sums*; sync then normalize.
-        with jax.named_scope("grad_sync"):
+        with scope("grad_sync"):
             if overlap_mode == "bucketed":
                 grads, residual = overlap_lib.bucketed_psum(
                     grads, residual, data_axis, mode=mode,
@@ -298,7 +303,7 @@ def make_train_step(
         )
 
     def apply_updates(state: TrainState, grads, lr):
-        with jax.named_scope("optimizer"):
+        with scope("optimizer"):
             if tx is None:
                 return sgd_update(
                     grads, state.momentum, state.params, lr,
@@ -395,7 +400,7 @@ def make_train_step(
             # the shard (momentum stays chunked), all-gather the delta.
             n = jax.lax.axis_size(data_axis)
             idx = jax.lax.axis_index(data_axis)
-            with jax.named_scope("grad_sync"):
+            with scope("grad_sync"):
                 if overlap_mode == "bucketed":
                     gchunks, new_residual = overlap_lib.bucketed_reduce_scatter(
                         grads, state.residual, data_axis, n, mode=mode,
@@ -410,7 +415,7 @@ def make_train_step(
                 gcount = jax.lax.psum(count, data_axis)
                 gchunks = jax.tree_util.tree_map(
                     lambda g: g / gcount, gchunks)
-            with jax.named_scope("optimizer"):
+            with scope("optimizer"):
                 if wus_gather == "deferred":
                     # Stage this step's deltas; the next step drains them.
                     deltas, new_buf = zero_lib.wus_update_chunks(
@@ -472,6 +477,7 @@ def make_train_step(
 
     def global_step(state: TrainState, batch: Batch, lr: jnp.ndarray):
         """GSPMD formulation: global-semantics math, XLA infers collectives."""
+        program.note(state, batch, lr)
         rng = jax.random.fold_in(base_key, state.step)
         grads, new_stats, (loss_sum, c1, c5, count) = accumulated_grads(
             state.params, state.batch_stats, batch, rng
@@ -480,7 +486,7 @@ def make_train_step(
         grads = jax.tree_util.tree_map(lambda g: g / count, grads)
         new_residual = state.residual
         if mode in qcomm.QUANTIZED_MODES:
-            with jax.named_scope("grad_sync"):
+            with scope("grad_sync"):
                 grads, new_residual = qcomm.compress_emulated(
                     grads, state.residual, mode)
         elif cast_dtype is not None:
@@ -550,17 +556,22 @@ def make_train_step(
 
     if explicit_collectives:
         batch_specs = {k: P(data_axis) for k in ("images", "labels", "weights")}
-        stepped = shard_map(
+        sharded_step = shard_map(
             local_step,
             mesh=mesh,
             in_specs=(state_spec, batch_specs, P()),
             out_specs=(state_spec, P()),
             check_vma=False,
         )
+
+        @functools.wraps(local_step)  # the module keeps its name
+        def stepped(state, batch, lr):
+            program.note(state, batch, lr)  # the global shapes, not a shard's
+            return sharded_step(state, batch, lr)
     else:
         stepped = global_step
 
-    return jax.jit(
+    return program.jit(
         stepped,
         in_shardings=(state_sharding, batch_shardings, replicated),
         out_shardings=(state_sharding, replicated),

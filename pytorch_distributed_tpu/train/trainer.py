@@ -29,7 +29,6 @@ from pytorch_distributed_tpu.data import (
 )
 from pytorch_distributed_tpu.data.transforms import eval_transform, train_transform
 from pytorch_distributed_tpu.obs import (
-    RECORDER,
     HeartbeatWriter,
     MetricsLogger,
     ProfileWindow,
@@ -37,6 +36,7 @@ from pytorch_distributed_tpu.obs import (
     scope,
     span,
 )
+from pytorch_distributed_tpu.obs.trace import dump_beside_capture
 from pytorch_distributed_tpu.parallel import DistContext, data_parallel_mesh
 from pytorch_distributed_tpu.train.checkpoint import load_checkpoint, save_checkpoint
 from pytorch_distributed_tpu.train.config import Config
@@ -1163,10 +1163,7 @@ class Trainer:
                                             if self.stepattr is not None
                                             else None))
             if cfg.profile_dir:
-                # the host spans of the run loop, the feeder and the loader
-                # (obs/trace.py), beside the profiler's capture
-                os.makedirs(cfg.profile_dir, exist_ok=True)
-                RECORDER.dump(os.path.join(cfg.profile_dir, "spans.jsonl"))
+                dump_beside_capture(cfg.profile_dir, self.train_step)
             self.obs.flush()
             if self._goodput is not None:
                 print(f"=> {self._goodput.format_summary()}", flush=True)

@@ -154,8 +154,7 @@ def _variant(name):
 @pytest.mark.parametrize("variant", ["replicated", "dp", "tp"])
 def test_the_gradient_runs_three_head_products_a_chunk(variant):
     """Under differentiation one loop: a chunk's logits, ``dh`` and ``dE``
-    (``GRAD_HEAD_PRODUCTS``, what the step reports as
-    ``loss_head_products``), and no loop that computes the logits again.
+    (``GRAD_HEAD_PRODUCTS``), and no loop that computes the logits again.
     The plain call, what an eval step gets, runs the one product and
     carries no float32 ``[V, D]`` (or vocab shard of it): it does not pay
     for a gradient."""
@@ -182,13 +181,17 @@ def test_the_gradient_runs_three_head_products_a_chunk(variant):
 
 
 @pytest.mark.parametrize("chunks", [0, 2])
-def test_the_fit_loop_books_loss_head_products_on_dispatch(chunks):
-    """The step's metrics carry ``loss_head_products`` (the head products
-    a chunk of the differentiated loss runs: a constant of the compiled
-    step) where the loss is the fused one, and ``LMTrainer.fit`` books it
-    on its ``dispatch`` records; the unfused step has no such counter."""
-    from pytorch_distributed_tpu.obs.trace import RECORDER
-    from pytorch_distributed_tpu.ops.fused_ce import GRAD_HEAD_PRODUCTS
+def test_the_compiled_fit_step_names_the_loss_loop(chunks):
+    """What the compiled program itself says (obs/trace.py
+    ``compiled_scopes``), where a build constant among the step's metrics
+    could not: a fit step on the fused loss holds a ``while`` and its
+    products under the ``fused_ce`` scope, one loop, and an unfused step
+    holds no such instruction.  The loop is booked *forward*: since PR 33
+    the custom VJP's forward rule runs the gradient's products in it, and
+    the backward rule's scaling by the loss's cotangent of 1 folds away, so
+    no backward instruction carries the scope."""
+    from pytorch_distributed_tpu.analysis import hlo
+    from pytorch_distributed_tpu.obs import trace
     from pytorch_distributed_tpu.train.lm import (
         LMTrainer,
         SyntheticTokenDataset,
@@ -199,15 +202,23 @@ def test_the_fit_loop_books_loss_head_products_on_dispatch(chunks):
     ds = SyntheticTokenDataset(32, 16, 64, seed=0)
     trainer = LMTrainer(model, mesh, ds, batch_size=8, lr=1e-2,
                         fused_ce_chunks=chunks)
-    RECORDER.clear()
     trainer.fit(2, print_freq=100)
-    booked = [r.fields for r in RECORDER.records() if r.name == "dispatch"]
-    assert len(booked) == 2
-    if chunks:
-        assert [int(f["loss_head_products"]) for f in booked] == [
-            GRAD_HEAD_PRODUCTS] * 2
-    else:
-        assert all("loss_head_products" not in f for f in booked)
+    scopes = trace.compiled_scopes("jit_step")
+    assert trace.STEP_PROGRAMS["jit_step"].jitted is trainer.step_fn
+    loss = {n: s for n, s in scopes.items() if "fused_ce" in s.scopes}
+    if not chunks:
+        assert not loss
+        return
+    program = trace.STEP_PROGRAMS["jit_step"]
+    opcodes = {i.name: i.opcode for i in hlo.parse_instructions(
+        program.jitted.lower(*program.args).compile().as_text())}
+    assert sum(opcodes[n] == "while" for n in loss) == 1
+    assert {s.phase for s in loss.values()} == {"forward"}
+    assert all(s.scopes == ("lm_forward", "fused_ce")
+               for s in loss.values())
+    # the rest of the step is named too: both passes and the update
+    assert {s.phase for s in scopes.values() if s.scopes} >= {
+        "forward", "backward", "optimizer"}
 
 
 def test_fused_ce_pads_indivisible_rows():

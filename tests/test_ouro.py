@@ -466,8 +466,10 @@ def test_the_kimi_presets_step_lowers_as_before():
     dense path); PR 31's two counters, ``attn_blocks_visited`` and
     ``attn_blocks_masked`` among the step's metrics, then moved it, and PR
     33's fused loss (one loop under differentiation, the mean's 1 / ntok
-    in the rows' weights, ``loss_head_products``; the returned hidden rows
-    behind a barrier) again."""
+    in the rows' weights; the returned hidden rows behind a barrier) again,
+    and PR 34 by taking ``loss_head_products`` out of the step's metrics
+    (one scalar result fewer; the scope names PR 34 adds are metadata and
+    not in this text)."""
     from test_decoder import PRESET as KIMI
 
     model = DecoderLM(DecoderConfig.from_dict(KIMI), dtype=jnp.bfloat16)
@@ -485,13 +487,13 @@ def test_the_kimi_presets_step_lowers_as_before():
             model, mesh, replicated_like(state.params), tx=tx,
             params=state.params, fused_ce_chunks=2)
     assert _digest(step, state, tokens) == (
-        "96b7c19939a92955edc78b66adade7bd0fe8b4151480146b7fdf3ba55f1ce5e6")
+        "746c6f554d7836b59208fb2634fdfbadce7a034bc28c6448942ce572a6bcc994")
 
 
 def test_the_transformer_lms_fused_step_lowers_as_before():
     """``TransformerLM`` through the fused loss (the path the exits
     share): the digest the commit before PR 30 gave it held until PR 33's
-    fused loss (as above) moved it."""
+    fused loss moved it, and PR 34's one result fewer (both as above)."""
     from pytorch_distributed_tpu.models.transformer import TransformerLM
 
     model = TransformerLM(vocab_size=128, d_model=32, n_heads=2, n_layers=2)
@@ -503,7 +505,7 @@ def test_the_transformer_lms_fused_step_lowers_as_before():
     mesh = data_parallel_mesh(jax.devices()[:1])
     step = make_lm_train_step(model, mesh, replicated_like(params),
                               fused_ce_chunks=2)
-    assert _digest(step, state, tokens) == "5bebeef2b7008d6c3637f6b131f011e534a8289a34e6804624d704fff24662a0"
+    assert _digest(step, state, tokens) == "9ba4b43584e083d1f425e0b046c2b8ee893e9fd47aee2c9fca9391e7e4af122e"
 
 
 def test_the_two_copies_of_the_reference_are_identical():

@@ -286,3 +286,46 @@ def test_the_steps_memory_scheduler_is_accepted_and_holds_the_tied_peak(
     chosen = _tied_step_compiled(v5e_devices, **size).memory_analysis()
     assert pinned.temp_size_in_bytes < 0.9 * chosen.temp_size_in_bytes, (
         pinned.temp_size_in_bytes, chosen.temp_size_in_bytes)
+
+
+def test_the_tied_step_compiled_for_v5e_says_whose_every_operation_is(
+        v5e_devices):
+    """The optimized module keeps the ``scope()`` names, and a capture's
+    event is named by that module's instruction: ``obs/trace.py``
+    ``scope_map`` on the chip compiler's own text.  The fused loss's one
+    loop reads ``fused_ce`` (forward: since PR 33 its forward rule runs the
+    gradient's products too, and nothing of the scope is left in the
+    backward pass at a cotangent of 1); every ``ragged-dot`` Mosaic call,
+    whose ``op_name`` the TPU compiler overwrites with ``ragged-dot-none``,
+    takes ``moe_experts`` and the phase from the loop that runs it, in all
+    three phases; at least 95% of the fusions the chip executes by
+    themselves resolve to a scope (at this size 606 of 632: the rest are
+    small top-level fusions without an ``op_name`` or under
+    ``DecoderLM.update_state`` / ``step_counters``)."""
+    from pytorch_distributed_tpu.analysis import hlo
+    from pytorch_distributed_tpu.obs import trace
+
+    text = _tied_step_compiled(v5e_devices, 8192, 256, 2, 1024, 2).as_text()
+    scopes = trace.scope_map(text, set(trace.SCOPE_NAMES))
+    opcodes = {i.name: i.opcode for i in hlo.parse_instructions(text)}
+
+    loops = {n: s for n, s in scopes.items() if opcodes[n] == "while"}
+    assert len(loops) >= 4 and all(s.scopes for s in loops.values()), loops
+    loss = [s for s in loops.values() if s.scopes[-1] == "fused_ce"]
+    assert [s.phase for s in loss] == ["forward"], loops
+    under_loss = [s for s in scopes.values() if "fused_ce" in s.scopes]
+    assert {s.phase for s in under_loss} == {"forward"}
+
+    ragged = {n: s for n, s in scopes.items() if n.startswith("ragged-dot")}
+    assert len(ragged) >= 12, sorted(ragged)
+    assert all(s.scopes[-1] == "moe_experts" for s in ragged.values()), {
+        n: s for n, s in ragged.items() if s.scopes[-1:] != ("moe_experts",)}
+    assert {s.phase for s in ragged.values()} == {
+        "forward", "backward", "recompute"}
+
+    fusions = [s for n, s in scopes.items() if opcodes[n] == "fusion"]
+    named = sum(bool(s.scopes) for s in fusions)
+    assert len(fusions) > 300 and named >= 0.95 * len(fusions), (
+        named, len(fusions))
+    assert {s.phase for s in fusions if s.scopes} == {
+        "forward", "backward", "recompute", "optimizer"}
