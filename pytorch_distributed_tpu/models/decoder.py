@@ -237,17 +237,18 @@ def dense_attention(q, k, v, scale: float) -> jnp.ndarray:
 FLASH_BLOCKS = (1024, 1024)
 
 
-def attention_blocks(L: int, attn_impl: str) -> Tuple[int, int]:
-    """``(visited, masked)``: the score blocks one forward call of
-    ``causal_attention`` visits a batch-head and those of them it masks;
-    ``(0, 0)`` where it takes the dense path."""
+def attention_blocks(L: int, attn_impl: str) -> Tuple[int, int, int]:
+    """``(visited, masked, subtiles_skipped)``: the score blocks one
+    forward call of ``causal_attention`` visits a batch-head, those of them
+    it masks and the sub-tiles above the diagonal it skips in them;
+    ``(0, 0, 0)`` where it takes the dense path."""
     from pytorch_distributed_tpu.ops.flash_attention import (
         blocks_visited,
         pick_attention_impl,
     )
 
     if pick_attention_impl(L, attn_impl) != "flash":
-        return 0, 0
+        return 0, 0, 0
     return blocks_visited(L, *FLASH_BLOCKS)
 
 
@@ -677,7 +678,8 @@ class DecoderLM(nn.Module):
         step's metrics."""
         exits = range(1, self.n_exits + 1) if self.n_exits > 1 else ()
         c = self.config
-        return (("attn_blocks_visited", "attn_blocks_masked")
+        return (("attn_blocks_visited", "attn_blocks_masked",
+                 "attn_subtiles_skipped")
                 + (self.ROUTING_COUNTERS if c.expert_layers else ())
                 + (self.TOP1_COUNTERS
                    if c.expert_layers and c.router_hidden_size else ())
@@ -686,11 +688,13 @@ class DecoderLM(nn.Module):
                 + tuple(f"loss_exit_{t}" for t in exits))
 
     def step_counters(self, model_state, counters):
-        """The counters a step reports.  ``attn_blocks_visited`` and
-        ``attn_blocks_masked``: the score blocks one forward call of the
-        causal attention visits a batch-head, and those of them the
-        diagonal crosses (``ops/flash_attention.py`` ``block_schedule``; 0
-        and 0 on the dense path; constants of the compiled step).
+        """The counters a step reports.  ``attn_blocks_visited``,
+        ``attn_blocks_masked`` and ``attn_subtiles_skipped``: the score
+        blocks one forward call of the causal attention visits a
+        batch-head, those of them the diagonal crosses
+        (``ops/flash_attention.py`` ``block_schedule``), and the sub-tiles
+        above the diagonal it skips in those (``subtile``); 0 on the dense
+        path; constants of the compiled step.
         Routing, each summed over the
         expert layers: ``routed_here`` (pairs on held experts),
         ``rows_grouped`` (rows the grouped products processed),
@@ -707,8 +711,9 @@ class DecoderLM(nn.Module):
         ``exit_p_t`` (the batch's mean of each exit's weight),
         ``exit_entropy``, and ``loss_exit_t``, each exit's own mean
         cross-entropy (``counters["exit_losses"]``, from the step)."""
-        visited, masked = counters["attn_blocks"][0]
-        out = {"attn_blocks_visited": visited, "attn_blocks_masked": masked}
+        visited, masked, skipped = counters["attn_blocks"][0]
+        out = {"attn_blocks_visited": visited, "attn_blocks_masked": masked,
+               "attn_subtiles_skipped": skipped}
         if self.config.expert_layers:
             layers = [layer["moe"] for name, layer in counters.items()
                       if name.startswith("layer_")]

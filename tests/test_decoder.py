@@ -372,20 +372,47 @@ def test_tx_none_lowers_the_transformer_lm_step_as_before():
         "7bd903cd57943631a56ce0dfee441838ca4e0e6292002cb87691ae3318d69735")
 
 
-def test_flash_kernel_on_float32_lowers_as_before():
+@pytest.mark.parametrize("blocks,causal,digest", [
+    # square causal blocks: the diagonal blocks in sub-tiles
+    ((128, 128), True,
+     "9b5e38bfbbc5c33528c118eb692c5828ed6672e69ee819c6ae513cfa77d249e1"),
+    # no sub-tiles: the text of the block schedule by scalar prefetch
+    ((64, 128), True,
+     "33137d3bea5f49e71c0aaecd09b5f2cccd8de43c5e7278c4f80c323cde04796d"),
+    ((128, 64), True,
+     "98c85c05ad052ddabb1bdc6ab07d7076c0016f77c6679ccf9abf744e845e5521"),
+    ((128, 128), False,
+     "b155860271f6903ea02571975edef3449f9591074793fd6769df3a9dee88dbc0"),
+])
+def test_flash_kernel_on_float32_lowers_as_before(blocks, causal, digest):
     """One head size, the default scale, float32 in: forward and the two
-    backward kernels lower to the text PR 31 gave them (the block
-    schedule by scalar prefetch; PR 26's text until then)."""
+    backward kernels lower to a fixed text.  Where the crossed blocks are
+    not sub-tiled (blocks that are not square, a call that is not causal)
+    it is the text of the block schedule before the sub-tiles; square
+    causal blocks take the sub-tiled text."""
     from pytorch_distributed_tpu.ops.flash_attention import flash_attention
 
     q = jnp.zeros((1, 256, 2, 64), jnp.float32)
 
     def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True, 128, 128, True) ** 2)
+        return jnp.sum(flash_attention(q, k, v, causal, *blocks, True) ** 2)
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == (
-        "7bb5b9f706683e764df07cc55eadf31843d60056e538d0eb1064172afef90e00")
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
+def test_step_reports_the_attention_counters(f32, f32_step):
+    """The three attention counters lead the step's counters: 0 on the
+    CPU's dense path; under flash at L = 8,192 the schedule's counts."""
+    from pytorch_distributed_tpu.models.decoder import attention_blocks
+
+    _, metrics, _ = f32_step
+    names = ("attn_blocks_visited", "attn_blocks_masked",
+             "attn_subtiles_skipped")
+    assert f32["model"].counter_names[:3] == names
+    assert [int(metrics[name]) for name in names] == [0, 0, 0]
+    assert attention_blocks(8192, "flash") == (36, 8, 48)
+    assert attention_blocks(8192, "dense") == (0, 0, 0)
 
 
 def test_the_two_copies_of_the_reference_are_identical():

@@ -227,16 +227,21 @@ def test_step_reports_no_attention_blocks_on_the_dense_path(f32, f32_step):
     assert int(metrics["attn_blocks_masked"]) == 0
 
 
-@pytest.mark.parametrize("passes", [1, 2])
-def test_step_reports_the_schedules_blocks_under_flash(monkeypatch, passes):
-    """``attn_impl="flash"`` (the Pallas interpreter here) on 16 x 32
-    blocks at L = 64: the step's counters are the schedule's counts, for a
-    looped decoder and for a plain one, and its loss is the dense path's."""
+@pytest.mark.parametrize("passes,blocks,counts", [
+    (1, (16, 32), (6, 4, 0)),
+    (2, (16, 32), (6, 4, 0)),
+    (1, (32, 32), (3, 2, 12)),   # square: diagonal blocks in 8-row sub-tiles
+])
+def test_step_reports_the_schedules_blocks_under_flash(monkeypatch, passes,
+                                                       blocks, counts):
+    """``attn_impl="flash"`` (the Pallas interpreter here) at L = 64: the
+    step's counters are the schedule's counts, for a looped decoder and for
+    a plain one, and its loss is the dense path's."""
     from pytorch_distributed_tpu.models import decoder
     from pytorch_distributed_tpu.ops.flash_attention import blocks_visited
 
-    monkeypatch.setattr(decoder, "FLASH_BLOCKS", (16, 32))
-    assert blocks_visited(L, 16, 32) == (6, 4)
+    monkeypatch.setattr(decoder, "FLASH_BLOCKS", blocks)
+    assert blocks_visited(L, *blocks) == counts
     over = dict(total_ut_steps=passes, num_hidden_layers=1,
                 layer_types=["full_attention"])
     tokens, tx = _tokens(), optax.sgd(1.0)
@@ -254,8 +259,9 @@ def test_step_reports_the_schedules_blocks_under_flash(monkeypatch, passes):
         state = TrainState.create({"params": params}, tx.init(params))
         _, metrics[impl] = step(jax.tree_util.tree_map(jnp.copy, state),
                                 tokens, jnp.float32(0.0))
-    assert int(metrics["flash"]["attn_blocks_visited"]) == 6
-    assert int(metrics["flash"]["attn_blocks_masked"]) == 4
+    names = ("attn_blocks_visited", "attn_blocks_masked",
+             "attn_subtiles_skipped")
+    assert tuple(int(metrics["flash"][name]) for name in names) == counts
     assert int(metrics["dense"]["attn_blocks_visited"]) == 0
     np.testing.assert_allclose(metrics["flash"]["loss"],
                                metrics["dense"]["loss"], atol=1e-4)
@@ -469,7 +475,8 @@ def test_the_kimi_presets_step_lowers_as_before():
     in the rows' weights; the returned hidden rows behind a barrier) again,
     and PR 34 by taking ``loss_head_products`` out of the step's metrics
     (one scalar result fewer; the scope names PR 34 adds are metadata and
-    not in this text)."""
+    not in this text), and the third attention counter,
+    ``attn_subtiles_skipped``."""
     from test_decoder import PRESET as KIMI
 
     model = DecoderLM(DecoderConfig.from_dict(KIMI), dtype=jnp.bfloat16)
@@ -487,7 +494,7 @@ def test_the_kimi_presets_step_lowers_as_before():
             model, mesh, replicated_like(state.params), tx=tx,
             params=state.params, fused_ce_chunks=2)
     assert _digest(step, state, tokens) == (
-        "746c6f554d7836b59208fb2634fdfbadce7a034bc28c6448942ce572a6bcc994")
+        "20fa27088359a5b56305d79e632ef4c0024076b2ac117fd6b1856a246ecc8d23")
 
 
 def test_the_transformer_lms_fused_step_lowers_as_before():

@@ -264,7 +264,7 @@ def test_grouped_flash_kernels_equal_explicit_scores(heads, kv_heads, dtype):
 def test_as_many_key_value_heads_as_query_heads_lowers_as_before():
     """``G = H`` is the parent's program: the digest of
     ``tests/test_decoder.py::test_flash_kernel_on_float32_lowers_as_before``
-    from a call that says its heads twice."""
+    (square causal blocks) from a call that says its heads twice."""
     from pytorch_distributed_tpu.ops.flash_attention import flash_attention
 
     q = jnp.zeros((1, 256, 2, 64), jnp.float32)
@@ -276,7 +276,7 @@ def test_as_many_key_value_heads_as_query_heads_lowers_as_before():
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "7bb5b9f706683e764df07cc55eadf31843d60056e538d0eb1064172afef90e00")
+        "9b5e38bfbbc5c33528c118eb692c5828ed6672e69ee819c6ae513cfa77d249e1")
     grouped = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv[:, :, :1], kv[:, :, :1]).as_text()
     assert grouped != text
